@@ -6,7 +6,7 @@ concurrency ladder with synthetic prompts of a given ISL/OSL, and report
 per-level TTFT/ITL percentiles + aggregate throughput — the numbers the
 SLA planner's interpolators and the Pareto plots consume.
 
-Load SHAPES (VERDICT r4 #9; reference `benchmarks/sin_load_generator/`,
+Load SHAPES (reference `benchmarks/sin_load_generator/`,
 `benchmarks/burstgpt_loadgen/`, `benchmarks/prefix_data_generator/`):
 - `--arrival closed` (default): concurrency-ladder closed loop.
 - `--arrival poisson --qps R`: open loop, exponential inter-arrivals.

@@ -109,27 +109,28 @@ def setup_logging(level: str) -> None:
     init_logging(level.upper())
 
 
-def enable_compile_cache() -> None:
-    """Persistent XLA compile cache (DYN_COMPILE_CACHE dir; empty string
-    disables). A cold 8B engine pays ~18 min of remote compiles for its
-    serving shapes on v5e; with the cache a restarted worker pays
-    seconds. Called by worker startup; safe no-op if jax lacks it."""
+def enable_compile_cache() -> str:
+    """Persistent XLA compile cache, for every entry that builds a real
+    engine; returns the directory. Placed from outside: where
+    JAX_COMPILATION_CACHE_DIR is set JAX reads it itself and nothing is
+    set in code. Otherwise a fixed directory inside the checkout — the
+    path is part of the cache key, so it never carries a temp name, pid
+    or timestamp. A cache that cannot be set up raises: a restarted
+    worker that silently recompiles every serving shape is not the
+    deployment that was asked for."""
     import os
 
-    path = os.environ.get("DYN_COMPILE_CACHE",
-                          os.path.expanduser("~/.cache/dynamo_tpu/xla"))
-    if not path:
-        return
-    try:
-        import jax
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if path:
+        return path
+    import jax
 
-        os.makedirs(path, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", path)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          1.0)
-    except Exception:  # pragma: no cover - degraded, not fatal
-        logging.getLogger(__name__).warning(
-            "persistent compile cache unavailable", exc_info=True)
+    path = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        ".jax_cache")
+    os.makedirs(path, exist_ok=True)
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def run_until_signal(main_coro_factory, *, shutdown=None) -> None:

@@ -92,7 +92,7 @@ def _a8_prologue(x):
 def _w8a8_kernel(x_ref, w_ref, y_ref):
     """Grid (m_tiles, n_tiles, k_tiles); y accumulates int32 across k.
     One native int8×int8→int32 MXU dot — 2× the bf16 pass rate on v5e,
-    and decode at serving batch sizes is MXU-pass-bound (ROUND4_NOTES),
+    and decode at serving batch sizes is MXU-pass-bound,
     so this (not weight bytes) is where quantized decode gains live."""
     k = pl.program_id(2)
 

@@ -29,6 +29,28 @@ def kv_write_supported(page_size: int, head_dim: int) -> bool:
     return page_size % 8 == 0 and head_dim % 128 == 0
 
 
+def per_tp_shard(kernel, in_specs, out_specs):
+    """`kernel`, run once per "tp" shard when the step is being traced
+    under a tensor-parallel mesh (the engine dispatches inside
+    `jax.set_mesh`), else unchanged. GSPMD cannot partition a Mosaic
+    kernel; heads are independent, so a `shard_map` over the head axis
+    the caches and projections are already sharded on is exact and
+    moves no data."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.shape.get("tp", 1) == 1:
+        return kernel
+    return jax.shard_map(kernel, in_specs=in_specs, out_specs=out_specs,
+                         check_vma=False)
+
+
+# PartitionSpecs of the kernels' operands under tp: caches (KVH, N, P, D)
+# split on kv heads, per-token rows (B, heads, D) on their head axis,
+# page ids / lengths / tables replicated
+KV_SPEC = jax.sharding.PartitionSpec("tp")
+ROW_SPEC = jax.sharding.PartitionSpec(None, "tp")
+REP_SPEC = jax.sharding.PartitionSpec()
+
+
 def paged_kv_write(kc: jax.Array, vc: jax.Array, k: jax.Array, v: jax.Array,
                    page_ids: jax.Array, offsets: jax.Array
                    ) -> tuple[jax.Array, jax.Array]:
@@ -38,6 +60,13 @@ def paged_kv_write(kc: jax.Array, vc: jax.Array, k: jax.Array, v: jax.Array,
     sequential on TPU, so duplicate page_ids (scratch page 0 for padding
     lanes) are safe — last write wins.
     """
+    return per_tp_shard(
+        _paged_kv_write,
+        (KV_SPEC, KV_SPEC, ROW_SPEC, ROW_SPEC, REP_SPEC, REP_SPEC),
+        (KV_SPEC, KV_SPEC))(kc, vc, k, v, page_ids, offsets)
+
+
+def _paged_kv_write(kc, vc, k, v, page_ids, offsets):
     pl, pltpu = _pltpu()
     kvh, n_pages, p, d = kc.shape
     b = k.shape[0]
@@ -92,6 +121,13 @@ def paged_kv_write_pages(kc: jax.Array, vc: jax.Array,
     programs/layer ≈ 143 ms per engine prefill; page path = 128
     programs/layer.
     """
+    return per_tp_shard(
+        _paged_kv_write_pages,
+        (KV_SPEC, KV_SPEC, ROW_SPEC, ROW_SPEC, REP_SPEC),
+        (KV_SPEC, KV_SPEC))(kc, vc, k_blocks, v_blocks, page_ids)
+
+
+def _paged_kv_write_pages(kc, vc, k_blocks, v_blocks, page_ids):
     pl, pltpu = _pltpu()
     kvh, n_pages, p, d = kc.shape
     m = k_blocks.shape[0]

@@ -23,7 +23,7 @@ from __future__ import annotations
 
 from dynamo_tpu.engine.compile_tracker import CompileTracker
 from dynamo_tpu.llm.perf import ITL_BUCKET_EDGES_MS
-from dynamo_tpu.runtime.metrics import (Counter, Histogram,
+from dynamo_tpu.runtime.metrics import (Counter, Gauge, Histogram,
                                         MetricsRegistry)
 
 # second-scale stage latencies: sub-ms admission checks up to multi-
@@ -135,6 +135,13 @@ class EngineMetrics:
             "dynamo_engine_dispatch_gap_seconds",
             "host gap between consecutive jitted dispatches",
             _GAP_BUCKETS)
+        # set once by the worker from TpuEngine.device_report(): value =
+        # device count, labels say which platform/kind the engine's
+        # arrays sit on and whether the attention kernel path is on
+        self.device_info = Gauge(
+            "dynamo_engine_device_info",
+            "devices holding this engine's weights and KV cache "
+            "(labels: platform, kind, attention_kernels)")
         self.compile = CompileTracker()
 
     def register(self, registry: MetricsRegistry) -> None:
@@ -149,7 +156,7 @@ class EngineMetrics:
                   self.pipelined_bursts, self.mixed_steps,
                   self.decode_steps_during_prefill,
                   self.goodput_tokens, self.padded_tokens,
-                  self.dispatch_gap):
+                  self.dispatch_gap, self.device_info):
             registry.register(m)
         # module-owned: the attention impl switch predates any engine,
         # but its fallback attribution belongs on the same scrape

@@ -15,8 +15,7 @@ Each record carries:
   * `entry` / `shape` — the CompileTracker key for the dispatch;
   * `host_s` — host wall time of the dispatch closure. When
     `synced=True` the closure ended with an `np.asarray` round-trip, so
-    this IS the honest device step time (docs/ROUND4_NOTES.md:
-    `block_until_ready()` lies for pallas outputs inside fori_loops;
+    this IS the honest device step time (`block_until_ready()` lies for pallas outputs inside fori_loops;
     only np.asarray round-trips are trustworthy). Pipelined decode
     bursts dispatch without syncing — those record `synced=False`
     (dispatch-only time) and the later `_pipeline_consume` np.asarray

@@ -38,10 +38,9 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from dynamo_tpu.compat import shard_map
 
 _NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 

@@ -1,8 +1,7 @@
 """Dispatch watchdog: detect a wedged engine dispatch and heal the fleet.
 
-The device bench has been dead since r03 on exactly one failure mode we
-only *diagnosed* before (docs/ROUND4_NOTES.md): a jitted device call
-that never returns. The engine loop blocks, the lease keeps refreshing
+The failure mode it exists for: a jitted device call that never
+returns. The engine loop blocks, the lease keeps refreshing
 (the keepalive task still runs), routers keep sending traffic, and
 every stream wedges until a client-side idle timeout fires — if one is
 configured. This module is the server-side answer: a monitor THREAD

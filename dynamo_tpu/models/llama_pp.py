@@ -30,10 +30,10 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
+from jax.lax import pcast
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from dynamo_tpu.compat import pcast, shard_map
 from dynamo_tpu.engine.quant import qm
 from dynamo_tpu.models.llama import (
     LlamaConfig,

@@ -26,10 +26,9 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from dynamo_tpu.compat import shard_map
 from dynamo_tpu.engine.quant import qm
 from dynamo_tpu.engine.ring_attention import ring_attention_local
 from dynamo_tpu.models.llama import (

@@ -73,18 +73,28 @@ def _fill(pool, offset: int, shape):
 
 def write_synthetic_hf_checkpoint(path: str, preset: str = "llama3-8b",
                                   seed: int = 0,
-                                  shard_bytes: int = 2 << 30) -> str:
+                                  shard_bytes: int = 2 << 30,
+                                  layers: int = 0,
+                                  head_gain: float = 1.0) -> str:
     """Write config.json + sharded safetensors + index under `path`.
 
     Returns `path`. Idempotent: a directory whose marker file matches
-    the preset is reused as-is (the 8B build writes 16 GB)."""
+    the preset is reused as-is (the 8B build writes 16 GB). `layers`
+    cuts the preset's depth (0 = as published); widths never change.
+    `head_gain` scales lm_head: at 1.0 the logits of noise weights are
+    nearly flat (std ~0.4, every token a near-tie), which makes token
+    comparisons between two runs meaningless; a gain of 16 gives a
+    peaked distribution whose greedy choice survives bf16 rounding."""
     from safetensors.numpy import save_file
 
+    hidden, inter, full_depth, heads, kv_heads, vocab = PRESETS[preset]
+    layers = layers or full_depth
     marker = os.path.join(path, ".synth_ckpt")
-    want = f"{preset}:{seed}:v1"
+    want = f"{preset}:{seed}:v1" + (
+        f":L{layers}" if layers != full_depth else "") + (
+        f":g{head_gain:g}" if head_gain != 1.0 else "")
     if os.path.exists(marker) and open(marker).read() == want:
         return path
-    hidden, inter, layers, heads, kv_heads, vocab = PRESETS[preset]
     head_dim = hidden // heads
     qwen = preset.startswith("qwen2")
     moe = MOE_PRESETS.get(preset)
@@ -173,6 +183,8 @@ def write_synthetic_hf_checkpoint(path: str, preset: str = "llama3-8b",
         else:
             off = int(rng.integers(0, _POOL_ELEMS))
             t = _fill(pool, off, shape)
+            if name == "lm_head.weight" and head_gain != 1.0:
+                t = (t.astype(np.float32) * head_gain).astype(pool.dtype)
         shard[name] = t
         shard_n += t.nbytes
         if shard_n >= shard_bytes:

@@ -93,8 +93,10 @@ async def build_local(out: str, args, runtime):
                                            default_max_tokens=args.max_tokens),
                           event_sink=ev, metrics_sink=ms), card
     if out.startswith("tpu:") or out == "tpu":
+        from dynamo_tpu.cli_util import enable_compile_cache
         from dynamo_tpu.llm.entrypoint import build_tpu_engine
 
+        enable_compile_cache()
         model = out[4:] if out.startswith("tpu:") else args.model_name
         engine, card = build_tpu_engine(model)
         card.namespace = args.namespace

@@ -27,7 +27,7 @@ Spec grammar (``DYN_FAULTS`` env var, or `FaultInjector.from_spec`):
                            backpressures evictions into the inline path)
     kind=dispatch_wedge    the engine scheduler loop parks mid-dispatch
                            with work pending — the chip-free model of a
-                           wedged jitted device call (docs/ROUND4_NOTES).
+                           wedged jitted device call.
                            The dispatch watchdog (engine/watchdog.py)
                            must detect it and quarantine the worker.
     kind=oom               a matching dispatch raises a synthetic

@@ -60,7 +60,7 @@ async def test_router_affinity_under_shared_prefix_load():
     """KV-router e2e with the sweep's prefix-sharing load: requests
     drawn from 2 shared prefixes over 2 workers must develop per-prefix
     worker affinity (overlap scoring doing its job); the default
-    prefix-disjoint load can't (VERDICT r4 #9)."""
+    prefix-disjoint load can't."""
     import asyncio
 
     from benchmarks.sweep import make_prompt

@@ -1,0 +1,220 @@
+"""Main-path kernels and step programs compiled for the chip, without it.
+
+The TPU compiler is installed here and compiles for a *described*
+v5e:2x2: what Mosaic refuses (tiling, VMEM) or what does not fit shows
+up in this file instead of costing chip time. Shapes are Llama-3-8B
+widths (32 q heads, 8 kv heads, head_dim 128, page 16, bf16 KV). A
+compile that passes is not a chip run — `chip_smoke.py` is.
+
+The topology is described inside the `topo` fixture only: libtpu loads
+once per process, so nothing here may touch it at import/collection
+time (every xdist worker imports this file; one runs it).
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+H, KVH, D, PAGE, PAGES, MAX_PAGES = 32, 8, 128, 16, 2048, 128
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    """A compile for a described chip is written to the persistent
+    cache but cannot be read back without the chip (the next one warns
+    and recompiles): keep these compiles out of it."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def sds(one_chip, no_persistent_cache):
+    def make(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    return make
+
+
+def _compiled_text(fn, *args) -> str:
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _kv(sds):
+    return sds((KVH, PAGES, PAGE, D)), sds((KVH, PAGES, PAGE, D))
+
+
+@pytest.mark.parametrize("batch", [4, 32])
+def test_paged_attention_decode_kernel(sds, batch):
+    from dynamo_tpu.engine.attention import _pallas_decode
+
+    kc, vc = _kv(sds)
+    text = _compiled_text(
+        _pallas_decode, sds((batch, H, D)), kc, vc,
+        sds((batch,), jnp.int32), sds((batch, MAX_PAGES), jnp.int32))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("batch", [4, 32])
+def test_paged_kv_write_kernel(sds, batch):
+    from dynamo_tpu.engine.kernels import paged_kv_write
+
+    kc, vc = _kv(sds)
+    text = _compiled_text(
+        paged_kv_write, kc, vc, sds((batch, KVH, D)), sds((batch, KVH, D)),
+        sds((batch,), jnp.int32), sds((batch,), jnp.int32))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("n_pages", [8, 256])   # 1 x 128 and 32 x 128 tokens
+def test_paged_kv_write_pages_kernel(sds, n_pages):
+    from dynamo_tpu.engine.kernels import paged_kv_write_pages
+
+    kc, vc = _kv(sds)
+    text = _compiled_text(
+        paged_kv_write_pages, kc, vc, sds((n_pages, KVH, PAGE, D)),
+        sds((n_pages, KVH, PAGE, D)), sds((n_pages,), jnp.int32))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("rows,lanes", [(8, 4), (64, 32)])
+def test_ragged_paged_attention_kernel(sds, rows, lanes):
+    from dynamo_tpu.engine.ragged import ragged_paged_attention
+
+    kc, vc = _kv(sds)
+    text = _compiled_text(
+        ragged_paged_attention, sds((rows, H, D)), kc, vc,
+        sds((rows,), jnp.int32), sds((rows,), jnp.int32),
+        sds((lanes, MAX_PAGES), jnp.int32))
+    assert "tpu_custom_call" in text
+
+
+# -- the engine's own jitted steps, 8B widths, depth cut to 2 layers --------
+
+
+@pytest.fixture
+def pallas_impl(monkeypatch):
+    # use_pallas() asks the default backend, which is the CPU here:
+    # steer it in the test, as the worker on the chip would decide
+    from dynamo_tpu.engine import attention
+
+    monkeypatch.setattr(attention, "_impl", "pallas")
+
+
+@pytest.fixture
+def model(sds, one_chip):
+    """(cfg, params, k_cache, v_cache) as shapes on the described chip:
+    int8 weights exactly as `--quantize int8` serves them."""
+    from dynamo_tpu.engine.quant import quantize_params
+    from dynamo_tpu.models.llama import (LlamaConfig, init_cache,
+                                         init_params)
+
+    cfg = LlamaConfig.llama3_8b(num_layers=2, max_pages_per_seq=MAX_PAGES)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda k: quantize_params(init_params(k, cfg), mode="int8"),
+        jax.random.PRNGKey(0)))
+    kc, vc = on_chip(jax.eval_shape(lambda: init_cache(cfg, PAGES)))
+    return cfg, params, kc, vc
+
+
+def test_engine_decode_burst_holds_the_kernels(sds, model, pallas_impl):
+    from dynamo_tpu.models.llama import decode_multi_step
+
+    cfg, params, kc, vc = model
+    b, i32, u32, f32 = 8, jnp.int32, jnp.uint32, jnp.float32
+    compiled = decode_multi_step.lower(
+        params, kc, vc, sds((b,), i32), sds((b,), i32),
+        sds((b, MAX_PAGES), i32), sds((b,), jnp.bool_), sds((b,), u32),
+        sds((b,), u32), sds((b,), f32), sds((b,), f32), sds((b,), i32),
+        cfg, 8, topk_lp=0).compile()
+    # per layer: the row KV write and the paged-attention decode
+    assert compiled.as_text().count("tpu_custom_call") >= 2 * cfg.num_layers
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 1 << 30
+
+
+def test_engine_prefill_chunk_holds_the_page_write(sds, model, pallas_impl):
+    from dynamo_tpu.models.llama import prefill_batch
+
+    cfg, params, kc, vc = model
+    bp, t, i32 = 1, 128, jnp.int32
+    compiled = prefill_batch.lower(
+        params, kc, vc, sds((bp, t), i32), sds((bp, MAX_PAGES), i32),
+        sds((bp,), i32), sds((bp,), i32), cfg, aligned=True).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= cfg.num_layers
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+def test_tensor_parallel_decode_burst_splits_the_kernels(
+        topo, no_persistent_cache, pallas_impl):
+    """tp=4 over the described 2x2: GSPMD cannot partition a Mosaic
+    kernel, so the step must run them per shard (kernels.per_tp_shard)
+    and still reduce over tp."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from dynamo_tpu.engine.quant import quantize_params
+    from dynamo_tpu.engine.sharding import (cache_sharding, make_mesh,
+                                            param_sharding)
+    from dynamo_tpu.models.llama import (LlamaConfig, decode_multi_step,
+                                         init_cache, init_params)
+
+    cfg = LlamaConfig.llama3_8b(num_layers=2, max_pages_per_seq=MAX_PAGES)
+    mesh = make_mesh(dp=1, tp=4, devices=list(topo.devices))
+
+    def placed(tree, shardings):
+        return jax.tree.map(
+            lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+            tree, shardings)
+
+    # as the engine does: bf16 shards placed, then quantised in place
+    raw = placed(jax.eval_shape(lambda k: init_params(k, cfg),
+                                jax.random.PRNGKey(0)),
+                 param_sharding(mesh))
+    quantise = jax.jit(lambda p: quantize_params(p, mode="int8"))
+    params = placed(jax.eval_shape(quantise, raw),
+                    quantise.lower(raw).compile().output_shardings)
+    caches = jax.eval_shape(lambda: init_cache(cfg, PAGES))
+    kc, vc = placed(caches, jax.tree.map(
+        lambda _: cache_sharding(mesh), caches))
+    rep = NamedSharding(mesh, P())
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=rep)
+
+    b, i32, u32, f32 = 8, jnp.int32, jnp.uint32, jnp.float32
+    with jax.set_mesh(mesh):
+        text = decode_multi_step.lower(
+            params, kc, vc, sds((b,), i32), sds((b,), i32),
+            sds((b, MAX_PAGES), i32), sds((b,), jnp.bool_), sds((b,), u32),
+            sds((b,), u32), sds((b,), f32), sds((b,), f32), sds((b,), i32),
+            cfg, 8, topk_lp=0).compile().as_text()
+    assert text.count("tpu_custom_call") >= 2 * cfg.num_layers
+    assert "all-reduce" in text

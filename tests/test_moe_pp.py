@@ -4,9 +4,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import set_mesh
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from dynamo_tpu.compat import set_mesh
 
 from dynamo_tpu.models.mixtral import (
     MoeConfig,
@@ -178,8 +177,7 @@ def test_pp_weights_are_stage_sharded(cpu_mesh_devices):
 
 def test_pp_decode_matches_single_device_decode(cpu_mesh_devices):
     """pp=2 microbatched decode emits tokens identical to the plain
-    fused decode loop on the same weights (greedy) — the VERDICT r3
-    'pp decode' done-criterion."""
+    fused decode loop on the same weights (greedy)."""
     from dynamo_tpu.engine.attention import set_attention_impl
     from dynamo_tpu.models.llama import (
         LlamaConfig,

@@ -351,7 +351,7 @@ async def test_profiler_normalizes_per_chip(tmp_path):
 
 
 def test_pre_swept_sizing_no_engine_boot():
-    """VERDICT r4 #10: the planner sizes p/d pools from a COMMITTED
+    """The planner sizes p/d pools from a COMMITTED
     pre-swept table alone — no engine, no live profiling."""
     import json
     import subprocess
@@ -405,8 +405,8 @@ def test_pre_swept_rejects_malformed_table(tmp_path):
 
 def test_holtwinters_tracks_seasonal_load():
     """The seasonal predictor must forecast a sinusoidal load with the
-    upcoming phase, where EWMA/linear lag it (VERDICT r4 missing #6 —
-    the Prophet/ARIMA planning role)."""
+    upcoming phase, where EWMA/linear lag it (the Prophet/ARIMA
+    planning role)."""
     from dynamo_tpu.planner.load_predictor import (
         EwmaPredictor,
         HoltWintersPredictor,

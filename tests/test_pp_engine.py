@@ -3,7 +3,7 @@
 A pp=2 TpuEngine serves requests through pp_prefill_paged (chunk
 microbatches, stage-local paged KV) + pp_decode_multi_step (lane-group
 microbatches, psum token mailbox); greedy output must equal the plain
-engine's on the same weights — VERDICT r3 #6's done-criterion.
+engine's on the same weights.
 Reference: trtllm --pipeline-parallel-size (trtllm_utils.py:39,167-170).
 """
 
@@ -89,8 +89,7 @@ async def test_pp_engine_concurrent_batch(cpu_mesh_devices):
 TOKEN_BYTES = [bytes([i]) if i < 256 else None
                for i in range(CFG.vocab_size)]
 
-# the full sampling matrix (VERDICT r4 #8: pp engines served a reduced
-# feature set): every request below must produce IDENTICAL output on a
+# the full sampling matrix: every request below must produce IDENTICAL output on a
 # pp=2 engine and the plain engine — the constrained head runs on the
 # last stage, same packings as the plain constrained burst
 MATRIX = [

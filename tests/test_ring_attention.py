@@ -8,8 +8,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 
-from dynamo_tpu.compat import shard_map
 from dynamo_tpu.engine.ring_attention import (
     ring_attention,
     ring_attention_local,

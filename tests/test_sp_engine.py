@@ -111,7 +111,7 @@ def sp_tp_mesh(devices, sp=2, tp=2):
 
 
 async def test_sp_tp_engine_matches_tp_only(cpu_mesh_devices):
-    """The VERDICT r2 composition: TP-sharded serving weights + SP ring
+    """The sp x tp composition: TP-sharded serving weights + SP ring
     prefill on one 2-D mesh, KV written back to the tp-sharded paged
     cache. Greedy tokens must equal the tp-only engine's."""
     from dynamo_tpu.engine.sharding import make_mesh

@@ -263,7 +263,7 @@ async def test_draft_catchup_after_fallback_burst():
 
 
 async def test_greedy_spec_with_guided_matches_constrained_engine():
-    """VERDICT r3: constrained lanes coexist in a spec burst. Greedy
+    """Constrained lanes coexist in a spec burst. Greedy
     spec+grammar output must equal the no-draft constrained engine's —
     the draft only changes speed, never tokens, even under a mask."""
     token_bytes = [bytes([i]) if i < 256 else None

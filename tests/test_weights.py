@@ -12,9 +12,9 @@ import os
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
-from dynamo_tpu.compat import tree_leaves_with_path
 from dynamo_tpu.engine.attention import set_attention_impl
 
 set_attention_impl("xla")
@@ -199,7 +199,7 @@ def test_device_loader_matches_host_loader(checkpoint):
 
 
 def test_loader_bit_exact_across_fresh_loads(checkpoint):
-    """VERDICT r4 #6: the loader witness — two fresh device loads of
+    """The loader witness — two fresh device loads of
     the same checkpoint produce IDENTICAL bytes on every leaf (incl.
     int8 quantized), and two fresh engines built from them emit
     identical greedy tokens. The prefetch/throttle pipeline must be
@@ -222,7 +222,7 @@ def test_loader_bit_exact_across_fresh_loads(checkpoint):
     def leaves(p):
         return [(k, np.asarray(x.q) if isinstance(x, QTensor) else
                  np.asarray(x))
-                for k, x in sorted(tree_leaves_with_path(
+                for k, x in sorted(jax.tree.leaves_with_path(
                     p, is_leaf=lambda v: isinstance(v, QTensor)),
                     key=lambda kv: str(kv[0]))]
 

@@ -193,7 +193,8 @@ prefix-smoke:
 # ring back through GET /debug/profile + doctor profile, and assert
 # decode goodput equals tokens emitted and the padded share matches the
 # analytically-known _pow2 bucketing of the scripted batch mix; plus
-# the zero-cost off path (no recorder state, scheduler_stats unchanged)
-# and the Chrome trace-event round-trip.
+# the zero-cost off path (no recorder state, scheduler_stats unchanged);
+# and the scheduler's host spans in a jax.profiler trace of a toy engine
+# plus the worker's /debug/profile?capture_s= route.
 profile-smoke:
-	$(PYTEST) tests/test_step_profiler.py
+	$(PYTEST) tests/test_step_profiler.py tests/test_host_spans.py

@@ -6,9 +6,10 @@ HTTP) or a path to a JSON file holding the same payload (tests and
 offline captures hand the file; a single-engine `profile_payload` dict
 works too). Renders, per engine: per-entry device-time share, the
 padding-waste table by bucket shape, a dispatch-gap histogram built
-from the ring window, and the top compile stalls. `--chrome out.json`
-additionally exports the merged ring as Chrome trace-event JSON for
-Perfetto. Exit code 0 when at least one armed engine was rendered,
+from the ring window, and the top compile stalls. A timeline comes from
+the profiler's own trace (`/debug/profile?capture_s=N`), where the
+engine's host spans sit beside the device.
+Exit code 0 when at least one armed engine was rendered,
 1 when the input was unusable or every engine had the recorder off.
 """
 
@@ -164,9 +165,6 @@ def main(argv: Optional[list[str]] = None) -> int:
                     "(/debug/profile)")
     p.add_argument("source",
                    help="frontend base url or profile JSON capture")
-    p.add_argument("--chrome", default=None, metavar="OUT.json",
-                   help="also export the ring as Chrome trace-event "
-                        "JSON (open in Perfetto)")
     p.add_argument("--top-shapes", type=int, default=8)
     args = p.parse_args(sys.argv[1:] if argv is None else argv)
 
@@ -181,19 +179,6 @@ def main(argv: Optional[list[str]] = None) -> int:
     for i, payload in enumerate(payloads):
         if render_engine(payload, i, top_shapes=args.top_shapes):
             rendered += 1
-
-    if args.chrome:
-        from dynamo_tpu.engine.profiler import chrome_trace_from_records
-
-        events: list = []
-        for i, payload in enumerate(payloads):
-            trace = chrome_trace_from_records(
-                payload.get("records") or [], pid=i + 1)
-            events.extend(trace["traceEvents"])
-        with open(args.chrome, "w", encoding="utf-8") as f:
-            json.dump({"traceEvents": events, "displayTimeUnit": "ms"},
-                      f)
-        print(f"chrome trace ({len(events)} events) -> {args.chrome}")
 
     return 0 if rendered else 1
 

@@ -68,6 +68,8 @@ from dynamo_tpu.tokens import TokenBlockSequence
 
 logger = logging.getLogger(__name__)
 
+_NO_SPAN = contextlib.nullcontext()
+
 
 @jax.jit
 def _gather_kv_jit(k_cache, v_cache, ids) -> "jax.Array":
@@ -1058,6 +1060,8 @@ class TpuEngine:
                     await asyncio.to_thread(self._drain_inflight_sync)
                     continue
                 self._wake.clear()
+                rec = self.step_recorder
+                t_wait = rec.begin("wait") if rec is not None else 0.0
                 if self._transfers:
                     # stay reap-able: pinned transfers must expire even
                     # when no requests are in flight
@@ -1068,24 +1072,28 @@ class TpuEngine:
                     self._reap_transfers()
                 else:
                     await self._wake.wait()
+                if rec is not None:
+                    rec.end("wait", t_wait)
                 continue
             try:
-                if self.bucket_ladder is not None:
-                    # safe point: between dispatches, before this
-                    # iteration picks its batch shapes
-                    self.bucket_ladder.maybe_apply()
-                self._reap_transfers()
-                self._admit()
-                if self.kvbm is not None and self._waiting:
-                    # stage tier blocks for still-queued requests so
-                    # their admission onboard is one device write
-                    # (no-op unless kvbm prefetch_blocks > 0); router
-                    # prefix hints (request extra.kv_hints) ride along
-                    hints = [s.req.extra.get("kv_hints")
-                             for s in self._waiting]
-                    self.kvbm.prefetch_waiting(
-                        self._waiting,
-                        hints=[h for h in hints if h] or None)
+                with self._span("admit"):
+                    if self.bucket_ladder is not None:
+                        # safe point: between dispatches, before this
+                        # iteration picks its batch shapes
+                        self.bucket_ladder.maybe_apply()
+                    self._reap_transfers()
+                    self._admit()
+                    if self.kvbm is not None and self._waiting:
+                        # stage tier blocks for still-queued requests
+                        # so their admission onboard is one device
+                        # write (no-op unless kvbm prefetch_blocks >
+                        # 0); router prefix hints (request
+                        # extra.kv_hints) ride along
+                        hints = [s.req.extra.get("kv_hints")
+                                 for s in self._waiting]
+                        self.kvbm.prefetch_waiting(
+                            self._waiting,
+                            hints=[h for h in hints if h] or None)
                 if self.kvbm is not None and self.kvbm.remote is not None:
                     # G4: continue freshly-admitted prompts' block chains
                     # from peer workers' tiers before prefill. Fetches
@@ -1111,11 +1119,16 @@ class TpuEngine:
                     self.metrics.decode_seconds.inc(
                         time.perf_counter() - t1)
                 progressed |= decoded
-                self._publish_metrics()
+                with self._span("publish"):
+                    self._publish_metrics()
                 if progressed:
                     self._progress += 1
                 else:
+                    rec = self.step_recorder
+                    t_yield = rec.begin("yield") if rec is not None else 0.0
                     await asyncio.sleep(0.001)
+                    if rec is not None:
+                        rec.end("yield", t_yield)
             except Exception as exc:
                 led = self.memory_ledger
                 if led is not None and is_resource_exhausted(exc):
@@ -1387,61 +1400,62 @@ class TpuEngine:
         first-token semantics can never diverge."""
         cfg, mcfg = self.config, self.model_cfg
         width = cfg.max_batch_size
-        stack = [last_logits[id(s)] for s in pending]
-        while len(stack) < width:
-            stack.append(stack[0])
-        guided_mask = None
-        if any(s.guided is not None for s in pending):
-            # first sampled token must already respect the grammar
-            V = mcfg.vocab_size
-            guided_mask = np.zeros((width, V), dtype=np.float32)
-            for i, s in enumerate(pending):
-                if s.guided is not None:
-                    ok = self._guided_allowed_row(s.guided, s, V)
-                    guided_mask[i, ~ok] = -1e30
-        penalty_args = None
-        if any(s.has_penalties for s in pending):
-            # the FIRST sampled token must see the same penalties as
-            # every decode-burst token (vLLM semantics: repetition
-            # covers prompt tokens)
-            penalty_args = self._penalty_arrays(pending, width)
+        with self._span("sample_first"):
+            stack = [last_logits[id(s)] for s in pending]
+            while len(stack) < width:
+                stack.append(stack[0])
+            guided_mask = None
+            if any(s.guided is not None for s in pending):
+                # first sampled token must already respect the grammar
+                V = mcfg.vocab_size
+                guided_mask = np.zeros((width, V), dtype=np.float32)
+                for i, s in enumerate(pending):
+                    if s.guided is not None:
+                        ok = self._guided_allowed_row(s.guided, s, V)
+                        guided_mask[i, ~ok] = -1e30
+            penalty_args = None
+            if any(s.has_penalties for s in pending):
+                # the FIRST sampled token must see the same penalties as
+                # every decode-burst token (vLLM semantics: repetition
+                # covers prompt tokens)
+                penalty_args = self._penalty_arrays(pending, width)
 
-        def arr(fn, dtype):
-            vals = [fn(s) for s in pending]
-            vals += [vals[0]] * (width - len(pending))
-            return np.asarray(vals, dtype=dtype)
+            def arr(fn, dtype):
+                vals = [fn(s) for s in pending]
+                vals += [vals[0]] * (width - len(pending))
+                return np.asarray(vals, dtype=dtype)
 
-        logits_stack = jax.numpy.stack(stack)
-        if penalty_args is not None:
-            from dynamo_tpu.engine.sampling import apply_penalties
+            logits_stack = jax.numpy.stack(stack)
+            if penalty_args is not None:
+                from dynamo_tpu.engine.sampling import apply_penalties
 
-            rep_a, freq_a, pres_a, pc, oc = penalty_args
-            logits_stack = apply_penalties(
-                logits_stack, jax.numpy.asarray(pc),
-                jax.numpy.asarray(oc),
-                jax.numpy.asarray(rep_a), jax.numpy.asarray(freq_a),
-                jax.numpy.asarray(pres_a))
-        if guided_mask is not None:
-            logits_stack = logits_stack + jax.numpy.asarray(
-                guided_mask)
-        tk = (self.TOPK_WIDTH
-              if any(s.wants_topk for s in pending) else 0)
+                rep_a, freq_a, pres_a, pc, oc = penalty_args
+                logits_stack = apply_penalties(
+                    logits_stack, jax.numpy.asarray(pc),
+                    jax.numpy.asarray(oc),
+                    jax.numpy.asarray(rep_a), jax.numpy.asarray(freq_a),
+                    jax.numpy.asarray(pres_a))
+            if guided_mask is not None:
+                logits_stack = logits_stack + jax.numpy.asarray(
+                    guided_mask)
+            tk = (self.TOPK_WIDTH
+                  if any(s.wants_topk for s in pending) else 0)
+            lane_arrays = (
+                arr(lambda s: s.seed, np.uint32),
+                arr(lambda s: s.generated, np.uint32),
+                arr(lambda s: s.req.sampling.temperature, np.float32),
+                arr(lambda s: s.req.sampling.top_p, np.float32),
+                arr(lambda s: s.req.sampling.top_k, np.int32),
+                arr(lambda s: s.req.sampling.min_p, np.float32))
         trk = self.metrics.compile.track("sample_first", (width, tk))
         led = self.memory_ledger
         if led is not None:
             led.on_dispatch(trk.entry, trk.shape, compiled=trk.compiled)
         with trk:
             sampled = self._mesh_dispatch(
-                trk, sample_tokens_lp,
-                logits_stack,
-                arr(lambda s: s.seed, np.uint32),
-                arr(lambda s: s.generated, np.uint32),
-                arr(lambda s: s.req.sampling.temperature, np.float32),
-                arr(lambda s: s.req.sampling.top_p, np.float32),
-                arr(lambda s: s.req.sampling.top_k, np.int32),
-                arr(lambda s: s.req.sampling.min_p, np.float32),
-                topk_lp=tk)
-            out = np.asarray(sampled)                 # ONE host sync
+                trk, sample_tokens_lp, logits_stack, *lane_arrays, topk_lp=tk,
+                span_tokens=len(pending))
+            out = self._host_sync(sampled)            # ONE host sync
         rec = self.step_recorder
         if rec is not None:
             rec.record("sample_first", trk.shape, trk.elapsed_s,
@@ -1457,33 +1471,34 @@ class TpuEngine:
         (budgeted path): the draft cache saw none of the prompt — leave
         draft_pos at 0 so _draft_catchup replays it before the first
         spec burst (the draft is small by construction)."""
-        mcfg = self.model_cfg
-        tokens = packed[0].astype(np.int32)
-        logprobs = packed[1]
-        self.metrics.prefill_emitted.inc(len(pending))
-        for i, (seq, token, lp) in enumerate(zip(pending, tokens,
-                                                 logprobs)):
-            # token_seq mirrors what prefill wrote to the device; register
-            # every complete block this worker now holds (no-op for blocks
-            # matched from already-registered shared pages)
-            seq.token_seq = TokenBlockSequence(mcfg.page_size, seq.prompt)
-            for block in seq.token_seq.blocks:
-                self.pool.register_page(
-                    seq.pages[block.block_index], block.seq_hash,
-                    block.local_hash, block.parent_seq_hash)
-            seq.prefilled = True
-            seq.prefill_pos = len(seq.prompt)
-            seq.draft_pos = len(seq.prompt) if draft_done else 0
-            topk_fn = None
-            if tk and seq.wants_topk:
-                def topk_fn(_k, _i=i, _s=seq):
-                    return _topk_list(
-                        packed[2:2 + tk, _i],
-                        packed[2 + tk:2 + 2 * tk, _i],
-                        min(_s.req.sampling.top_logprobs, tk))
+        with self._span("emit"):
+            mcfg = self.model_cfg
+            tokens = packed[0].astype(np.int32)
+            logprobs = packed[1]
+            self.metrics.prefill_emitted.inc(len(pending))
+            for i, (seq, token, lp) in enumerate(zip(pending, tokens,
+                                                     logprobs)):
+                # token_seq mirrors what prefill wrote to the device; register
+                # every complete block this worker now holds (no-op for blocks
+                # matched from already-registered shared pages)
+                seq.token_seq = TokenBlockSequence(mcfg.page_size, seq.prompt)
+                for block in seq.token_seq.blocks:
+                    self.pool.register_page(
+                        seq.pages[block.block_index], block.seq_hash,
+                        block.local_hash, block.parent_seq_hash)
+                seq.prefilled = True
+                seq.prefill_pos = len(seq.prompt)
+                seq.draft_pos = len(seq.prompt) if draft_done else 0
+                topk_fn = None
+                if tk and seq.wants_topk:
+                    def topk_fn(_k, _i=i, _s=seq):
+                        return _topk_list(
+                            packed[2:2 + tk, _i],
+                            packed[2 + tk:2 + 2 * tk, _i],
+                            min(_s.req.sampling.top_logprobs, tk))
 
-            self._emit_lane(seq, np.asarray([token]), [float(lp)],
-                            topk_fn, append_inputs=False)
+                self._emit_lane(seq, np.asarray([token]), [float(lp)],
+                                topk_fn, append_inputs=False)
 
     async def _prefill_budgeted(self) -> bool:
         """Token-budgeted interleaved prefill step: advance pending
@@ -1581,7 +1596,8 @@ class TpuEngine:
         batch: list[_Seq] = []
         if (runnable and self._inflight is None
                 and self.draft_params is None and cfg.pp_mesh is None):
-            self._prep_decode_lanes(runnable, k_steps)
+            with self._span("decode_prep"):
+                self._prep_decode_lanes(runnable, k_steps)
             batch = runnable[:cfg.max_batch_size]
             if any(s.needs_constrained for s in batch):
                 batch = []
@@ -1616,43 +1632,44 @@ class TpuEngine:
         if self._ragged_active():
             return await self._ragged_mixed(picks, offsets, caps, batch)
         cfg, mcfg = self.config, self.model_cfg
-        bp = self._prefill_width(len(picks))
-        chunk_lens = [caps[id(s)] for s in picks]
-        t_bucket = self._token_bucket(max(chunk_lens))
-        ch_toks = np.zeros((bp, t_bucket), dtype=np.int32)
-        ch_tables = np.zeros((bp, mcfg.max_pages_per_seq),
-                             dtype=np.int32)
-        ch_cached = np.zeros(bp, dtype=np.int32)
-        ch_seq_lens = np.zeros(bp, dtype=np.int32)
-        for i, s in enumerate(picks):
-            off, n = offsets[id(s)], chunk_lens[i]
-            ch_toks[i, :n] = s.prompt[off:off + n]
-            ch_tables[i, :len(s.pages)] = s.pages
-            ch_cached[i] = off
-            ch_seq_lens[i] = off + n
+        with self._span("prefill_prep"):
+            bp = self._prefill_width(len(picks))
+            chunk_lens = [caps[id(s)] for s in picks]
+            t_bucket = self._token_bucket(max(chunk_lens))
+            ch_toks = np.zeros((bp, t_bucket), dtype=np.int32)
+            ch_tables = np.zeros((bp, mcfg.max_pages_per_seq),
+                                 dtype=np.int32)
+            ch_cached = np.zeros(bp, dtype=np.int32)
+            ch_seq_lens = np.zeros(bp, dtype=np.int32)
+            for i, s in enumerate(picks):
+                off, n = offsets[id(s)], chunk_lens[i]
+                ch_toks[i, :n] = s.prompt[off:off + n]
+                ch_tables[i, :len(s.pages)] = s.pages
+                ch_cached[i] = off
+                ch_seq_lens[i] = off + n
 
-        b = cfg.max_batch_size
-        tokens = np.zeros(b, dtype=np.int32)
-        positions = np.zeros(b, dtype=np.int32)
-        page_tables = np.zeros((b, mcfg.max_pages_per_seq),
-                               dtype=np.int32)
-        valid = np.zeros(b, dtype=bool)
-        seeds = np.zeros(b, dtype=np.uint32)
-        steps = np.zeros(b, dtype=np.uint32)
-        temps = np.zeros(b, dtype=np.float32)
-        top_ps = np.ones(b, dtype=np.float32)
-        top_ks = np.zeros(b, dtype=np.int32)
-        for i, s in enumerate(batch):
-            tokens[i] = s.next_token
-            positions[i] = s.pos
-            page_tables[i, :len(s.pages)] = s.pages
-            valid[i] = True
-            seeds[i] = s.seed
-            steps[i] = s.generated
-            temps[i] = s.req.sampling.temperature
-            top_ps[i] = s.req.sampling.top_p
-            top_ks[i] = s.req.sampling.top_k
-        tk = self.TOPK_WIDTH if any(s.wants_topk for s in batch) else 0
+            b = cfg.max_batch_size
+            tokens = np.zeros(b, dtype=np.int32)
+            positions = np.zeros(b, dtype=np.int32)
+            page_tables = np.zeros((b, mcfg.max_pages_per_seq),
+                                   dtype=np.int32)
+            valid = np.zeros(b, dtype=bool)
+            seeds = np.zeros(b, dtype=np.uint32)
+            steps = np.zeros(b, dtype=np.uint32)
+            temps = np.zeros(b, dtype=np.float32)
+            top_ps = np.ones(b, dtype=np.float32)
+            top_ks = np.zeros(b, dtype=np.int32)
+            for i, s in enumerate(batch):
+                tokens[i] = s.next_token
+                positions[i] = s.pos
+                page_tables[i, :len(s.pages)] = s.pages
+                valid[i] = True
+                seeds[i] = s.seed
+                steps[i] = s.generated
+                temps[i] = s.req.sampling.temperature
+                top_ps[i] = s.req.sampling.top_p
+                top_ks[i] = s.req.sampling.top_k
+            tk = self.TOPK_WIDTH if any(s.wants_topk for s in batch) else 0
 
         trk = self.metrics.compile.track(
             "mixed_step", (bp, t_bucket, k_steps, int(aligned), tk))
@@ -1676,10 +1693,11 @@ class TpuEngine:
                     jax.numpy.asarray(steps), jax.numpy.asarray(temps),
                     jax.numpy.asarray(top_ps),
                     jax.numpy.asarray(top_ks),
-                    mcfg, k_steps, aligned, tk)
+                    mcfg, k_steps, aligned, tk,
+                    span_tokens=sum(chunk_lens) + len(batch) * k_steps)
                 # ONE host sync; chunk logits stay on device for the
                 # first-token sampler
-                return np.asarray(packed), ch_logits, kc, vc
+                return self._host_sync(packed), ch_logits, kc, vc
 
         async with self._device_lock:
             packed, ch_logits, self.k_cache, self.v_cache = \
@@ -1751,21 +1769,22 @@ class TpuEngine:
         cfg, mcfg = self.config, self.model_cfg
         n_stages = cfg.pp_mesh.shape["pp"]
         chunk = min(cfg.prefill_chunk, 128)
-        takes = [caps[id(s)] for s in picks]
-        t_pad = _next_pow2(max(max(takes), chunk * n_stages), chunk,
-                           1 << 30)
-        b_pad = _next_pow2(len(picks), 1, cfg.max_batch_size)
-        tokens = np.zeros((b_pad, t_pad), dtype=np.int32)
-        tables = np.zeros((b_pad, mcfg.max_pages_per_seq),
-                          dtype=np.int32)
-        cached = np.zeros(b_pad, dtype=np.int32)
-        seq_lens = np.zeros(b_pad, dtype=np.int32)
-        for i, s in enumerate(picks):
-            off, n = offsets[id(s)], takes[i]
-            tokens[i, :n] = s.prompt[off:off + n]
-            tables[i, :len(s.pages)] = s.pages
-            cached[i] = off
-            seq_lens[i] = off + n
+        with self._span("prefill_prep"):
+            takes = [caps[id(s)] for s in picks]
+            t_pad = _next_pow2(max(max(takes), chunk * n_stages), chunk,
+                               1 << 30)
+            b_pad = _next_pow2(len(picks), 1, cfg.max_batch_size)
+            tokens = np.zeros((b_pad, t_pad), dtype=np.int32)
+            tables = np.zeros((b_pad, mcfg.max_pages_per_seq),
+                              dtype=np.int32)
+            cached = np.zeros(b_pad, dtype=np.int32)
+            seq_lens = np.zeros(b_pad, dtype=np.int32)
+            for i, s in enumerate(picks):
+                off, n = offsets[id(s)], takes[i]
+                tokens[i, :n] = s.prompt[off:off + n]
+                tables[i, :len(s.pages)] = s.pages
+                cached[i] = off
+                seq_lens[i] = off + n
         trk = self.metrics.compile.track("pp_prefill", (b_pad, t_pad))
         led = self.memory_ledger
         if led is not None:
@@ -1775,7 +1794,8 @@ class TpuEngine:
                 trk, pp_prefill_paged,
                 self.params, self.k_cache, self.v_cache,
                 jax.numpy.asarray(tokens), jax.numpy.asarray(tables),
-                cached, seq_lens, mcfg, cfg.pp_mesh, chunk)
+                cached, seq_lens, mcfg, cfg.pp_mesh, chunk,
+                span_tokens=sum(takes))
         self.metrics.prefill_chunk.observe(trk.elapsed_s)
         rec = self.step_recorder
         if rec is not None:
@@ -1848,7 +1868,8 @@ class TpuEngine:
         use_spec = self.draft_params is not None and not self.spec_shrink
         k_steps = (cfg.spec_iters_per_sync * (cfg.spec_gamma + 1)
                    if use_spec else cfg.decode_steps_per_sync)
-        self._prep_decode_lanes(runnable, k_steps)
+        with self._span("decode_prep"):
+            self._prep_decode_lanes(runnable, k_steps)
         if not runnable:
             return False
         b = cfg.max_batch_size
@@ -1884,25 +1905,26 @@ class TpuEngine:
             # exists to create (every path below dispatches a burst)
             self.metrics.decode_steps_during_prefill.inc(k_steps)
         max_pages = mcfg.max_pages_per_seq
-        tokens = np.zeros(b, dtype=np.int32)
-        positions = np.zeros(b, dtype=np.int32)
-        page_tables = np.zeros((b, max_pages), dtype=np.int32)
-        valid = np.zeros(b, dtype=bool)
-        seeds = np.zeros(b, dtype=np.uint32)
-        steps = np.zeros(b, dtype=np.uint32)
-        temps = np.zeros(b, dtype=np.float32)
-        top_ps = np.ones(b, dtype=np.float32)
-        top_ks = np.zeros(b, dtype=np.int32)
-        for i, s in enumerate(batch):
-            tokens[i] = s.next_token
-            positions[i] = s.pos
-            page_tables[i, :len(s.pages)] = s.pages
-            valid[i] = True
-            seeds[i] = s.seed
-            steps[i] = s.generated
-            temps[i] = s.req.sampling.temperature
-            top_ps[i] = s.req.sampling.top_p
-            top_ks[i] = s.req.sampling.top_k
+        with self._span("decode_prep"):
+            tokens = np.zeros(b, dtype=np.int32)
+            positions = np.zeros(b, dtype=np.int32)
+            page_tables = np.zeros((b, max_pages), dtype=np.int32)
+            valid = np.zeros(b, dtype=bool)
+            seeds = np.zeros(b, dtype=np.uint32)
+            steps = np.zeros(b, dtype=np.uint32)
+            temps = np.zeros(b, dtype=np.float32)
+            top_ps = np.ones(b, dtype=np.float32)
+            top_ks = np.zeros(b, dtype=np.int32)
+            for i, s in enumerate(batch):
+                tokens[i] = s.next_token
+                positions[i] = s.pos
+                page_tables[i, :len(s.pages)] = s.pages
+                valid[i] = True
+                seeds[i] = s.seed
+                steps[i] = s.generated
+                temps[i] = s.req.sampling.temperature
+                top_ps[i] = s.req.sampling.top_p
+                top_ks[i] = s.req.sampling.top_k
 
         if use_spec:
             from dynamo_tpu.engine.spec import spec_decode_multi_step
@@ -1962,8 +1984,10 @@ class TpuEngine:
                     jax.numpy.asarray(steps), jax.numpy.asarray(temps),
                     jax.numpy.asarray(top_ps), jax.numpy.asarray(top_ks),
                     mcfg, cfg.draft_model, cfg.spec_gamma,
-                    cfg.spec_iters_per_sync, topk_lp=tk, **gkw)
-                return np.asarray(packed), kc, vc, dk, dv  # ONE host sync
+                    cfg.spec_iters_per_sync, topk_lp=tk,
+                    span_tokens=len(batch) * k_steps, **gkw)
+                # ONE host sync
+                return self._host_sync(packed), kc, vc, dk, dv
 
             async with self._device_lock:
                 with trk:
@@ -2071,8 +2095,9 @@ class TpuEngine:
                     jax.numpy.asarray(steps), jax.numpy.asarray(temps),
                     jax.numpy.asarray(top_ps), jax.numpy.asarray(top_ks),
                     mcfg, cfg.pp_mesh, k_steps,
-                    n_micro=cfg.pp_microbatches, topk_lp=tk, **ckw)
-                return np.asarray(packed), kc, vc     # ONE host sync
+                    n_micro=cfg.pp_microbatches, topk_lp=tk,
+                    span_tokens=len(batch) * k_steps, **ckw)
+                return self._host_sync(packed), kc, vc  # ONE host sync
 
             trk = self.metrics.compile.track(
                 "pp_decode", (b, k_steps, tk, bool(ckw)))
@@ -2112,7 +2137,7 @@ class TpuEngine:
                     jax.numpy.asarray(steps), jax.numpy.asarray(temps),
                     jax.numpy.asarray(top_ps),
                     jax.numpy.asarray(top_ks), mcfg, k_steps,
-                    topk_lp=tk)
+                    topk_lp=tk, span_tokens=len(batch) * k_steps)
 
             trk = self.metrics.compile.track(
                 "decode_burst", (b, k_steps, tk))
@@ -2163,8 +2188,8 @@ class TpuEngine:
                     g_bits, g_next, g_eos_ok, jax.numpy.asarray(g_ids),
                     jax.numpy.asarray(g_states),
                     jax.numpy.asarray(stop_ids), mcfg, k_steps,
-                    topk_lp=tk)
-                return np.asarray(sampled), kc, vc
+                    topk_lp=tk, span_tokens=len(batch) * k_steps)
+                return self._host_sync(sampled), kc, vc
             sampled, kc, vc = self._mesh_dispatch(
                 trk, decode_multi_step,
                 self.params, self.k_cache, self.v_cache,
@@ -2172,8 +2197,9 @@ class TpuEngine:
                 jax.numpy.asarray(page_tables), jax.numpy.asarray(valid),
                 jax.numpy.asarray(seeds), jax.numpy.asarray(steps),
                 jax.numpy.asarray(temps), jax.numpy.asarray(top_ps),
-                jax.numpy.asarray(top_ks), mcfg, k_steps, topk_lp=tk)
-            return np.asarray(sampled), kc, vc            # ONE host sync
+                jax.numpy.asarray(top_ks), mcfg, k_steps, topk_lp=tk,
+                span_tokens=len(batch) * k_steps)
+            return self._host_sync(sampled), kc, vc       # ONE host sync
 
         trk = self.metrics.compile.track(
             "decode_guided" if use_constrained else "decode_burst",
@@ -2196,8 +2222,14 @@ class TpuEngine:
         self._emit_burst(batch, packed, k_steps, tk)
         return True
 
-    def _mesh_dispatch(self, trk, fn, *args, **kwargs):
-        """Mesh-recorder shim around one jitted dispatch. Off
+    def _mesh_dispatch(self, trk, fn, *args, span_tokens: int = 0,
+                       **kwargs):
+        """The one place every jitted dispatch passes through, on the
+        thread that runs it. Armed (DYN_STEP_PROFILE) the call sits under
+        a `dispatch` host span labelled as CompileTracker labels it, with
+        `span_tokens` = the round's real token positions; the sites
+        convert their inputs (`jnp.asarray`) before they get here, so
+        those transfers are outside the span. Mesh-recorder shim too. Off
         (mesh_recorder is None, the default): one attribute check, then
         the call — tokens and scheduler_stats stay byte-identical
         (pinned by tests/test_mesh_recorder.py). Armed: a
@@ -2206,7 +2238,16 @@ class TpuEngine:
         call consumes are never touched — then the dispatch runs and
         its cached collective bytes fold into the per-entry comm
         budget."""
-        with self._mesh_ctx():
+        srec = self.step_recorder
+        # ONE frame and one call site armed or not: the persistent
+        # compile cache's key follows the source lines of the call stack
+        # (PERF.md), and a traced run should find the programs an
+        # untraced one compiled
+        span = _NO_SPAN if srec is None else srec.span(
+            "dispatch", entry=trk.entry,
+            shape="x".join(str(x) for x in trk.shape),
+            tokens=int(span_tokens))
+        with self._mesh_ctx(), span:
             rec = self.mesh_recorder
             if rec is None:
                 return fn(*args, **kwargs)
@@ -2218,6 +2259,19 @@ class TpuEngine:
             rec.record_dispatch(trk.entry, trk.shape,
                                 time.perf_counter() - t0)
             return out
+
+    def _span(self, phase: str):
+        """A host span of the scheduler when the step recorder is armed,
+        else the shared no-op. Never hold one across an `await`."""
+        rec = self.step_recorder
+        return _NO_SPAN if rec is None else rec.span(phase)
+
+    def _host_sync(self, packed) -> np.ndarray:
+        """The np.asarray round trip that ends a dispatch: the honest
+        device wait (`block_until_ready` lies for pallas outputs inside
+        fori_loops). Runs on the dispatch closure's thread."""
+        with self._span("sync"):
+            return np.asarray(packed)
 
     def _mesh_ctx(self):
         """Every jitted step of a mesh engine is called (hence traced)
@@ -2260,24 +2314,25 @@ class TpuEngine:
         BATCHED: one EngineOutput (one queue wakeup, one dict) per lane
         per burst — at b48×K32 the per-token version was 1536 outputs
         per sync and measurably the engine's host bottleneck."""
-        sampled = packed[0].astype(np.int32)     # (K, B)
-        logprobs = packed[1]                     # (K, B)
-        tk_ids = tk_lps = None
-        if tk:
-            tk_ids = packed[2:2 + tk].astype(np.int32)   # (tk, K, B)
-            tk_lps = packed[2 + tk:2 + 2 * tk]
-        for i, s in enumerate(batch):
-            if s.finished or s not in self._running:
-                continue  # whole burst is overshoot for this lane
-            topk_fn = None
-            if tk and s.wants_topk:
-                w = min(s.req.sampling.top_logprobs, tk)
+        with self._span("emit"):
+            sampled = packed[0].astype(np.int32)     # (K, B)
+            logprobs = packed[1]                     # (K, B)
+            tk_ids = tk_lps = None
+            if tk:
+                tk_ids = packed[2:2 + tk].astype(np.int32)   # (tk, K, B)
+                tk_lps = packed[2 + tk:2 + 2 * tk]
+            for i, s in enumerate(batch):
+                if s.finished or s not in self._running:
+                    continue  # whole burst is overshoot for this lane
+                topk_fn = None
+                if tk and s.wants_topk:
+                    w = min(s.req.sampling.top_logprobs, tk)
 
-                def topk_fn(k, _i=i, _w=w):
-                    return _topk_list(tk_ids[:, k, _i], tk_lps[:, k, _i],
-                                      _w)
+                    def topk_fn(k, _i=i, _w=w):
+                        return _topk_list(tk_ids[:, k, _i], tk_lps[:, k, _i],
+                                          _w)
 
-            self._emit_lane(s, sampled[:, i], logprobs[:, i], topk_fn)
+                self._emit_lane(s, sampled[:, i], logprobs[:, i], topk_fn)
 
     def _pp_prefill_all(self, pending: list[_Seq],
                         offsets: dict[int, int]):
@@ -2488,59 +2543,60 @@ class TpuEngine:
         cfg, mcfg = self.config, self.model_cfg
         P = mcfg.page_size
         bmax = cfg.max_batch_size
-        total = sum(chunk_lens) + (bmax if batch else 0)
-        tb = self._ragged_bucket(total)
-        toks = np.zeros(tb, dtype=np.int32)
-        poss = np.zeros(tb, dtype=np.int32)
-        pages = np.zeros(tb, dtype=np.int32)
-        offs = np.zeros(tb, dtype=np.int32)
-        valid = np.zeros(tb, dtype=bool)
-        lanes = np.zeros(tb, dtype=np.int32)
-        # lane-table rows 0..bmax-1 = chunk picks, bmax..2*bmax-1 =
-        # decode lanes; the width is a constant so it never buckets
-        lane_tables = np.zeros((2 * bmax, mcfg.max_pages_per_seq),
-                               dtype=np.int32)
-        ch_rows = np.zeros(bmax, dtype=np.int32)
-        d_rows = np.zeros(bmax, dtype=np.int32)
-        seeds = np.zeros(bmax, dtype=np.uint32)
-        steps = np.zeros(bmax, dtype=np.uint32)
-        temps = np.zeros(bmax, dtype=np.float32)
-        top_ps = np.ones(bmax, dtype=np.float32)
-        top_ks = np.zeros(bmax, dtype=np.int32)
-        r = 0
-        for i, s in enumerate(picks):
-            off, n = offsets[id(s)], chunk_lens[i]
-            seq_pages = np.asarray(s.pages, dtype=np.int32)
-            lane_tables[i, :len(s.pages)] = seq_pages
-            p_arr = np.arange(off, off + n, dtype=np.int32)
-            toks[r:r + n] = tokens_of(s)[off:off + n]
-            poss[r:r + n] = p_arr
-            pages[r:r + n] = seq_pages[p_arr // P]
-            offs[r:r + n] = p_arr % P
-            valid[r:r + n] = True
-            lanes[r:r + n] = i
-            r += n
-            ch_rows[i] = r - 1
-        if batch:
-            # fixed decode block: row r+j is lane j, valid only for the
-            # lanes actually present; d_rows for empty slots point at
-            # their own (masked, zero-output) padding row
-            d_rows[:] = r + np.arange(bmax, dtype=np.int32)
-        for j, s in enumerate(batch):
-            li = bmax + j
-            rj = r + j
-            lane_tables[li, :len(s.pages)] = s.pages
-            toks[rj] = s.next_token
-            poss[rj] = s.pos
-            pages[rj] = s.pages[s.pos // P]
-            offs[rj] = s.pos % P
-            valid[rj] = True
-            lanes[rj] = li
-            seeds[j] = s.seed
-            steps[j] = s.generated
-            temps[j] = s.req.sampling.temperature
-            top_ps[j] = s.req.sampling.top_p
-            top_ks[j] = s.req.sampling.top_k
+        with self._span("prefill_prep"):
+            total = sum(chunk_lens) + (bmax if batch else 0)
+            tb = self._ragged_bucket(total)
+            toks = np.zeros(tb, dtype=np.int32)
+            poss = np.zeros(tb, dtype=np.int32)
+            pages = np.zeros(tb, dtype=np.int32)
+            offs = np.zeros(tb, dtype=np.int32)
+            valid = np.zeros(tb, dtype=bool)
+            lanes = np.zeros(tb, dtype=np.int32)
+            # lane-table rows 0..bmax-1 = chunk picks, bmax..2*bmax-1 =
+            # decode lanes; the width is a constant so it never buckets
+            lane_tables = np.zeros((2 * bmax, mcfg.max_pages_per_seq),
+                                   dtype=np.int32)
+            ch_rows = np.zeros(bmax, dtype=np.int32)
+            d_rows = np.zeros(bmax, dtype=np.int32)
+            seeds = np.zeros(bmax, dtype=np.uint32)
+            steps = np.zeros(bmax, dtype=np.uint32)
+            temps = np.zeros(bmax, dtype=np.float32)
+            top_ps = np.ones(bmax, dtype=np.float32)
+            top_ks = np.zeros(bmax, dtype=np.int32)
+            r = 0
+            for i, s in enumerate(picks):
+                off, n = offsets[id(s)], chunk_lens[i]
+                seq_pages = np.asarray(s.pages, dtype=np.int32)
+                lane_tables[i, :len(s.pages)] = seq_pages
+                p_arr = np.arange(off, off + n, dtype=np.int32)
+                toks[r:r + n] = tokens_of(s)[off:off + n]
+                poss[r:r + n] = p_arr
+                pages[r:r + n] = seq_pages[p_arr // P]
+                offs[r:r + n] = p_arr % P
+                valid[r:r + n] = True
+                lanes[r:r + n] = i
+                r += n
+                ch_rows[i] = r - 1
+            if batch:
+                # fixed decode block: row r+j is lane j, valid only for the
+                # lanes actually present; d_rows for empty slots point at
+                # their own (masked, zero-output) padding row
+                d_rows[:] = r + np.arange(bmax, dtype=np.int32)
+            for j, s in enumerate(batch):
+                li = bmax + j
+                rj = r + j
+                lane_tables[li, :len(s.pages)] = s.pages
+                toks[rj] = s.next_token
+                poss[rj] = s.pos
+                pages[rj] = s.pages[s.pos // P]
+                offs[rj] = s.pos % P
+                valid[rj] = True
+                lanes[rj] = li
+                seeds[j] = s.seed
+                steps[j] = s.generated
+                temps[j] = s.req.sampling.temperature
+                top_ps[j] = s.req.sampling.top_p
+                top_ks[j] = s.req.sampling.top_k
 
         trk = self.metrics.compile.track("ragged_step", (tb, tk))
         led = self.memory_ledger
@@ -2557,10 +2613,11 @@ class TpuEngine:
                 jax.numpy.asarray(ch_rows), jax.numpy.asarray(d_rows),
                 jax.numpy.asarray(seeds), jax.numpy.asarray(steps),
                 jax.numpy.asarray(temps), jax.numpy.asarray(top_ps),
-                jax.numpy.asarray(top_ks), mcfg, tk)
+                jax.numpy.asarray(top_ks), mcfg, tk,
+                span_tokens=sum(chunk_lens) + len(batch))
             # ONE host sync; chunk logits stay on device for the
             # first-token sampler
-            packed = np.asarray(packed)
+            packed = self._host_sync(packed)
         if picks:
             self.metrics.prefill_chunk.observe(trk.elapsed_s)
         rec = self.step_recorder
@@ -2655,28 +2712,29 @@ class TpuEngine:
         # offset: mid-page starts (disagg imports) need the row
         # write path — batching them with aligned lanes would
         # drag everyone onto it
-        aligned_s = [s for s in ready
-                     if offsets[id(s)] % model_cfg.page_size == 0]
-        active = aligned_s or ready
-        aligned = bool(aligned_s)
-        bp = self._prefill_width(len(active))
-        active = active[:bp]
-        chunk_lens = [min(target_len_of(s) - offsets[id(s)],
-                          cfg.prefill_chunk,
-                          caps[id(s)] if caps else cfg.prefill_chunk)
-                      for s in active]
-        t_bucket = self._token_bucket(max(chunk_lens), model_cfg)
-        toks = np.zeros((bp, t_bucket), dtype=np.int32)
-        tables = np.zeros((bp, model_cfg.max_pages_per_seq),
-                          dtype=np.int32)
-        cached = np.zeros(bp, dtype=np.int32)
-        seq_lens = np.zeros(bp, dtype=np.int32)
-        for i, s in enumerate(active):
-            off, n = offsets[id(s)], chunk_lens[i]
-            toks[i, :n] = tokens_of(s)[off:off + n]
-            tables[i, :len(s.pages)] = s.pages
-            cached[i] = off
-            seq_lens[i] = off + n
+        with self._span("prefill_prep"):
+            aligned_s = [s for s in ready
+                         if offsets[id(s)] % model_cfg.page_size == 0]
+            active = aligned_s or ready
+            aligned = bool(aligned_s)
+            bp = self._prefill_width(len(active))
+            active = active[:bp]
+            chunk_lens = [min(target_len_of(s) - offsets[id(s)],
+                              cfg.prefill_chunk,
+                              caps[id(s)] if caps else cfg.prefill_chunk)
+                          for s in active]
+            t_bucket = self._token_bucket(max(chunk_lens), model_cfg)
+            toks = np.zeros((bp, t_bucket), dtype=np.int32)
+            tables = np.zeros((bp, model_cfg.max_pages_per_seq),
+                              dtype=np.int32)
+            cached = np.zeros(bp, dtype=np.int32)
+            seq_lens = np.zeros(bp, dtype=np.int32)
+            for i, s in enumerate(active):
+                off, n = offsets[id(s)], chunk_lens[i]
+                toks[i, :n] = tokens_of(s)[off:off + n]
+                tables[i, :len(s.pages)] = s.pages
+                cached[i] = off
+                seq_lens[i] = off + n
         trk = self.metrics.compile.track(
             "prefill_draft" if (self.draft_params is not None
                                 and params_ is self.draft_params)
@@ -2690,7 +2748,7 @@ class TpuEngine:
                 params_, kc, vc,
                 jax.numpy.asarray(toks), jax.numpy.asarray(tables),
                 jax.numpy.asarray(cached), jax.numpy.asarray(seq_lens),
-                model_cfg, aligned)
+                model_cfg, aligned, span_tokens=sum(chunk_lens))
         self.metrics.prefill_chunk.observe(trk.elapsed_s)
         rec = self.step_recorder
         if rec is not None:
@@ -3016,15 +3074,23 @@ class TpuEngine:
                     break
             if ok:
                 b = cfg.max_batch_size
-                page_tables2 = np.zeros((b, mcfg.max_pages_per_seq),
-                                        dtype=np.int32)
-                for i, s in enumerate(batch):
-                    page_tables2[i, :len(s.pages)] = s.pages
+                with self._span("decode_prep"):
+                    page_tables2 = np.zeros((b, mcfg.max_pages_per_seq),
+                                            dtype=np.int32)
+                    for i, s in enumerate(batch):
+                        page_tables2[i, :len(s.pages)] = s.pages
+                # the speculative burst runs the program of the burst
+                # it follows, under that burst's (entry, shape)
+                trk2 = self.metrics.compile.track(
+                    "decode_burst", (b, k, inf.get("tk", 0)))
 
                 def dispatch2():
+                    # sliced on device while the in-flight burst still
+                    # runs: no device idle at stake, so under no span
                     tokens2 = inf["packed"][0, k - 1].astype(jnp.int32)
-                    with self._mesh_ctx():
-                        return decode_multi_step(
+                    with trk2:
+                        return self._mesh_dispatch(
+                            trk2, decode_multi_step,
                             self.params, self.k_cache, self.v_cache,
                             tokens2,
                             jax.numpy.asarray(inf["positions"] + k),
@@ -3035,7 +3101,8 @@ class TpuEngine:
                             jax.numpy.asarray(inf["temps"]),
                             jax.numpy.asarray(inf["top_ps"]),
                             jax.numpy.asarray(inf["top_ks"]),
-                            mcfg, k, topk_lp=inf.get("tk", 0))
+                            mcfg, k, topk_lp=inf.get("tk", 0),
+                            span_tokens=len(batch) * k)
 
                 rec = self.step_recorder
                 t_d2 = time.perf_counter() if rec is not None else 0.0
@@ -3060,7 +3127,7 @@ class TpuEngine:
                        "tk": inf.get("tk", 0), "deferred": []}
         rec = self.step_recorder
         t_sync = time.perf_counter() if rec is not None else 0.0
-        packed = await asyncio.to_thread(np.asarray, inf["packed"])
+        packed = await asyncio.to_thread(self._host_sync, inf["packed"])
         if rec is not None:
             # the honest device wait for a pipelined burst: np.asarray
             # round-trip, not block_until_ready;

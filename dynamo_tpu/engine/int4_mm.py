@@ -132,6 +132,7 @@ def _w8a8_matmul_jit(x: jax.Array, q: jax.Array, s: jax.Array,
     grid = (m // bm, n // bn, kdim // bk)
     y = pl.pallas_call(
         _w8a8_kernel,
+        name="w8a8_matmul",
         interpret=interpret,
         grid=grid,
         in_specs=[
@@ -196,6 +197,7 @@ def _int4_matmul_jit(x: jax.Array, p: jax.Array, s: jax.Array,
     grid = (m // bm, n2 // bn2, kdim // bk)
     y_p, y_lou = pl.pallas_call(
         _kernel,
+        name="int4_matmul",
         interpret=interpret,
         grid=grid,
         in_specs=[

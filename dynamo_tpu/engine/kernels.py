@@ -100,6 +100,7 @@ def _paged_kv_write(kc, vc, k, v, page_ids, offsets):
         out_shape=[jax.ShapeDtypeStruct(kc.shape, kc.dtype),
                    jax.ShapeDtypeStruct(vc.shape, vc.dtype)],
         input_output_aliases={4: 0, 5: 1},  # kc/vc updated in place
+        name="kv_write_rows",
     )(page_ids.astype(jnp.int32), offsets.astype(jnp.int32), k, v, kc, vc)
     return out_kc, out_vc
 
@@ -155,5 +156,6 @@ def _paged_kv_write_pages(kc, vc, k_blocks, v_blocks, page_ids):
         out_shape=[jax.ShapeDtypeStruct(kc.shape, kc.dtype),
                    jax.ShapeDtypeStruct(vc.shape, vc.dtype)],
         input_output_aliases={3: 0, 4: 1},
+        name="kv_write_pages",
     )(page_ids.astype(jnp.int32), k_blocks, v_blocks, kc, vc)
     return out_kc, out_vc

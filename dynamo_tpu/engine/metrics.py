@@ -135,6 +135,11 @@ class EngineMetrics:
             "dynamo_engine_dispatch_gap_seconds",
             "host gap between consecutive jitted dispatches",
             _GAP_BUCKETS)
+        # Host spans of the scheduler (engine/profiler.py `span`): made
+        # by `arm_host_spans()` when DYN_STEP_PROFILE arms the recorder,
+        # so an unarmed engine's /metrics does not carry their names.
+        self.host_seconds = None
+        self.host_spans = None
         # set once by the worker from TpuEngine.device_report(): value =
         # device count, labels say which platform/kind the engine's
         # arrays sit on and whether the attention kernel path is on
@@ -143,6 +148,17 @@ class EngineMetrics:
             "devices holding this engine's weights and KV cache "
             "(labels: platform, kind, attention_kernels)")
         self.compile = CompileTracker()
+
+    def arm_host_spans(self) -> None:
+        if self.host_seconds is None:
+            self.host_seconds = Counter(
+                "dynamo_engine_host_seconds_total",
+                "scheduler host seconds by phase and kind (sched: the "
+                "scheduler's own host work; device: launching or "
+                "awaiting a dispatch; idle: nothing to run)")
+            self.host_spans = Counter(
+                "dynamo_engine_host_spans_total",
+                "scheduler host spans by phase and kind")
 
     def register(self, registry: MetricsRegistry) -> None:
         """Adopt every metric into a runtime registry so one `/metrics`
@@ -158,6 +174,9 @@ class EngineMetrics:
                   self.goodput_tokens, self.padded_tokens,
                   self.dispatch_gap, self.device_info):
             registry.register(m)
+        if self.host_seconds is not None:
+            registry.register(self.host_seconds)
+            registry.register(self.host_spans)
         # module-owned: the attention impl switch predates any engine,
         # but its fallback attribution belongs on the same scrape
         from dynamo_tpu.engine.attention import attention_fallbacks

@@ -39,9 +39,26 @@ EngineMetrics counters (`dynamo_engine_goodput_tokens_total{entry}`,
 `_sys.stats`, the fleet plane, and bench all read the same attribution.
 
 Consumers: `GET /debug/profile` (ring snapshot + summary as JSON;
-`?capture_s=N` arms a windowed `jax.profiler.trace()`), the
-Chrome-trace-event exporter (`chrome_trace()` — open in Perfetto), and
-`python -m dynamo_tpu.doctor profile`.
+`?capture_s=N` arms a windowed `jax.profiler.trace()`, on the frontend
+for in-process engines and on the worker's system port for the process
+that holds the chip) and `python -m dynamo_tpu.doctor profile`.
+
+**Host spans.** The armed recorder also hands out `span(phase, **attrs)`
+for the scheduler's host work: a `jax.profiler.TraceAnnotation`
+(`engine.<phase>`), so the span lands in the profiler's own trace,
+in the host planes beside the device planes (which sit off the host's
+clock by a constant of about a millisecond a session: a reader measures
+it from the runtime's launch events and their `run_id`,
+benchmarks/chip/lib/host_spans.py),
+and on exit one increment each of
+`dynamo_engine_host_seconds_total{phase,kind}` and
+`dynamo_engine_host_spans_total{phase,kind}`. A span never crosses an
+`await` (the annotation stack is per thread, and across an await the
+loop's thread runs other tasks): `sched` spans wrap synchronous
+stretches, `dispatch`/`sync` live inside the `asyncio.to_thread`
+closures, and the two awaited phases are bracketed by `begin()`/`end()`
+marker annotations (`engine.wait.begin` / `engine.wait.end`) from which
+a trace reader rebuilds the interval.
 """
 
 from __future__ import annotations
@@ -60,6 +77,20 @@ STEP_ENTRIES = (
     "ragged_step", "sample_first", "gather_kv", "write_kv", "burst_sync",
 )
 
+# Host phases of the scheduler, each with its kind: `sched` is the
+# scheduler's own host work (on the event loop's thread, or in a closure
+# thread where a whole prefill round runs off the loop), `device` is spent
+# launching or waiting for the device on the thread that dispatches,
+# `idle` is an awaited phase in which the engine had nothing to run.
+HOST_PHASES = (
+    ("admit", "sched"), ("prefill_prep", "sched"),
+    ("decode_prep", "sched"), ("sample_first", "sched"),
+    ("emit", "sched"), ("publish", "sched"),
+    ("dispatch", "device"), ("sync", "device"),
+    ("wait", "idle"), ("yield", "idle"),
+)
+_PHASE_KIND = dict(HOST_PHASES)
+
 DEFAULT_RING = 2048
 _TRUTHY = {"1", "true", "yes", "on"}
 
@@ -68,6 +99,45 @@ def _shape_label(shape) -> str:
     if isinstance(shape, (tuple, list)):
         return "x".join(str(s) for s in shape)
     return str(shape)
+
+
+_TraceAnnotation = None
+
+
+def _annotation(name: str, **attrs):
+    """A `jax.profiler.TraceAnnotation`; JAX is imported at the first
+    span, so a recorder in a process without a device (the mocker)
+    never pays for it."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        from jax.profiler import TraceAnnotation
+
+        _TraceAnnotation = TraceAnnotation
+    return _TraceAnnotation(name, **attrs)
+
+
+class _Span:
+    """One host span: a TraceAnnotation on the current thread plus the
+    two host counters on exit. Enter and exit on ONE thread, with no
+    `await` in between."""
+
+    __slots__ = ("_rec", "_phase", "_ann", "_t0")
+
+    def __init__(self, rec: "StepRecorder", phase: str, attrs: dict) -> None:
+        self._rec = rec
+        self._phase = phase
+        self._ann = _annotation("engine." + phase, **attrs)
+        self._t0 = 0.0
+
+    def __enter__(self) -> "_Span":
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        elapsed = time.perf_counter() - self._t0
+        self._ann.__exit__(exc_type, exc, tb)
+        self._rec._count_host(self._phase, elapsed)
 
 
 class StepRecorder:
@@ -93,6 +163,34 @@ class StepRecorder:
         self._first_wall = 0.0
         self._last_wall = 0.0
         self._pc_to_wall = time.time() - time.perf_counter()
+        if metrics is not None:
+            metrics.arm_host_spans()
+
+    # -- host spans ----------------------------------------------------------
+
+    def span(self, phase: str, **attrs) -> _Span:
+        """Context manager around a synchronous stretch of host work."""
+        return _Span(self, phase, attrs)
+
+    def begin(self, phase: str) -> float:
+        """Marker before an awaited phase (`wait`, `yield`); returns the
+        clock reading to hand to `end()`."""
+        with _annotation(f"engine.{phase}.begin"):
+            pass
+        return time.perf_counter()
+
+    def end(self, phase: str, t0: float) -> None:
+        elapsed = time.perf_counter() - t0
+        with _annotation(f"engine.{phase}.end"):
+            pass
+        self._count_host(phase, elapsed)
+
+    def _count_host(self, phase: str, seconds: float) -> None:
+        m = self._metrics
+        if m is not None:
+            kind = _PHASE_KIND[phase]
+            m.host_seconds.inc(seconds, phase=phase, kind=kind)
+            m.host_spans.inc(1, phase=phase, kind=kind)
 
     # -- hot path ------------------------------------------------------------
 
@@ -272,58 +370,6 @@ class StepRecorder:
             "dispatch_gap": gap_stats,
         }
 
-    # -- exporters -----------------------------------------------------------
-
-    def chrome_trace(self, extra_events: Optional[list] = None) -> dict:
-        """Ring as Chrome trace-event JSON (Perfetto-compatible): one
-        complete event (`ph: "X"`, ts/dur in microseconds) per step, a
-        lane (tid) per entry so step timelines read like a swimlane,
-        and instant events marking compiles."""
-        return chrome_trace_from_records(self.snapshot(),
-                                         extra_events=extra_events)
-
-
-def chrome_trace_from_records(records: list,
-                              extra_events: Optional[list] = None,
-                              pid: Optional[int] = None) -> dict:
-    """Build the Chrome trace from a ring snapshot. Module-level so
-    `doctor profile --chrome` can export from an offline JSON capture
-    without a live recorder."""
-    pid = os.getpid() if pid is None else pid
-    tids: dict[str, int] = {}
-    events: list[dict] = [{
-        "name": "process_name", "ph": "M", "pid": pid, "tid": 0,
-        "args": {"name": "dynamo_tpu engine steps"},
-    }]
-    for r in records:
-        tid = tids.get(r["entry"])
-        if tid is None:
-            tid = tids[r["entry"]] = len(tids) + 1
-            events.append({"name": "thread_name", "ph": "M",
-                           "pid": pid, "tid": tid,
-                           "args": {"name": r["entry"]}})
-        ts_us = r["at"] * 1e6
-        events.append({
-            "name": f'{r["entry"]} {r["shape"]}',
-            "cat": "step", "ph": "X", "pid": pid, "tid": tid,
-            "ts": ts_us, "dur": max(0.001, r["host_s"] * 1e6),
-            "args": {
-                "shape": r["shape"], "lanes": r["lanes"],
-                "width": r["width"],
-                "good_tokens": r["good_tokens"],
-                "padded_tokens": r["padded_tokens"],
-                "gap_s": r["gap_s"], "synced": r["synced"],
-                "compiled": r["compiled"],
-            },
-        })
-        if r["compiled"]:
-            events.append({"name": "compile", "cat": "compile",
-                           "ph": "i", "s": "t", "pid": pid,
-                           "tid": tid, "ts": ts_us})
-    if extra_events:
-        events.extend(extra_events)
-    return {"traceEvents": events, "displayTimeUnit": "ms"}
-
 
 # -- construction / integration helpers -------------------------------------
 
@@ -394,8 +440,17 @@ def capture_device_profile(seconds: float,
         f"dynamo-profile-{int(time.time())}")
     try:
         import jax
-        with jax.profiler.trace(out):
+
+        # device planes and the runtime's host events (the engine's
+        # spans among them); no Python frames, which would make most
+        # of the file and slow the scheduler's thread
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(out, profiler_options=opts)
+        try:
             time.sleep(seconds)
+        finally:
+            jax.profiler.stop_trace()
     except Exception as exc:  # no jax / profiler unavailable
         return {"captured_s": 0.0, "error": f"{type(exc).__name__}: {exc}"}
     return {"captured_s": seconds, "out_dir": out}

@@ -192,6 +192,7 @@ def ragged_paged_attention(q: jax.Array, k_pages: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
+        name="ragged_paged_attention",
     )(token_lanes.astype(jnp.int32), token_qpos.astype(jnp.int32),
       lane_tables.astype(jnp.int32), q,
       *([k_pages] * ppcb), *([v_pages] * ppcb))

@@ -808,7 +808,7 @@ class HttpService:
             },
             "/debug/profile": {
                 "what": "engine step flight recorder "
-                        "(goodput/padding, ?format=chrome, ?capture_s)",
+                        "(goodput/padding, ?capture_s)",
                 "arm": "DYN_STEP_PROFILE=1",
                 "armed": any(getattr(e, "step_recorder", None)
                              is not None for e in engines or []),
@@ -905,12 +905,13 @@ class HttpService:
     async def _debug_profile(self, request: web.Request) -> web.Response:
         """Step flight-recorder view (docs/observability.md "Step
         profiler"): per-engine ring snapshot + goodput/padding summary.
-        `?limit=N` bounds each ring dump, `?format=chrome` returns a
-        Perfetto-loadable Chrome trace-event JSON instead, and
-        `?capture_s=N` additionally arms a windowed on-demand
-        `jax.profiler.trace()` capture (blocks this request for N
-        seconds, serving continues). 503 when no in-proc engine is
-        wired (frontend-only process — hit the worker's surface)."""
+        `?limit=N` bounds each ring dump and `?capture_s=N`
+        additionally arms a windowed on-demand `jax.profiler` capture
+        (blocks this request for N seconds, serving continues; the
+        engine's host spans land in the same trace). 503 when no
+        in-proc engine is wired (frontend-only process — the worker's
+        system port serves `/debug/profile?capture_s=N` for the
+        process that holds the chip)."""
         if self.profile_engines is None:
             return web.json_response(
                 {"status": "unavailable",
@@ -920,14 +921,6 @@ class HttpService:
                                                 profile_payload)
 
         engines = list(self.profile_engines() or [])
-        if request.query.get("format") == "chrome":
-            events: list = []
-            for eng in engines:
-                rec = getattr(eng, "step_recorder", None)
-                if rec is not None:
-                    events.extend(rec.chrome_trace()["traceEvents"])
-            return web.json_response({"traceEvents": events,
-                                      "displayTimeUnit": "ms"})
         try:
             limit = int(request.query.get("limit", "256"))
         except ValueError:
@@ -1240,8 +1233,8 @@ class HttpService:
             "/debug/requests": ("In-flight + recent request lifecycle "
                                 "timings", False),
             "/debug/profile": ("Step flight-recorder ring + goodput/"
-                               "padding summary (?format=chrome, "
-                               "?capture_s=N)", False),
+                               "padding summary (?capture_s=N)",
+                               False),
             "/debug/router": ("Router decision ring + placement/overlap "
                               "summary per kv-mode model (?limit=N)",
                               False),

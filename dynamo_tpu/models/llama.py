@@ -313,27 +313,32 @@ def paged_forward(params: dict, k_cache: tuple, v_cache: tuple,
     for l in range(cfg.num_layers):
         lp = _layer_params(params, l)
         kc, vc = k_cache[l], v_cache[l]
-        hn = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
-        q, k, v = qkv_proj(hn, lp, cfg)
-        q = q.reshape(Bp, T, cfg.num_heads, cfg.head_dim)
-        k = k.reshape(Bp, T, cfg.num_kv_heads, cfg.head_dim)
-        v = v.reshape(Bp, T, cfg.num_kv_heads, cfg.head_dim)
-        q = rope(q, positions, cfg.rope_theta)
-        k = rope(k, positions, cfg.rope_theta)
-        if page_path:
-            kc, vc = paged_kv_write_pages(
-                kc, vc, to_blocks(k), to_blocks(v), slot_pages)
-        else:
-            kc, vc = _write_kv(kc, vc, flat(k), flat(v), f_pages, f_offs,
-                               f_valid)
-        attn = jax.vmap(
-            lambda q1, pt, pos1, sl: prefill_attention(
-                q1, kc, vc, pt, q_positions=pos1, seq_len=sl,
-                page_size=cfg.page_size)
-        )(q, page_tables, positions, seq_lens)             # (Bp, T, H, D)
-        x = x + qm(attn.reshape(Bp, T, -1), lp["wo"])
-        hn = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
-        x = x + _mlp(hn, lp, cfg)
+        with jax.named_scope("attn_qkv"):
+            hn = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
+            q, k, v = qkv_proj(hn, lp, cfg)
+            q = q.reshape(Bp, T, cfg.num_heads, cfg.head_dim)
+            k = k.reshape(Bp, T, cfg.num_kv_heads, cfg.head_dim)
+            v = v.reshape(Bp, T, cfg.num_kv_heads, cfg.head_dim)
+            q = rope(q, positions, cfg.rope_theta)
+            k = rope(k, positions, cfg.rope_theta)
+        with jax.named_scope("kv_write"):
+            if page_path:
+                kc, vc = paged_kv_write_pages(
+                    kc, vc, to_blocks(k), to_blocks(v), slot_pages)
+            else:
+                kc, vc = _write_kv(kc, vc, flat(k), flat(v), f_pages,
+                                   f_offs, f_valid)
+        with jax.named_scope("attn_core"):
+            attn = jax.vmap(
+                lambda q1, pt, pos1, sl: prefill_attention(
+                    q1, kc, vc, pt, q_positions=pos1, seq_len=sl,
+                    page_size=cfg.page_size)
+            )(q, page_tables, positions, seq_lens)         # (Bp, T, H, D)
+        with jax.named_scope("attn_out"):
+            x = x + qm(attn.reshape(Bp, T, -1), lp["wo"])
+        with jax.named_scope("mlp"):
+            hn = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
+            x = x + _mlp(hn, lp, cfg)
         new_k.append(kc)
         new_v.append(vc)
 
@@ -366,9 +371,10 @@ def prefill_batch(params: dict, k_cache: tuple, v_cache: tuple,
     x, k_cache, v_cache = paged_forward(
         params, k_cache, v_cache, tokens, page_tables, cached_lens,
         seq_lens, cfg, aligned)
-    last = jnp.maximum(seq_lens - cached_lens - 1, 0)      # (Bp,)
-    x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
-    logits = qm(x_last, params["lm_head"])                 # (Bp, V)
+    with jax.named_scope("lm_head"):
+        last = jnp.maximum(seq_lens - cached_lens - 1, 0)  # (Bp,)
+        x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
+        logits = qm(x_last, params["lm_head"])             # (Bp, V)
     return logits.astype(jnp.float32), k_cache, v_cache
 
 
@@ -388,24 +394,30 @@ def _decode_once(params: dict, k_cache: tuple, v_cache: tuple,
     for l in range(cfg.num_layers):
         lp = _layer_params(params, l)
         kc, vc = k_cache[l], v_cache[l]
-        hn = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
-        q, k, v = qkv_proj(hn, lp, cfg)
-        q = q.reshape(B, cfg.num_heads, cfg.head_dim)
-        k = k.reshape(B, cfg.num_kv_heads, cfg.head_dim)
-        v = v.reshape(B, cfg.num_kv_heads, cfg.head_dim)
-        q = rope(q[:, None], positions[:, None], cfg.rope_theta)[:, 0]
-        k = rope(k[:, None], positions[:, None], cfg.rope_theta)[:, 0]
-        kc, vc = _write_kv(kc, vc, k, v, page_ids, offsets, valid)
-        attn = paged_attention_decode(
-            q, kc, vc, lengths, page_tables, page_size=cfg.page_size)
-        x = x + qm(attn.reshape(B, -1), lp["wo"])
-        hn = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
-        x = x + _mlp(hn, lp, cfg)
+        with jax.named_scope("attn_qkv"):
+            hn = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
+            q, k, v = qkv_proj(hn, lp, cfg)
+            q = q.reshape(B, cfg.num_heads, cfg.head_dim)
+            k = k.reshape(B, cfg.num_kv_heads, cfg.head_dim)
+            v = v.reshape(B, cfg.num_kv_heads, cfg.head_dim)
+            q = rope(q[:, None], positions[:, None], cfg.rope_theta)[:, 0]
+            k = rope(k[:, None], positions[:, None], cfg.rope_theta)[:, 0]
+        with jax.named_scope("kv_write"):
+            kc, vc = _write_kv(kc, vc, k, v, page_ids, offsets, valid)
+        with jax.named_scope("attn_core"):
+            attn = paged_attention_decode(
+                q, kc, vc, lengths, page_tables, page_size=cfg.page_size)
+        with jax.named_scope("attn_out"):
+            x = x + qm(attn.reshape(B, -1), lp["wo"])
+        with jax.named_scope("mlp"):
+            hn = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
+            x = x + _mlp(hn, lp, cfg)
         new_k.append(kc)
         new_v.append(vc)
 
-    x = rms_norm(x, params["final_norm"], cfg.rms_eps)
-    logits = qm(x, params["lm_head"])                      # (B, V)
+    with jax.named_scope("lm_head"):
+        x = rms_norm(x, params["final_norm"], cfg.rms_eps)
+        logits = qm(x, params["lm_head"])                  # (B, V)
     return logits.astype(jnp.float32), tuple(new_k), tuple(new_v)
 
 
@@ -457,23 +469,24 @@ def decode_multi_step(params: dict, k_cache: jax.Array, v_cache: jax.Array,
 
     def body(i, carry):
         toks, kc, vc, out = carry
-        logits, kc, vc = _decode_once(
-            params, kc, vc, toks, positions + i, page_tables, valid, cfg)
-        sampled = sample_tokens_traced(
-            logits, seeds, steps0 + i, temperature, top_p, top_k)
-        # chosen-token logprob: one extra (B, V) reduction pass — noise
-        # next to the lm_head matmul that produced the logits
         from dynamo_tpu.engine.sampling import chosen_logprob, topk_logprobs
 
-        chosen = chosen_logprob(logits, sampled)
-        out = out.at[0, i].set(sampled.astype(jnp.float32))
-        out = out.at[1, i].set(chosen)
-        if topk_lp:
-            ids, vals = topk_logprobs(logits, topk_lp)
-            out = lax.dynamic_update_slice(
-                out, ids.T[:, None, :], (2, i, 0))
-            out = lax.dynamic_update_slice(
-                out, vals.T[:, None, :], (2 + topk_lp, i, 0))
+        logits, kc, vc = _decode_once(
+            params, kc, vc, toks, positions + i, page_tables, valid, cfg)
+        with jax.named_scope("sample"):
+            sampled = sample_tokens_traced(
+                logits, seeds, steps0 + i, temperature, top_p, top_k)
+            # chosen-token logprob: one extra (B, V) reduction pass —
+            # noise next to the lm_head matmul that produced the logits
+            chosen = chosen_logprob(logits, sampled)
+            out = out.at[0, i].set(sampled.astype(jnp.float32))
+            out = out.at[1, i].set(chosen)
+            if topk_lp:
+                ids, vals = topk_logprobs(logits, topk_lp)
+                out = lax.dynamic_update_slice(
+                    out, ids.T[:, None, :], (2, i, 0))
+                out = lax.dynamic_update_slice(
+                    out, vals.T[:, None, :], (2 + topk_lp, i, 0))
         return sampled, kc, vc, out
 
     out0 = jnp.zeros((2 + 2 * topk_lp, num_steps, tokens.shape[0]),

@@ -1,4 +1,5 @@
-"""System status HTTP server: /live /health /metrics.
+"""System status HTTP server: /live /health /metrics /config, and
+/debug/profile for a device trace of this process.
 
 Reference: `lib/runtime/src/system_status_server.rs` (axum server on
 DYN_SYSTEM_PORT aggregating health + hierarchical metric registries).
@@ -6,6 +7,7 @@ DYN_SYSTEM_PORT aggregating health + hierarchical metric registries).
 
 from __future__ import annotations
 
+import asyncio
 from typing import TYPE_CHECKING
 
 from aiohttp import web
@@ -28,6 +30,7 @@ class SystemStatusServer:
         app.router.add_get("/health", self._health)
         app.router.add_get("/metrics", self._metrics)
         app.router.add_get("/config", self._config)
+        app.router.add_get("/debug/profile", self._profile)
         self._runner = web.AppRunner(app)
         await self._runner.setup()
         site = web.TCPSite(self._runner, self.host, self.port)
@@ -53,6 +56,26 @@ class SystemStatusServer:
     async def _metrics(self, request: web.Request) -> web.Response:
         return web.Response(text=self.runtime.metrics.render(),
                             content_type="text/plain")
+
+    async def _profile(self, request: web.Request) -> web.Response:
+        """`?capture_s=N[&dir=PATH]`: a windowed `jax.profiler` capture
+        of THIS process — the worker, which holds the chip — with the
+        engine's host spans (DYN_STEP_PROFILE) in the same trace.
+        Blocks the request for N seconds off the loop; serving goes
+        on. The answer names the directory holding the `.xplane.pb`."""
+        cap = request.query.get("capture_s")
+        try:
+            secs = float(cap) if cap is not None else None
+        except ValueError:
+            secs = None
+        if secs is None or secs != secs:
+            return web.json_response(
+                {"error": "capture_s must be a number"}, status=400)
+        from dynamo_tpu.engine.profiler import capture_device_profile
+
+        out = await asyncio.to_thread(
+            capture_device_profile, secs, request.query.get("dir"))
+        return web.json_response(out, status=500 if "error" in out else 200)
 
     async def _config(self, request: web.Request) -> web.Response:
         """Reproducibility dump (common/config_dump analog): effective

@@ -88,6 +88,7 @@ def test_paged_kv_write_kernel(sds, batch):
         paged_kv_write, kc, vc, sds((batch, KVH, D)), sds((batch, KVH, D)),
         sds((batch,), jnp.int32), sds((batch,), jnp.int32))
     assert "tpu_custom_call" in text
+    assert "kv_write_rows" in text      # the name a device trace shows
 
 
 @pytest.mark.parametrize("n_pages", [8, 256])   # 1 x 128 and 32 x 128 tokens
@@ -99,6 +100,7 @@ def test_paged_kv_write_pages_kernel(sds, n_pages):
         paged_kv_write_pages, kc, vc, sds((n_pages, KVH, PAGE, D)),
         sds((n_pages, KVH, PAGE, D)), sds((n_pages,), jnp.int32))
     assert "tpu_custom_call" in text
+    assert "kv_write_pages" in text
 
 
 @pytest.mark.parametrize("rows,lanes", [(8, 4), (64, 32)])
@@ -111,9 +113,19 @@ def test_ragged_paged_attention_kernel(sds, rows, lanes):
         sds((rows,), jnp.int32), sds((rows,), jnp.int32),
         sds((lanes, MAX_PAGES), jnp.int32))
     assert "tpu_custom_call" in text
+    assert "ragged_paged_attention" in text
 
 
 # -- the engine's own jitted steps, 8B widths, depth cut to 2 layers --------
+
+# jax.named_scope blocks of models/llama.py: they reach the compiled
+# program as op_name metadata, which a trace with the HLO proto shows
+LAYER_SCOPES = ("attn_qkv", "kv_write", "attn_core", "attn_out", "mlp",
+                "lm_head")
+
+
+def _missing(text: str, names) -> list[str]:
+    return [n for n in names if f"/{n}/" not in text]
 
 
 @pytest.fixture
@@ -155,8 +167,11 @@ def test_engine_decode_burst_holds_the_kernels(sds, model, pallas_impl):
         sds((b, MAX_PAGES), i32), sds((b,), jnp.bool_), sds((b,), u32),
         sds((b,), u32), sds((b,), f32), sds((b,), f32), sds((b,), i32),
         cfg, 8, topk_lp=0).compile()
+    text = compiled.as_text()
     # per layer: the row KV write and the paged-attention decode
-    assert compiled.as_text().count("tpu_custom_call") >= 2 * cfg.num_layers
+    assert text.count("tpu_custom_call") >= 2 * cfg.num_layers
+    assert text.count("kv_write_rows") >= cfg.num_layers
+    assert not _missing(text, LAYER_SCOPES + ("sample",))
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 1 << 30
 
@@ -169,7 +184,10 @@ def test_engine_prefill_chunk_holds_the_page_write(sds, model, pallas_impl):
     compiled = prefill_batch.lower(
         params, kc, vc, sds((bp, t), i32), sds((bp, MAX_PAGES), i32),
         sds((bp,), i32), sds((bp,), i32), cfg, aligned=True).compile()
-    assert compiled.as_text().count("tpu_custom_call") >= cfg.num_layers
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= cfg.num_layers
+    assert text.count("kv_write_pages") >= cfg.num_layers
+    assert not _missing(text, LAYER_SCOPES)
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
 
 

@@ -1,6 +1,7 @@
 """Step flight recorder (engine/profiler.py): ring semantics, zero-cost
-off path, MockEngine parity, analytic padding math, Chrome export,
-doctor profile rendering, and the /debug/profile surface."""
+off path, MockEngine parity, analytic padding math, doctor profile
+rendering, and the /debug/profile surface (host spans:
+tests/test_host_spans.py)."""
 
 import asyncio
 import json
@@ -9,7 +10,6 @@ import pytest
 
 from dynamo_tpu.engine.profiler import (
     StepRecorder,
-    chrome_trace_from_records,
     profile_payload,
     recorder_from_env,
     step_profile_summary,
@@ -157,38 +157,7 @@ async def test_mock_engine_analytic_padding(monkeypatch):
     assert abs(sp["padded_pct"] - round(expect_pct, 3)) < 1e-9
 
 
-# -- exporters --------------------------------------------------------------
-
-
-@pytest.mark.tier0
-def test_chrome_trace_valid_json():
-    rec = StepRecorder()
-    rec.record("prefill", (8, 512), 0.012, good_tokens=3000,
-               work_tokens=4096, lanes=8, width=8, compiled=True,
-               synced=False)
-    rec.record("decode_burst", (16, 8), 0.004, good_tokens=96,
-               work_tokens=128, lanes=12, width=16, tokens=96)
-    trace = json.loads(json.dumps(rec.chrome_trace()))
-    assert trace["displayTimeUnit"] == "ms"
-    events = trace["traceEvents"]
-    assert events, "no events"
-    phases = {e["ph"] for e in events}
-    assert phases <= {"M", "X", "i"}
-    steps = [e for e in events if e["ph"] == "X"]
-    assert len(steps) == 2
-    for e in steps:
-        assert e["dur"] > 0 and isinstance(e["ts"], float)
-        assert "good_tokens" in e["args"]
-    # one compile instant for the compiled prefill
-    assert sum(1 for e in events if e["ph"] == "i") == 1
-    # swimlane metadata: one thread_name per entry
-    lanes = [e for e in events if e["ph"] == "M"
-             and e["name"] == "thread_name"]
-    assert {e["args"]["name"] for e in lanes} == {"prefill",
-                                                 "decode_burst"}
-    # module-level builder (doctor profile --chrome) agrees
-    offline = chrome_trace_from_records(rec.snapshot(), pid=1)
-    assert len(offline["traceEvents"]) == len(events)
+# -- doctor ------------------------------------------------------------------
 
 
 @pytest.mark.tier0
@@ -207,13 +176,11 @@ def test_doctor_profile_renders(tmp_path, capsys):
     src = tmp_path / "profile.json"
     src.write_text(json.dumps(
         {"enabled": True, "engines": [profile_payload(_E())]}))
-    chrome = tmp_path / "trace.json"
-    assert profile_main([str(src), "--chrome", str(chrome)]) == 0
+    assert profile_main([str(src)]) == 0
     out = capsys.readouterr().out
     assert "goodput" in out
     assert "padding waste by bucket shape" in out
     assert "top compile stalls" in out
-    assert json.loads(chrome.read_text())["traceEvents"]
     # recorder-off payload exits nonzero
     off = tmp_path / "off.json"
     off.write_text(json.dumps(
@@ -342,12 +309,6 @@ async def test_debug_profile_endpoint(monkeypatch):
             summary = data["engines"][0]["summary"]
             assert summary["totals"]["good_tokens"] > 0
             assert data["engines"][0]["records"]
-            # Chrome round-trip straight off the live ring
-            async with s.get(f"{fe.url}/debug/profile?format=chrome") as r:
-                assert r.status == 200
-                trace = await r.json()
-            assert trace["traceEvents"]
-            assert any(e.get("ph") == "X" for e in trace["traceEvents"])
             async with s.get(f"{fe.url}/debug/profile?capture_s=nope") as r:
                 assert r.status == 400
             # openapi advertises the route

@@ -2,10 +2,11 @@
 
 The XLA path is pure lax ops, so it runs on any backend and partitions under
 `jit` + sharding annotations (tensor parallelism over the kv-head axis).
-The pallas path uses the TPU paged-attention kernel
-(`jax.experimental.pallas.ops.tpu.paged_attention`) for decode — the HBM-
-bandwidth-bound hot loop — and is selected automatically on TPU when the
-kv-head axis is not sharded (single-chip or per-shard invocation).
+The pallas path is this module's own decode kernel,
+`paged_decode_attention` (the name a device trace shows): decode is the
+HBM-bandwidth-bound hot loop, and the kernel reads a lane's live pages and
+nothing else, a page of all kv heads per copy. It is selected automatically
+on TPU and runs once per "tp" shard under a tensor-parallel mesh.
 
 Cache layout (both paths): K/V pages per layer are
 ``(num_kv_heads, num_pages, page_size, head_dim)``.
@@ -88,17 +89,13 @@ def _note_fallback(reason: str) -> None:
 
 @functools.lru_cache(maxsize=None)
 def block_choice(max_pages: int, page_size: int) -> int:
-    """Pages per compute block for the paged-attention kernels.
-
-    Measured on v5e (batch 32, ctx 1152): tiny blocks are grid-overhead-
-    bound — pages_per_compute_block=8 ran the fused step at 26 ms vs
-    16 ms at 32 pages/block (and 12 ms with 32-token pages). Bigger
-    blocks also read more padding past each lane's length, which hurts
-    short contexts (b16 ctx128: 6.8 ms at 256-token blocks vs 7.5 ms at
-    512). Target: ~1/4 of max context, at least 256 tokens, snapped to
-    the largest divisor of max_pages (the kernels need the block count
-    to tile the page table exactly). Shared by `_pallas_decode` and
-    `ragged.ragged_paged_attention`; cached — the geometry set is tiny.
+    """Pages per compute block of `ragged.ragged_paged_attention`, whose
+    grid walks every block of the page table: about a quarter of the
+    maximum context, at least 256 tokens, snapped to the largest divisor
+    of max_pages (the block count has to tile the table exactly). Bigger
+    blocks read more padding past each row's position, smaller ones pay
+    more grid steps. The decode kernel sizes its blocks from the operand
+    shapes instead (`decode_geometry`). Cached: the geometry set is tiny.
     """
     want_tokens = max(256, (max_pages * page_size) // 4)
     want = max(1, want_tokens // page_size)
@@ -215,26 +212,233 @@ def _xla_decode(q, k_pages, v_pages, lengths, page_tables):
     return out.astype(q.dtype)
 
 
-@functools.cache
-def _pallas_paged_attention():
-    from jax.experimental.pallas.ops.tpu.paged_attention import (
-        paged_attention as kernel,
-    )
-    return kernel
+# VMEM one K+V block of the decode kernel may take, and how many such
+# blocks are in flight or in use at once. Measured on v5e (PERF.md §6,
+# PR 28); the block's page count follows from the operand shapes. Alone
+# on the chip 2, 3 and 4 slots read alike; 3 keeps two copies in flight
+# where a lane's last block holds one live page and the next lane's
+# first follows it, and is what every serving run was measured with.
+_DECODE_BLOCK_BYTES = 512 * 1024
+_DECODE_SLOTS = 3
+_DECODE_Q_BYTES = 1 << 20
+
+
+@functools.lru_cache(maxsize=None)
+def decode_geometry(batch: int, kvh: int, groups: int, page_size: int,
+                    head_dim: int, itemsize: int) -> tuple[int, int]:
+    """(pages per KV block, lanes per grid step) of `paged_decode_attention`
+    for these operand shapes.
+
+    A block is as many whole pages of all kv heads, K and V, as fit
+    `_DECODE_BLOCK_BYTES`, in multiples of 128 tokens (the scores' lane
+    width) where the page size divides 128: 128 tokens at 8 kv heads, 256
+    at 4, 512 at 2 (a tp=4 shard of Mistral), so a block costs the same
+    bytes and the same vector work whatever the model. Lanes per grid
+    step: the largest divisor of the batch whose q rows, each group
+    padded to a (16, 128) tile in VMEM, stay under `_DECODE_Q_BYTES`.
+    """
+    page_bytes = 2 * kvh * page_size * head_dim * itemsize
+    ppb = max(1, _DECODE_BLOCK_BYTES // page_bytes)
+    unit = max(1, 128 // page_size)
+    ppb = max(unit, ppb // unit * unit)
+    lane_bytes = kvh * (-(-groups // 16) * 16) * head_dim * itemsize
+    lanes = max(c for c in range(1, batch + 1)
+                if batch % c == 0 and (c == 1
+                                       or c * lane_bytes <= _DECODE_Q_BYTES))
+    return ppb, lanes
+
+
+def paged_decode_attention(q, k_pages, v_pages, lengths, page_tables, *,
+                           interpret=False):
+    """The decode attention kernel (signature = `_xla_decode`).
+
+    Reads only what is live: a lane walks `cdiv(length, block tokens)`
+    blocks of its own page table, a padding lane (`length == 0`) none,
+    and a table entry past a lane's last live page is never followed (the
+    slots of a last block that lie beyond it fetch that last page again;
+    their scores are masked). One async copy moves a page of *all* kv
+    heads, `(KVH, P, D)` out of the `(KVH, N, P, D)` cache. The
+    (lane, block) items of a grid step form one queue through
+    `_DECODE_SLOTS` VMEM slots: while an item is computed the next ones
+    are in flight, across lanes too. Per kv head the `groups` q rows meet
+    the block in one `(groups, D) x (D, tokens)` product; scores, softmax
+    state and accumulator are f32. The probabilities enter the second
+    product as bf16 high + low halves in one LHS, so KV in bf16 loses
+    nothing against f32 probabilities (2**-17 relative). Padding lanes
+    return zeros.
+    """
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    kvh, _, p, d = k_pages.shape
+    b, h, _ = q.shape
+    groups = h // kvh
+    max_pages = page_tables.shape[1]
+    kv_dtype = k_pages.dtype
+    ppb, lanes = decode_geometry(b, kvh, groups, p, d, kv_dtype.itemsize)
+    t = ppb * p
+    slots = _DECODE_SLOTS
+    scale = 1.0 / (d ** 0.5)
+    f32 = jnp.float32
+    # q and K meet in their own dtype when it is one (bf16 products are
+    # exact in the f32 accumulator), else in f32
+    qk_dtype = kv_dtype if q.dtype == kv_dtype else f32
+    qk_precision = jax.lax.Precision.HIGHEST if qk_dtype == f32 else None
+    precision = jax.lax.Precision.HIGHEST if kv_dtype == f32 else None
+    split = kv_dtype != f32           # probabilities as high + low halves
+    gp = -(-groups // 8) * 8          # a kv head's q rows, in whole tiles
+
+    def across(x, n):
+        """(rows, 128) state, the same in every column, as (rows, n)."""
+        return x if n == x.shape[1] else jnp.broadcast_to(
+            x[:, :1], (x.shape[0], n))
+
+    def kernel(len_ref, tab_ref, q_ref, k_hbm, v_hbm, o_ref,
+               kbuf, vbuf, ksem, vsem, m_ref, l_ref, acc_ref):
+        lo = pl.program_id(0) * lanes
+        hi = lo + lanes
+
+        def blocks(lane):
+            return pl.cdiv(len_ref[lane], t)
+
+        def live_from(lane):
+            """First lane >= `lane` of this step that holds tokens, or hi."""
+            return jax.lax.while_loop(
+                lambda x: (x < hi) & (len_ref[jnp.minimum(x, hi - 1)] == 0),
+                lambda x: x + 1, lane)
+
+        def advance(lane, blk):
+            more = (lane < hi) & (blk + 1 < blocks(jnp.minimum(lane, hi - 1)))
+            return jax.lax.cond(
+                more, lambda: (lane, blk + 1),
+                lambda: (live_from(jnp.minimum(lane + 1, hi)), jnp.int32(0)))
+
+        def start(lane, blk, slot):
+            """Fetch a block into a slot, a page of all kv heads a copy;
+            nothing once the cursor has left the step's lanes."""
+            @pl.when(lane < hi)
+            def _():
+                last = pl.cdiv(len_ref[lane], p) - 1    # last live page
+                for i in range(ppb):
+                    page = tab_ref[lane * max_pages
+                                   + jnp.minimum(blk * ppb + i, last)]
+                    dst = pl.ds(i * p, p)
+                    pltpu.make_async_copy(k_hbm.at[:, page],
+                                          kbuf.at[slot, :, dst],
+                                          ksem.at[slot]).start()
+                    pltpu.make_async_copy(v_hbm.at[:, page],
+                                          vbuf.at[slot, :, dst],
+                                          vsem.at[slot]).start()
+
+        def item(w, cursors):
+            lane, blk, ahead_lane, ahead_blk = cursors
+            slot = w % slots
+            start(ahead_lane, ahead_blk, (w + slots - 1) % slots)
+            row = lane - lo
+
+            @pl.when(blk == 0)
+            def _():
+                m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+                l_ref[...] = jnp.zeros_like(l_ref)
+                acc_ref[...] = jnp.zeros_like(acc_ref)
+
+            mask = (blk * t + jax.lax.broadcasted_iota(jnp.int32, (1, t), 1)
+                    < len_ref[lane])
+            # one wait a cache for the block's ppb page copies: a wait
+            # counts the bytes of its destination, here the whole slot
+            pltpu.make_async_copy(kbuf.at[slot], kbuf.at[slot],
+                                  ksem.at[slot]).wait()
+            pltpu.make_async_copy(vbuf.at[slot], vbuf.at[slot],
+                                  vsem.at[slot]).wait()
+            # every kv head's product first, then one softmax update over
+            # all q rows: the heads' chains overlap instead of queueing
+            s = jnp.concatenate([jax.lax.dot_general(
+                q_ref[row, g], kbuf[slot, g].astype(qk_dtype),
+                (((1,), (1,)), ((), ())), precision=qk_precision,
+                preferred_element_type=f32) for g in range(kvh)], axis=0)
+            s = jnp.where(mask, s * scale, _NEG_INF)        # (kvh * gp, t)
+            m_prev = m_ref[...]                             # (kvh * gp, 128)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            # a block in the queue holds a live token, so m_new is a real
+            # score and exp() of a masked one is exactly 0
+            pr = jnp.exp(s - across(m_new, t))
+            l_ref[...] = alpha * l_ref[...] + jnp.sum(pr, axis=1,
+                                                      keepdims=True)
+            m_ref[...] = m_new
+            if split:
+                high = pr.astype(kv_dtype).astype(f32)
+                low = pr - high
+            pv = []
+            for g in range(kvh):
+                mine = slice(g * gp, (g + 1) * gp)
+                lhs = (jnp.concatenate([high[mine], low[mine]], axis=0)
+                       if split else pr[mine])
+                out = jax.lax.dot_general(
+                    lhs.astype(kv_dtype), vbuf[slot, g],
+                    (((1,), (0,)), ((), ())), precision=precision,
+                    preferred_element_type=f32)
+                pv.append(out[:gp] + out[gp:] if split else out)
+            acc_ref[...] = (across(alpha, d) * acc_ref[...]
+                            + jnp.concatenate(pv, axis=0))
+
+            @pl.when(blk == blocks(lane) - 1)
+            def _():
+                out = acc_ref[...] / across(l_ref[...], d)
+                for g in range(kvh):
+                    o_ref[row, g] = out[g * gp:(g + 1) * gp].astype(
+                        o_ref.dtype)
+
+            return (*advance(lane, blk), *advance(ahead_lane, ahead_blk))
+
+        o_ref[...] = jnp.zeros_like(o_ref)
+        first = live_from(lo)
+        ahead = (first, jnp.int32(0))
+        for slot in range(slots - 1):
+            start(*ahead, slot)
+            ahead = advance(*ahead)
+        n_items = jax.lax.fori_loop(lo, hi, lambda i, n: n + blocks(i),
+                                    jnp.int32(0))
+        jax.lax.fori_loop(0, n_items, item, (first, jnp.int32(0), *ahead))
+
+    q_block = pl.BlockSpec((lanes, kvh, gp, d),
+                           lambda c, lens, tabs: (c, 0, 0, 0))
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b // lanes,),
+            in_specs=[q_block, pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=q_block,
+            scratch_shapes=[
+                pltpu.VMEM((slots, kvh, t, d), kv_dtype),
+                pltpu.VMEM((slots, kvh, t, d), kv_dtype),
+                pltpu.SemaphoreType.DMA((slots,)),
+                pltpu.SemaphoreType.DMA((slots,)),
+                pltpu.VMEM((kvh * gp, 128), f32),          # m
+                pltpu.VMEM((kvh * gp, 128), f32),          # l
+                pltpu.VMEM((kvh * gp, d), f32),            # acc
+            ]),
+        out_shape=jax.ShapeDtypeStruct((b, kvh, gp, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="paged_decode_attention",
+    )(lengths.astype(jnp.int32), page_tables.astype(jnp.int32).reshape(-1),
+      jnp.pad(q.astype(qk_dtype).reshape(b, kvh, groups, d),
+              ((0, 0), (0, 0), (0, gp - groups), (0, 0))), k_pages, v_pages)
+    return out[:, :, :groups].reshape(b, h, d)
 
 
 def _pallas_decode(q, k_pages, v_pages, lengths, page_tables):
     from dynamo_tpu.engine.kernels import (KV_SPEC, REP_SPEC, ROW_SPEC,
                                            per_tp_shard)
 
-    kernel = functools.partial(
-        _pallas_paged_attention(),
-        pages_per_compute_block=block_choice(page_tables.shape[1],
-                                             k_pages.shape[2]))
     return per_tp_shard(
-        kernel, (ROW_SPEC, KV_SPEC, KV_SPEC, REP_SPEC, REP_SPEC),
-        ROW_SPEC)(q, k_pages, v_pages, lengths.astype(jnp.int32),
-                  page_tables.astype(jnp.int32))
+        paged_decode_attention,
+        (ROW_SPEC, KV_SPEC, KV_SPEC, REP_SPEC, REP_SPEC),
+        ROW_SPEC)(q, k_pages, v_pages, lengths, page_tables)
 
 
 def ragged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,

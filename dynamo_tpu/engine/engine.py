@@ -1338,18 +1338,32 @@ class TpuEngine:
     async def _prefill_pending(self) -> bool:
         """Prefill every admitted-but-unprefilled sequence with BATCHED
         chunk rounds (prefill_batch): each round streams the weights once
-        for all pending sequences, then all first tokens are sampled in one
-        device call + ONE host sync."""
+        for the sequences in it. Every round is launched before any is
+        waited for. A sequence's first token is sampled behind the round
+        that ends ITS prompt, not behind the batch's last round, and
+        prompts of more than one chunk take their rounds one after the
+        other (_chunk_rounds), so a prompt waits for those ahead of it
+        and not for those behind; sequences that end in one round share
+        one device call + ONE host sync, so a batch of one-chunk prompts
+        is one round and one group."""
         pending = [s for s in self._running if not s.prefilled]
         if not pending:
             return False
         mcfg, cfg = self.model_cfg, self.config
+        # (sequences, sampled on the device, tk) in launch order; the
+        # draft replay and the pp path need the whole batch first
+        firsts: list[tuple[list[_Seq], Any, int]] = []
+        by_sequence = self.draft_params is None and cfg.pp_mesh is None
 
-        def run_chunks(params_, model_cfg, kc, vc, offsets):
+        def sample_early(done):
+            group = [s for s in pending if id(s) in done]
+            firsts.append((group, *self._first_token_dispatch(group, done)))
+
+        def run_chunks(params_, model_cfg, kc, vc, offsets, on_done=None):
             return self._chunk_rounds(
                 params_, model_cfg, kc, vc, pending, offsets,
                 tokens_of=lambda s: s.prompt,
-                target_len_of=lambda s: len(s.prompt))
+                target_len_of=lambda s: len(s.prompt), on_done=on_done)
 
         def prefill_all():
             for seq in pending:
@@ -1367,7 +1381,7 @@ class TpuEngine:
             else:
                 self.k_cache, self.v_cache, last_logits = run_chunks(
                     self.params, mcfg, self.k_cache, self.v_cache,
-                    offsets)
+                    offsets, sample_early if by_sequence else None)
             if self.draft_params is not None:
                 # the draft's paged cache must hold the prompt KV too —
                 # over the FULL prompt, never trusting the cached prefix:
@@ -1380,22 +1394,35 @@ class TpuEngine:
                 self.dk_cache, self.dv_cache, _ = run_chunks(
                     self.draft_params, self.config.draft_model,
                     self.dk_cache, self.dv_cache, d_offsets)
-            return self._first_token_packed(pending, last_logits)
+            early = {id(s) for group, _, _ in firsts for s in group}
+            rest = [s for s in pending if id(s) not in early]
+            firsts.append(
+                (rest, *self._first_token_dispatch(rest, last_logits)))
 
         self.metrics.prefill_new_tokens.inc(sum(
             max(len(s.prompt) - s.cached_len, 0) for s in pending))
         async with self._device_lock:
-            packed, tk = await asyncio.to_thread(prefill_all)
-        self._emit_first_tokens(pending, packed, tk, draft_done=True)
+            await asyncio.to_thread(prefill_all)
+            for group, sampled, tk in firsts:
+                # ONE host sync a group; later rounds are still running
+                packed = await asyncio.to_thread(self._host_sync, sampled)
+                self._emit_first_tokens(group, packed, tk, draft_done=True)
         return True
 
     def _first_token_packed(self, pending: list[_Seq], last_logits):
-        """Sample every just-prefilled sequence's FIRST token in one
-        device call + ONE host sync: pad the last-token logits to the
+        """_first_token_dispatch + ONE host sync. Returns (packed np
+        (2 + 2*tk, width), tk). Device-blocking — call under the device
+        lock, in a thread."""
+        sampled, tk = self._first_token_dispatch(pending, last_logits)
+        return self._host_sync(sampled), tk
+
+    def _first_token_dispatch(self, pending: list[_Seq], last_logits):
+        """Launch the sampling of every just-prefilled sequence's FIRST
+        token in one device call: pad the last-token logits to the
         fixed max_batch_size width (so sampling compiles exactly once),
         overlay grammar masks and penalties, run sample_tokens_lp.
-        Returns (packed np (2 + 2*tk, width), tk). Device-blocking —
-        call under the device lock, in a thread. Shared by the legacy
+        Returns (sampled on the device (2 + 2*tk, width), tk) without
+        waiting. Call under the device lock, in a thread. Shared by the
         all-at-once prefill and the budgeted scheduler's completions so
         first-token semantics can never diverge."""
         cfg, mcfg = self.config, self.model_cfg
@@ -1455,14 +1482,14 @@ class TpuEngine:
             sampled = self._mesh_dispatch(
                 trk, sample_tokens_lp, logits_stack, *lane_arrays, topk_lp=tk,
                 span_tokens=len(pending))
-            out = self._host_sync(sampled)            # ONE host sync
         rec = self.step_recorder
         if rec is not None:
             rec.record("sample_first", trk.shape, trk.elapsed_s,
                        good_tokens=len(pending), work_tokens=width,
                        lanes=len(pending), width=width,
-                       tokens=len(pending), compiled=trk.compiled)
-        return out, tk
+                       tokens=len(pending), compiled=trk.compiled,
+                       synced=False)
+        return sampled, tk
 
     def _emit_first_tokens(self, pending: list[_Seq], packed: np.ndarray,
                            tk: int, draft_done: bool) -> None:
@@ -2441,22 +2468,39 @@ class TpuEngine:
             offsets[id(s)] = t_sp
 
     def _chunk_rounds(self, params_, model_cfg, kc, vc, seqs, offsets,
-                      tokens_of, target_len_of):
+                      tokens_of, target_len_of, on_done=None):
         """Batched prefill chunk rounds over `seqs` until every seq's
         offset reaches target_len_of(s). tokens_of(s) supplies the token
         list offsets index into. Returns (kc, vc, final-round logits per
         seq id). Shared by prompt prefill (target AND draft) and the
         draft catch-up replay, so bucketing/compile shapes can't diverge
-        between them."""
+        between them.
+
+        on_done({id(s): logits}), when given, is called behind a round
+        that ended some sequences while others have rounds to go (the
+        last round's are the caller's), and the rounds then go by
+        sequence: sequences in their last chunk share a round, ahead of
+        the rest; a sequence with more chunks to go takes its rounds
+        alone, in the order of `seqs`. A full chunk fills the MXU, so a
+        round of two such sequences takes twice as long and each would
+        wait for the sum of both prompts (PERF.md §6, PR 28)."""
         last_logits: dict[int, Any] = {}
+        chunk = self.config.prefill_chunk
         while True:
             ready = [s for s in seqs if offsets[id(s)] < target_len_of(s)]
             if not ready:
                 break
+            if on_done is not None:
+                ending = [s for s in ready
+                          if target_len_of(s) - offsets[id(s)] <= chunk]
+                ready = ending or ready[:1]
             kc, vc, done, _ = self._chunk_round_once(
                 params_, model_cfg, kc, vc, ready, offsets, tokens_of,
                 target_len_of)
             last_logits.update(done)
+            if on_done is not None and done and any(
+                    offsets[id(s)] < target_len_of(s) for s in seqs):
+                on_done(done)
         return kc, vc, last_logits
 
     def _prefill_width(self, n: int) -> int:

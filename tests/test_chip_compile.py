@@ -77,6 +77,25 @@ def test_paged_attention_decode_kernel(sds, batch):
         _pallas_decode, sds((batch, H, D)), kc, vc,
         sds((batch,), jnp.int32), sds((batch, MAX_PAGES), jnp.int32))
     assert "tpu_custom_call" in text
+    assert "paged_decode_attention" in text   # the name a device trace shows
+
+
+@pytest.mark.parametrize("heads,kv_heads,max_pages,batch", [
+    (28, 4, 288, 8),      # Qwen2.5-7B: 7 q heads to a kv head, 4608 tokens
+    (8, 2, 128, 32),      # a tp=4 shard of Mistral-7B / Llama-3-8B
+    (7, 1, 288, 8),       # a tp=4 shard of Qwen2.5-7B
+    (10, 2, 128, 8),      # a tp=4 shard of Qwen2.5-14B: 5 to a kv head
+    (32, 8, 128, 64),     # batch 64: two grid steps of 32 lanes
+], ids=["qwen2.5-7b", "tp4-8kv", "tp4-qwen7b", "tp4-qwen14b", "two-steps"])
+def test_paged_decode_attention_geometries(sds, heads, kv_heads, max_pages,
+                                           batch):
+    from dynamo_tpu.engine.attention import paged_decode_attention
+
+    cache = sds((kv_heads, PAGES, PAGE, D))
+    text = _compiled_text(
+        paged_decode_attention, sds((batch, heads, D)), cache, cache,
+        sds((batch,), jnp.int32), sds((batch, max_pages), jnp.int32))
+    assert "paged_decode_attention" in text
 
 
 @pytest.mark.parametrize("batch", [4, 32])
@@ -171,6 +190,7 @@ def test_engine_decode_burst_holds_the_kernels(sds, model, pallas_impl):
     # per layer: the row KV write and the paged-attention decode
     assert text.count("tpu_custom_call") >= 2 * cfg.num_layers
     assert text.count("kv_write_rows") >= cfg.num_layers
+    assert text.count("paged_decode_attention") >= cfg.num_layers
     assert not _missing(text, LAYER_SCOPES + ("sample",))
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 1 << 30
@@ -235,4 +255,5 @@ def test_tensor_parallel_decode_burst_splits_the_kernels(
             sds((b,), u32), sds((b,), f32), sds((b,), f32), sds((b,), i32),
             cfg, 8, topk_lp=0).compile().as_text()
     assert text.count("tpu_custom_call") >= 2 * cfg.num_layers
+    assert text.count("paged_decode_attention") >= cfg.num_layers
     assert "all-reduce" in text

@@ -163,3 +163,90 @@ async def test_partial_prefill_excluded_from_decode():
         assert not eng._running and not eng._waiting
     finally:
         await eng.close()
+
+
+async def _prefill_order(eng, prompts, max_tokens=3):
+    """Serve `prompts` as one admission batch; returns (tokens per
+    prompt, host-side order of launches: ("round", n sequences) and
+    ("first", prompt lengths sampled))."""
+    order = []
+    round_once, first = eng._chunk_round_once, eng._first_token_dispatch
+
+    def spy_round(params_, model_cfg, kc, vc, ready, *a, **kw):
+        order.append(("round", len(ready)))
+        return round_once(params_, model_cfg, kc, vc, ready, *a, **kw)
+
+    def spy_first(pending, last_logits):
+        order.append(("first", sorted(len(s.prompt) for s in pending)))
+        return first(pending, last_logits)
+
+    eng._chunk_round_once, eng._first_token_dispatch = spy_round, spy_first
+    tasks = [asyncio.create_task(run(eng, req(p, max_tokens=max_tokens)))
+             for p in prompts]
+    outs = [await t for t in tasks]
+    return [[t for o in out for t in o.get("token_ids", ())]
+            for out in outs], order
+
+
+@pytest.mark.parametrize("lengths, want", [
+    # a one-chunk prompt goes first; the long one then takes its rounds
+    # alone and neither waits for the other's
+    ((8, 40), [("round", 1), ("first", [8]), ("round", 1), ("round", 1),
+               ("round", 1), ("first", [40])]),
+    # prompts of several chunks: in arrival order, one after the other
+    ((40, 8, 24), [("round", 1), ("first", [8]), ("round", 1), ("round", 1),
+                   ("round", 1), ("first", [40]), ("round", 1),
+                   ("round", 1), ("first", [24])]),
+    # prompts in their last chunk share a round, one group, one sync
+    ((8, 12), [("round", 2), ("first", [8, 12])]),
+    ((24, 40, 8), [("round", 1), ("first", [8]), ("round", 1), ("round", 1),
+                   ("first", [24]), ("round", 1), ("round", 1),
+                   ("round", 1), ("first", [40])]),
+    ((40,), [("round", 1), ("round", 1), ("round", 1), ("first", [40])]),
+])
+async def test_first_token_follows_the_sequences_own_last_round(
+        lengths, want):
+    prompts = [list(range(1 + i, 1 + i + n)) for i, n in enumerate(lengths)]
+    eng = make_engine(prefill_chunk=16)
+    try:
+        toks, order = await _prefill_order(eng, prompts)
+        assert order == want
+        assert not eng._running and not eng._waiting
+    finally:
+        await eng.close()
+    # the grouping changes when a first token leaves, never which
+    for p, got in zip(prompts, toks):
+        alone = make_engine(prefill_chunk=16)
+        try:
+            outs = await run(alone, req(p, max_tokens=3))
+        finally:
+            await alone.close()
+        assert got == [t for o in outs for t in o.get("token_ids", ())]
+
+
+async def test_early_first_token_reaches_the_caller_before_the_batch_ends():
+    # the short prompt's frame is on its stream before the long prompt's
+    # first token is waited for
+    eng = make_engine(prefill_chunk=16)
+    events, order = [], []
+    host_sync, emit = eng._host_sync, eng._emit_first_tokens
+
+    def spy_sync(packed):
+        order.append("sync")
+        return host_sync(packed)
+
+    def spy_emit(pending, *a, **kw):
+        order.append(("emit", [len(s.prompt) for s in pending]))
+        return emit(pending, *a, **kw)
+
+    eng._host_sync, eng._emit_first_tokens = spy_sync, spy_emit
+    try:
+        long_ = asyncio.create_task(_consume(
+            eng, req(range(1, 41), max_tokens=1), "long", events))
+        short = asyncio.create_task(_consume(
+            eng, req(range(2, 10), max_tokens=1), "short", events))
+        await long_, await short
+        assert [lab for lab, _, _ in events] == ["short", "long"]
+        assert order[:4] == ["sync", ("emit", [8]), "sync", ("emit", [40])]
+    finally:
+        await eng.close()

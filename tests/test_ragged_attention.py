@@ -110,8 +110,8 @@ def test_ragged_supported_geometry():
 
 
 def test_block_choice_pins_measured_v5e_points():
-    # measured on v5e (see attention.block_choice docstring): 36 pages of
-    # 32 tokens -> 9 pages/block; 32 pages of 16 tokens -> 16
+    # a quarter of the context, at least 256 tokens, a divisor of the
+    # table: 36 pages of 32 tokens -> 9 pages/block; 32 of 16 -> 16
     assert block_choice(36, 32) == 9
     assert block_choice(32, 16) == 16
 

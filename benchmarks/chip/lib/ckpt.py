@@ -27,49 +27,40 @@ import shutil
 
 import numpy as np
 
+from lib import family
+
 # keys of a configuration file that belong to the benchmark, not to the
 # model's config.json
-BENCH_KEYS = ("source", "assumed", "deployment", "weights", "rehearsal")
+BENCH_KEYS = ("source", "assumed", "deployment", "weights", "rehearsal",
+              "family")
 SHARD_BYTES = 1 << 30
 WRITER_THREADS = 6
 WORD = "t{:06d}"
+BASE_KINDS = ("norm", "dense", "head")      # fills every family has
 
 
 def hf_config(config: dict) -> dict:
     return {k: v for k, v in config.items() if k not in BENCH_KEYS}
 
 
-def tensor_specs(hf: dict) -> list[tuple[str, tuple, str]]:
-    """(name, shape, kind) of every tensor of a llama-family checkpoint;
-    kind is `norm`, `head` or `dense`."""
-    hidden, inter = hf["hidden_size"], hf["intermediate_size"]
-    heads = hf["num_attention_heads"]
-    kv_heads = hf.get("num_key_value_heads", heads)
-    head_dim = hf.get("head_dim") or hidden // heads
-    vocab = hf["vocab_size"]
-    arch = (hf.get("architectures") or [""])[0].lower()
-    biased = bool(hf.get("attention_bias", "qwen2" in arch))
-    out = [("model.embed_tokens.weight", (vocab, hidden), "dense")]
-    for i in range(hf["num_hidden_layers"]):
-        p = f"model.layers.{i}."
-        out.append((p + "input_layernorm.weight", (hidden,), "norm"))
-        for proj, rows in (("q", heads), ("k", kv_heads), ("v", kv_heads)):
-            out.append((p + f"self_attn.{proj}_proj.weight",
-                        (rows * head_dim, hidden), "dense"))
-            if biased:
-                out.append((p + f"self_attn.{proj}_proj.bias",
-                            (rows * head_dim,), "dense"))
-        out.append((p + "self_attn.o_proj.weight",
-                    (hidden, heads * head_dim), "dense"))
-        out.append((p + "post_attention_layernorm.weight", (hidden,),
-                    "norm"))
-        out.append((p + "mlp.gate_proj.weight", (inter, hidden), "dense"))
-        out.append((p + "mlp.up_proj.weight", (inter, hidden), "dense"))
-        out.append((p + "mlp.down_proj.weight", (hidden, inter), "dense"))
-    out.append(("model.norm.weight", (hidden,), "norm"))
-    if not hf.get("tie_word_embeddings"):
-        out.append(("lm_head.weight", (vocab, hidden), "head"))
-    return out
+def fills_of(fam, hf: dict, head_gain: float) -> dict:
+    """kind -> how a tensor of that kind is filled. Every family has `norm`
+    (ones), `dense` (noise) and `head` (noise x `head_gain`); a family adds
+    kinds of its own by returning them from `fills(hf)`, as data:
+    `{"fill": "zeros"}` (a router's bias), `{"fill": "noise", "gain": g,
+    "fan_in": n}` (noise of rms 0.4 / sqrt(n) x g; `fan_in` absent means
+    `hidden_size`, right for a projection that reads the residual stream
+    and wrong for one that reads a narrower latent)."""
+    fills = {"norm": {"fill": "ones"}, "dense": {"fill": "noise"},
+             "head": {"fill": "noise", "gain": head_gain}}
+    own = getattr(fam, "fills", None)
+    for kind, fill in (own(hf) if own else {}).items():
+        if kind in fills:
+            raise ValueError(f"fill {kind!r} is the benchmark's own")
+        if fill.get("fill") not in ("ones", "zeros", "noise"):
+            raise ValueError(f"fill {kind!r}: unknown {fill.get('fill')!r}")
+        fills[kind] = fill
+    return fills
 
 
 def _noise_bf16(rng: np.random.Generator, shape: tuple, scale: float):
@@ -100,18 +91,22 @@ def _plan_shards(specs: list) -> list[list]:
 
 
 def _write_shard(path: str, index: int, specs: list, seed: int,
-                 scale: float, head_gain: float) -> tuple[str, list, int]:
+                 hidden: int, fills: dict) -> tuple[str, list, int]:
     import ml_dtypes
     from safetensors.numpy import save_file
 
     rng = np.random.default_rng([seed, index])
     tensors = {}
     for name, shape, kind in specs:
-        if kind == "norm":
-            tensors[name] = np.ones(shape, dtype=ml_dtypes.bfloat16)
+        fill = fills[kind]
+        if fill["fill"] != "noise":
+            make = np.ones if fill["fill"] == "ones" else np.zeros
+            tensors[name] = make(shape, dtype=ml_dtypes.bfloat16)
         else:
+            # layer outputs stay O(1): rms 0.4 / sqrt(fan-in)
+            scale = 0.4 / np.sqrt(fill.get("fan_in") or hidden)
             tensors[name] = _noise_bf16(
-                rng, shape, scale * (head_gain if kind == "head" else 1.0))
+                rng, shape, scale * float(fill.get("gain", 1.0)))
     fname = f"model-{index:05d}.safetensors"
     save_file(tensors, os.path.join(path, fname))
     return fname, list(tensors), sum(t.nbytes for t in tensors.values())
@@ -135,15 +130,34 @@ def write_tokenizer(path: str, vocab_size: int) -> None:
                    "model_max_length": 1 << 20}, f)
 
 
+def head_gain_of(config: dict) -> float:
+    return float((config.get("weights") or {}).get("head_gain", 16.0))
+
+
+def _plan(config: dict) -> tuple:
+    """(model keys, family module, fills by kind) of a configuration."""
+    hf = hf_config(config)
+    fam = family.load("families", config)
+    return hf, fam, fills_of(fam, hf, head_gain_of(config))
+
+
+def marker_of(config: dict, seed: int) -> str:
+    """What a checkpoint directory holds: the model's keys, the seed, the
+    head's gain and, where the family brings fills of its own, those."""
+    hf, _, fills = _plan(config)
+    own = {k: v for k, v in fills.items() if k not in BASE_KINDS}
+    what = [hf, seed, head_gain_of(config), "v1"] + ([own] if own else [])
+    return hashlib.sha256(json.dumps(what, sort_keys=True).encode()
+                          ).hexdigest()
+
+
 def write_checkpoint(path: str, config: dict, seed: int) -> bool:
     """config.json + sharded safetensors + index + tokenizer under `path`.
     A directory whose marker matches (configuration, seed) is reused;
     anything else there is replaced, so one directory never holds more
     than one checkpoint. Returns True when it wrote."""
-    hf = hf_config(config)
-    head_gain = float((config.get("weights") or {}).get("head_gain", 16.0))
-    want = hashlib.sha256(json.dumps(
-        [hf, seed, head_gain, "v1"], sort_keys=True).encode()).hexdigest()
+    want = marker_of(config, seed)
+    hf, fam, fills = _plan(config)
     marker = os.path.join(path, ".bench_ckpt")
     try:
         with open(marker) as f:
@@ -156,12 +170,12 @@ def write_checkpoint(path: str, config: dict, seed: int) -> bool:
     with open(os.path.join(path, "config.json"), "w") as f:
         json.dump(hf, f, indent=1)
     write_tokenizer(path, hf["vocab_size"])
-    scale = 0.4 / np.sqrt(hf["hidden_size"])      # layer outputs stay O(1)
-    shards = _plan_shards(tensor_specs(hf))
+    shards = _plan_shards(fam.tensor_specs(hf))
     weight_map, total = {}, 0
     with concurrent.futures.ThreadPoolExecutor(WRITER_THREADS) as pool:
-        futs = [pool.submit(_write_shard, path, i, specs, seed, scale,
-                            head_gain) for i, specs in enumerate(shards)]
+        futs = [pool.submit(_write_shard, path, i, specs, seed,
+                            hf["hidden_size"], fills)
+                for i, specs in enumerate(shards)]
         for fut in futs:
             fname, names, nbytes = fut.result()
             weight_map.update(dict.fromkeys(names, fname))

@@ -32,7 +32,9 @@ class Result:
     finish: str | None = None
     error: str | None = None
     done: float = 0.0           # stream ended (or failed)
+    prompt: str = ""
     text: list = field(default_factory=list)
+    logprobs: list = field(default_factory=list)   # asked for: the probe
 
     @property
     def tokens(self) -> int:
@@ -65,13 +67,21 @@ class Client:
         await self.session.close()
 
     async def complete(self, phase: str, prompt: str, prompt_tokens: int,
-                       max_tokens: int, due: float,
-                       keep_text: bool = False) -> Result:
+                       max_tokens: int, due: float, logprobs: bool = False,
+                       sampling: dict | None = None) -> Result:
         """One streamed completion. Never raises for a failed request: the
-        failure is the result."""
-        res = Result(phase, prompt_tokens, max_tokens, due)
+        failure is the result. The prompt and the frames' text are kept
+        (references, no copies): the comparison with the reference draws
+        its sample from them once the window has closed. `logprobs` asks
+        for the chosen token's log-probability (`"logprobs": 0`: no
+        alternatives, so the programs are the window's own); `sampling`
+        overrides the mix's sampling for this request."""
+        res = Result(phase, prompt_tokens, max_tokens, due, prompt=prompt)
         self.results.append(res)
-        body = dict(self.body, prompt=prompt, max_tokens=max_tokens)
+        body = dict(self.body, prompt=prompt, max_tokens=max_tokens,
+                    **(sampling or {}))
+        if logprobs:
+            body["logprobs"] = 0
         res.sent = time.perf_counter()
         try:
             async with self.session.post(self.url, json=body) as resp:
@@ -94,8 +104,10 @@ class Client:
                         n = len(text.split())
                         if n:
                             res.frames.append((now, n))
-                            if keep_text:
-                                res.text.append(text)
+                            res.text.append(text)
+                        if logprobs:
+                            res.logprobs += (ch.get("logprobs") or {}).get(
+                                "token_logprobs") or []
                         res.finish = ch.get("finish_reason") or res.finish
         except asyncio.CancelledError:
             res.error = "cancelled"      # cut by the end of the window

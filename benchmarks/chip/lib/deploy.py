@@ -23,6 +23,7 @@ MODEL_NAME = "bench"
 LOAD_TIMEOUT_S = 900.0
 HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(os.path.dirname(HERE))       # the checkout
+WORKER_LAUNCH = os.path.join(HERE, "lib", "worker_launch.py")
 
 
 class BenchFailure(Exception):
@@ -167,8 +168,7 @@ class Deployment:
         self._start("coordinator", py + [
             "dynamo_tpu.coordinator", "--port", str(store_port)], env
         ).wait_line("COORDINATOR_READY", 60)
-        wargs = [sys.executable,
-                 os.path.join(HERE, "lib", "worker_launch.py"),
+        wargs = [sys.executable, WORKER_LAUNCH,
                  "--model", ckpt, "--store", store,
                  "--served-model-name", MODEL_NAME,
                  "--system-port", str(self.sys_port)]

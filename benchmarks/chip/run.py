@@ -37,7 +37,7 @@ import tempfile
 HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, HERE)
 
-from lib import ckpt, endtoend, prom                            # noqa: E402
+from lib import ckpt, correct, endtoend, prom                   # noqa: E402
 from lib.client import Client                                   # noqa: E402
 from lib.deploy import (ROOT, BenchFailure, Deployment, MODEL_NAME, http,
                         parse_prom, scrape)                      # noqa: E402
@@ -141,17 +141,27 @@ async def quiet(dep: Deployment) -> None:
     raise BenchFailure("the engine did not come to rest before the probe")
 
 
-async def probe(client: Client, dep: Deployment, text: str, n: int) -> str:
-    """A fixed greedy request through the same programs, alone in the
-    engine: the reusable prefix cache is dropped first, so before and after
-    the window it is prefilled whole."""
+async def probe(client: Client, dep: Deployment, text: str, n: int,
+                sampling: dict | None):
+    """A fixed request through the same programs, alone in the engine: the
+    reusable prefix cache is dropped first, so before and after the window
+    it is prefilled whole. It reports the log-probability of each token it
+    emits, for the comparison with the reference. `sampling`
+    (`warmup.probe_sampling` of the mix: a temperature and a seed) makes it
+    draw its tokens, the same draws both times: a greedy stream of noise
+    weights settles on one id whose probability is all but 1, and a
+    log-probability of 0 deviates by nothing whatever the precision."""
     await quiet(dep)
     await asyncio.to_thread(http, "POST", dep.url + "/clear_kv_blocks", {})
     r = await client.complete("probe", text, n, PROBE_TOKENS,
-                              time.perf_counter(), keep_text=True)
+                              time.perf_counter(), logprobs=True,
+                              sampling=sampling)
     if not r.ok:
         raise BenchFailure(f"probe request failed: {r.error or r.finish}")
-    return "".join(r.text)
+    if len(r.logprobs) != PROBE_TOKENS:
+        raise BenchFailure(f"probe reported {len(r.logprobs)} "
+                           f"log-probabilities for {PROBE_TOKENS} tokens")
+    return r
 
 
 async def marks(dep: Deployment, w0: float, w1: float, trace_dir: str | None
@@ -190,7 +200,9 @@ async def drive(dep: Deployment, config: dict, traffic: dict, seed: int,
             v for k, v in (await asyncio.to_thread(
                 scrape, dep.sys_port)).items()
             if k.startswith("dynamo_compile_seconds_total"))
-        before = await probe(client, dep, probe_text, probe_tokens)
+        probe_sampling = traffic["warmup"].get("probe_sampling")
+        before = await probe(client, dep, probe_text, probe_tokens,
+                             probe_sampling)
         phases = []
         if traffic["loop"] == "open":
             for name, length in (("ramp", ramp["seconds"]),
@@ -216,9 +228,11 @@ async def drive(dep: Deployment, config: dict, traffic: dict, seed: int,
                 t.cancel()
             await asyncio.gather(*tasks, return_exceptions=True)
         got = await marker
-        after = await probe(client, dep, probe_text, probe_tokens)
+        after = await probe(client, dep, probe_text, probe_tokens,
+                            probe_sampling)
         return {"results": client.results, "w0": w0, "w1": w1, **got,
-                "probe_same": before == after, "undrained": cut,
+                "probes": [before, after], "undrained": cut,
+                "probe_same": "".join(before.text) == "".join(after.text),
                 "compile_s_after_warmup": compile_s,
                 "setup_s": w0 - T_PROCESS}
 
@@ -310,6 +324,9 @@ def main() -> int:
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     ap.add_argument("--bench-file", default=os.path.join(
         ROOT, "BENCHMARK.json"), help="for the rehearsals' own cell list")
+    ap.add_argument("--control", default=None, choices=("int4",),
+                    help="builder's reading: the reference once more at "
+                         "this lower precision, in the program's place")
     args = ap.parse_args()
     try:
         return run(args)
@@ -323,7 +340,10 @@ def main() -> int:
 def load_cell(bench_file: str, workload: str) -> tuple:
     """(benchmark, cell, configuration, traffic) of one workload, each from
     the file that BENCHMARK.json names."""
-    bench = load_json(bench_file)
+    # a rehearsal's cell list may bring only `configs` and `workloads`: what
+    # it leaves out is BENCHMARK.json's
+    bench = {**load_json(os.path.join(ROOT, "BENCHMARK.json")),
+             **load_json(bench_file)}
     cell = next((w for w in bench["workloads"] if w["name"] == workload),
                 None)
     if cell is None:
@@ -343,6 +363,24 @@ def checkpoint_dir() -> str:
 
 
 def run(args) -> int:
+    line, device, cell, peaks = measure(args)
+    if device["platform"] != "tpu":
+        raise BenchFailure(
+            f"the worker's arrays sit on {device['platform']!r}, not on a "
+            "TPU: no result")
+    if device["count"] != cell["chips"]:
+        raise BenchFailure(f"worker used {device['count']} devices, the "
+                           f"cell asks for {cell['chips']}")
+    if device["kind"] not in peaks:
+        raise BenchFailure(f"device kind {device['kind']!r} is not in "
+                           "peaks.json")
+    print(json.dumps(line), flush=True)
+    return 0
+
+
+def measure(args) -> tuple:
+    """Everything of a run but the look at the platform: (result line, the
+    worker's device report, cell, peaks)."""
     if not os.path.isdir(os.path.join(ROOT, "dynamo_tpu")):
         raise BenchFailure(f"no dynamo_tpu package in {ROOT}: nothing to "
                            "measure")
@@ -377,6 +415,20 @@ def run(args) -> int:
         device = dep.device
         load_s = dep.load_s
     cli = reduce_client(run_, traffic["loop"])
+    # the worker has exited and its memory peak is read: the chip is free
+    # for the reference
+    t = time.monotonic()
+    ref = correct.compare(config, ckpt_dir, log_dir,
+                          correct.sequences(run_, traffic, args.seed),
+                          args.control)
+    say(f"reference ({ref['device']}) over {ref['served_n']} served tokens "
+        f"in {ref['seconds']:.1f}s, {time.monotonic() - t:.1f}s with the "
+        "child's start and compiles")
+    if ref.get("control"):
+        say(f"control {json.dumps(ref['control'])}")
+    ref_ok, ref_lines = correct.judge(config, ref)
+    say("probe ids " + " ".join(str(i) for i in correct.ids_of(
+        "".join(run_["probes"][0].text))))
 
     window = {"prom_start": run_["prom_start"],
               "prom_stop": run_["prom_stop"]}
@@ -385,7 +437,10 @@ def run(args) -> int:
     say(f"set-up {run_['setup_s']:.1f}s (worker load {load_s:.1f}s, compile "
         f"seconds after warm-up {run_['compile_s_after_warmup']:.1f}); "
         f"compiles in window {compiles:.0f}"
-        + ("  <-- A PROGRAM COMPILED INSIDE THE WINDOW" if compiles else ""))
+        + ("  <-- A PROGRAM COMPILED INSIDE THE WINDOW: " + ", ".join(
+            k for k, v in window["prom_stop"].items()
+            if k.startswith("dynamo_compile_total")
+            and v > window["prom_start"].get(k, 0.0)) if compiles else ""))
     say(f"generator lateness p50 {cli['late_ms_p50']:.2f} ms, max "
         f"{cli['late_ms_max']:.2f} ms"
         + ("  <-- GENERATOR RAN LATE" if cli["late_ms_max"] > MAX_LATE_MS
@@ -396,17 +451,27 @@ def run(args) -> int:
         f"each) undrained {run_['undrained']}"
         + ("  <-- FEWER THAN 100 REQUESTS" if cli["completed"] < 100
            else ""))
-    correct = (cli["failed"] == 0 and cli["attempted"] > 0
-               and run_["probe_same"] and compiles == 0
-               and run_["undrained"] == 0)
-    say(f"probe repeated identically: {run_['probe_same']}; correct: "
-        f"{correct}")
+    checks = [
+        f"failed {cli['failed']} (limit 0) of attempted "
+        f"{cli['attempted']} (at least 1)",
+        f"probe repeated identically {run_['probe_same']} (must)",
+        f"compiles in window {compiles:.0f} (limit 0)",
+        f"undrained {run_['undrained']} (limit 0)", *ref_lines]
+    is_correct = (cli["failed"] == 0 and cli["attempted"] > 0
+                  and run_["probe_same"] and compiles == 0
+                  and run_["undrained"] == 0 and ref_ok)
+    checks.append(f"correct: {is_correct}")
+    for text in checks:
+        say(text)
 
     out_device = {"platform": device["platform"], "kind": device["kind"],
                   "count": device["count"],
                   "memory_peak_bytes": memory["peak_bytes"]}
-    line = {"correct": correct, "attempted": cli["attempted"],
-            "failed": cli["failed"], "device": out_device}
+    line = {"correct": is_correct, "attempted": cli["attempted"],
+            "failed": cli["failed"], "device": out_device,
+            "reference": {k: ref[k] for k in (
+                "logprob_dev", "logprob_n", "served_gap", "served_n",
+                "seconds")}}
     if not args.trace:
         line["metrics"] = endtoend.compute(
             [m for m in bench["end_to_end"] if applies(m, args.workload)],
@@ -423,6 +488,10 @@ def run(args) -> int:
                "trace": trace, "client": cli, "config": config,
                "deployment": {"load_s": load_s}, "traffic": traffic,
                "peaks": peaks.get(device["kind"], {})}
+        # what the readers read, kept beside the trace: a later reduction
+        # of this run reads the same numbers
+        with open(os.path.join(log_dir, "layer_ctx.json"), "w") as f:
+            json.dump(ctx, f)
         line["metrics"] = read_layer_metrics(
             [m for m in bench["per_layer"] if applies(m, args.workload)], ctx)
         out_device["busy_s"] = trace["busy_s"]
@@ -430,18 +499,11 @@ def run(args) -> int:
         line["breakdown"] = {"device_ops": trace["device_ops"][:10],
                              "idle_gaps": trace["idle_gaps"][:10]}
     say(f"metrics computed: {sorted(line['metrics'])}")
-    if device["platform"] != "tpu":
-        raise BenchFailure(
-            f"the worker's arrays sit on {device['platform']!r}, not on a "
-            "TPU: no result")
-    if device["count"] != cell["chips"]:
-        raise BenchFailure(f"worker used {device['count']} devices, the "
-                           f"cell asks for {cell['chips']}")
-    if device["kind"] not in peaks:
-        raise BenchFailure(f"device kind {device['kind']!r} is not in "
-                           "peaks.json")
-    print(json.dumps(line), flush=True)
-    return 0
+    # each number compared beside its limit: the last lines on standard
+    # error too, where the driver's record keeps them
+    print("\n".join(f"[bench] {text}" for text in checks), file=sys.stderr,
+          flush=True)
+    return line, device, cell, peaks
 
 
 if __name__ == "__main__":

@@ -2,11 +2,13 @@
 
 The XLA path is pure lax ops, so it runs on any backend and partitions under
 `jit` + sharding annotations (tensor parallelism over the kv-head axis).
-The pallas path is this module's own decode kernel,
-`paged_decode_attention` (the name a device trace shows): decode is the
-HBM-bandwidth-bound hot loop, and the kernel reads a lane's live pages and
-nothing else, a page of all kv heads per copy. It is selected automatically
-on TPU and runs once per "tp" shard under a tensor-parallel mesh.
+The pallas path is this module's own two kernels, under the names a device
+trace shows. `paged_decode_attention`: decode is the HBM-bandwidth-bound hot
+loop, and the kernel reads a lane's live pages and nothing else.
+`paged_prefill_attention`: a chunk's query tiles walk only the KV blocks they
+can see, flash style, and no `[T, context]` score tensor exists. Both fetch a
+page of all kv heads per copy, are selected automatically on TPU and run once
+per "tp" shard under a tensor-parallel mesh.
 
 Cache layout (both paths): K/V pages per layer are
 ``(num_kv_heads, num_pages, page_size, head_dim)``.
@@ -118,9 +120,10 @@ def prefill_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
 
     q: (T, H, D); k_pages/v_pages: (KVH, N, P, D); page_table: (max_pages,);
     q_positions: (T,) absolute positions; seq_len: scalar valid length.
-    Returns (T, H, D). Quadratic XLA attention — prefill is MXU-bound and
-    XLA fuses the mask/softmax; a flash-style pallas kernel is a later
-    optimisation for very long context (ring attention covers longer still).
+    Returns (T, H, D). Quadratic XLA attention over the whole page table,
+    f32 scores `[H, T, context]`: the path off the TPU, the fallback of
+    `paged_attention_prefill` and the reference `paged_prefill_attention`
+    is tested against.
     """
     kvh, _, p, d = k_pages.shape
     h = q.shape[1]
@@ -149,7 +152,7 @@ def mixed_attention(q_dec: jax.Array, q_chunk: jax.Array,
                     page_size: int) -> tuple[jax.Array, jax.Array]:
     """One attention entry for a MIXED prefill+decode dispatch: the
     decode sub-batch routes through `paged_attention_decode` and the
-    chunk sub-batch through `prefill_attention`, against the same page
+    chunk sub-batch through `paged_attention_prefill`, against the same page
     caches, inside one traced step (models/llama.py mixed_prefill_decode
     jits the whole thing; compile shapes bucket on (decode width, chunk
     tokens)). The two sub-batches are different sequences with disjoint
@@ -164,11 +167,9 @@ def mixed_attention(q_dec: jax.Array, q_chunk: jax.Array,
     dec_out = paged_attention_decode(
         q_dec, k_pages, v_pages, dec_lengths, dec_tables,
         page_size=page_size)
-    chunk_out = jax.vmap(
-        lambda q1, pt, pos1, sl: prefill_attention(
-            q1, k_pages, v_pages, pt, q_positions=pos1, seq_len=sl,
-            page_size=page_size)
-    )(q_chunk, chunk_tables, chunk_positions, chunk_seq_lens)
+    chunk_out = paged_attention_prefill(
+        q_chunk, k_pages, v_pages, chunk_tables, chunk_positions[:, 0],
+        chunk_seq_lens, page_size=page_size)
     return dec_out, chunk_out
 
 
@@ -439,6 +440,250 @@ def _pallas_decode(q, k_pages, v_pages, lengths, page_tables):
         paged_decode_attention,
         (ROW_SPEC, KV_SPEC, KV_SPEC, REP_SPEC, REP_SPEC),
         ROW_SPEC)(q, k_pages, v_pages, lengths, page_tables)
+
+
+def paged_attention_prefill(q: jax.Array, k_pages: jax.Array,
+                            v_pages: jax.Array, page_tables: jax.Array,
+                            q_starts: jax.Array, seq_lens: jax.Array,
+                            page_size: int) -> jax.Array:
+    """Causal attention of a round of prefill chunks against the pages
+    that already hold them.
+
+    q: (Bp, T, H, D), row i of a sequence at position `q_starts + i`;
+    k_pages/v_pages: (KVH, N, P, D); page_tables: (Bp, max_pages);
+    q_starts/seq_lens: (Bp,) (`seq_len == q_start` = padding lane).
+    -> (Bp, T, H, D). Rows at or past `seq_len` are finite and ignored.
+    """
+    kvh, _, p, d = k_pages.shape
+    _, t, h, _ = q.shape
+    if use_pallas():
+        if d % 128:
+            _note_fallback("head_dim")
+        elif prefill_geometry(kvh, h // kvh, t, p, d,
+                              k_pages.dtype.itemsize) is None:
+            _note_fallback("chunk_shape")
+        else:
+            return _pallas_prefill(q, k_pages, v_pages, page_tables,
+                                   q_starts, seq_lens)
+    positions = q_starts[:, None] + jnp.arange(t)[None, :]
+    return jax.vmap(
+        lambda q1, pt, pos1, sl: prefill_attention(
+            q1, k_pages, v_pages, pt, q_positions=pos1, seq_len=sl,
+            page_size=page_size)
+    )(q, page_tables, positions, seq_lens)
+
+
+# The prefill kernel's tiles. A kv head's `groups x tile` q rows meet a KV
+# block in one product: at most _PREFILL_ROWS rows, and a block as wide as
+# keeps the f32 scores of that product under _PREFILL_SCORE_BYTES and its
+# K + V, all kv heads, under _PREFILL_BLOCK_BYTES. Measured on v5e
+# (PERF.md §6, PR 32). _PREFILL_VMEM_BYTES is the kernel's scoped-VMEM
+# limit: at Mistral's 32 q heads x 256 rows the q and output blocks, the
+# slots and the f32 state come to ~35 MB, over the compiler's default 16.
+_PREFILL_ROWS = 1024
+_PREFILL_SCORE_BYTES = 1 << 20
+_PREFILL_BLOCK_BYTES = 1 << 20
+_PREFILL_SLOTS = 3
+_PREFILL_VMEM_BYTES = 96 << 20
+
+
+@functools.lru_cache(maxsize=None)
+def prefill_geometry(kvh: int, groups: int, chunk: int, page_size: int,
+                     head_dim: int, itemsize: int
+                     ) -> tuple[int, int] | None:
+    """(q tile, pages per KV block) of `paged_prefill_attention` for these
+    operand shapes, or None where the kernel declines them.
+
+    The q tile is the largest divisor of the chunk width, in whole (16,
+    128) tiles, whose `groups x tile` rows stay within `_PREFILL_ROWS`:
+    128 of a 512-token chunk at 7 q heads to a kv head (Qwen2.5-7B), the
+    whole 256-token chunk at 4 (Mistral). A chunk of no whole tile (the
+    few tokens of a spec-verify) is declined. The KV block is whole pages
+    in multiples of 128 tokens, the scores' lane width, within the two
+    byte limits above: 256 tokens in both of those models.
+    """
+    tiles = [c for c in range(16, chunk + 1, 16)
+             if chunk % c == 0 and groups * c <= _PREFILL_ROWS]
+    if not tiles or (128 % page_size and page_size % 128):
+        return None
+    tq = tiles[-1]
+    unit = max(1, 128 // page_size)
+    tokens = min(_PREFILL_SCORE_BYTES // (4 * groups * tq),
+                 _PREFILL_BLOCK_BYTES // (2 * kvh * head_dim * itemsize))
+    ppb = max(unit, tokens // page_size // unit * unit)
+    return tq, ppb
+
+
+# jitted so that the layers of a step share one trace and one lowering of
+# the kernel: inline, 28 layers' worth cost a prefill program 6 s of
+# lowering at every start, compile cache or not (PERF.md §6, PR 32)
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def paged_prefill_attention(q, k_pages, v_pages, page_tables, q_starts,
+                            seq_lens, *, interpret=False):
+    """The prefill attention kernel (operands as `paged_attention_prefill`).
+
+    Grid (sequence, q tile). A tile of queries at positions `[p0, p1)`
+    walks KV blocks `0 .. cdiv(min(p1, seq_len), block) - 1` of its
+    sequence's page table and no further: nothing past the live length,
+    nothing above the diagonal but inside the last block, where the
+    reference's mask (`s_pos <= q_position & s_pos < seq_len`) applies.
+    A tile that starts at or past `seq_len` (a padding lane, the padded
+    end of a bucket) walks none and returns zeros. Pages come as in
+    `paged_decode_attention`: one async copy a page of all kv heads
+    through `_PREFILL_SLOTS` VMEM slots, the next blocks in flight while
+    one is computed; the slots of a tile's last block past its last
+    visible page fetch that page again, masked. Per kv head the
+    `groups x tile` q rows meet a block in one product, q and K in their
+    own dtype with an f32 accumulator; running max, sum and accumulator
+    are f32; the probabilities meet V as bf16 high + low halves (f32 KV:
+    as they are), so bf16 KV loses nothing against f32 probabilities.
+    """
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    kvh, _, p, d = k_pages.shape
+    bp, t, h, _ = q.shape
+    groups = h // kvh
+    max_pages = page_tables.shape[1]
+    kv_dtype = k_pages.dtype
+    tq, ppb = prefill_geometry(kvh, groups, t, p, d, kv_dtype.itemsize)
+    tk = ppb * p
+    rows = groups * tq
+    slots = _PREFILL_SLOTS
+    scale = 1.0 / (d ** 0.5)
+    f32 = jnp.float32
+    qk_dtype = kv_dtype if q.dtype == kv_dtype else f32
+    qk_precision = jax.lax.Precision.HIGHEST if qk_dtype == f32 else None
+    precision = jax.lax.Precision.HIGHEST if kv_dtype == f32 else None
+    split = kv_dtype != f32           # probabilities as high + low halves
+
+    def kernel(start_ref, len_ref, tab_ref, q_ref, k_hbm, v_hbm, o_ref,
+               qbuf, kbuf, vbuf, ksem, vsem, m_ref, l_ref, acc_ref):
+        lane = pl.program_id(0)
+        seq_len = len_ref[lane]
+        p0 = start_ref[lane] + pl.program_id(1) * tq
+        # positions a row of this tile can see: none for a tile of padding
+        seen = jnp.where(p0 < seq_len, jnp.minimum(p0 + tq, seq_len), 0)
+        n_blocks = pl.cdiv(seen, tk)
+        last = pl.cdiv(seen, p) - 1                     # last visible page
+
+        def start(blk, slot):
+            @pl.when(blk < n_blocks)
+            def _():
+                for i in range(ppb):
+                    page = tab_ref[lane * max_pages
+                                   + jnp.minimum(blk * ppb + i, last)]
+                    dst = pl.ds(i * p, p)
+                    pltpu.make_async_copy(k_hbm.at[:, page],
+                                          kbuf.at[slot, :, dst],
+                                          ksem.at[slot]).start()
+                    pltpu.make_async_copy(v_hbm.at[:, page],
+                                          vbuf.at[slot, :, dst],
+                                          vsem.at[slot]).start()
+
+        for slot in range(slots - 1):
+            start(slot, slot)
+        # a kv head's rows: its q heads one after the other, `tq` rows each
+        for head in range(h):
+            g, r = divmod(head, groups)
+            qbuf[g, r * tq:(r + 1) * tq] = q_ref[
+                0, :, head * d:(head + 1) * d].astype(qk_dtype)
+        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        q_pos = jnp.tile(
+            p0 + jax.lax.broadcasted_iota(jnp.int32, (tq, 1), 0), (groups, 1))
+
+        def block(j, _):
+            slot = j % slots
+            start(j + slots - 1, (j + slots - 1) % slots)
+            s_pos = j * tk + jax.lax.broadcasted_iota(jnp.int32, (1, tk), 1)
+            mask = (s_pos <= q_pos) & (s_pos < seq_len)     # (rows, tk)
+            # one wait a cache for the block's ppb page copies: a wait
+            # counts the bytes of its destination, here the whole slot
+            pltpu.make_async_copy(kbuf.at[slot], kbuf.at[slot],
+                                  ksem.at[slot]).wait()
+            pltpu.make_async_copy(vbuf.at[slot], vbuf.at[slot],
+                                  vsem.at[slot]).wait()
+            for g in range(kvh):
+                s = jax.lax.dot_general(
+                    qbuf[g], kbuf[slot, g].astype(qk_dtype),
+                    (((1,), (1,)), ((), ())), precision=qk_precision,
+                    preferred_element_type=f32)
+                s = jnp.where(mask, s * scale, _NEG_INF)
+                m_prev = m_ref[g]                           # (rows, 128)
+                m_new = jnp.maximum(m_prev,
+                                    jnp.max(s, axis=1, keepdims=True))
+                alpha = jnp.exp(m_prev - m_new)
+                # position 0 is visible to every row of a tile that walks
+                # any block, so m_new is a real score from block 0 on and
+                # exp() of a masked one is exactly 0
+                pr = jnp.exp(s - jnp.tile(m_new, (1, tk // 128)))
+                l_ref[g] = alpha * l_ref[g] + jnp.sum(pr, axis=1,
+                                                      keepdims=True)
+                m_ref[g] = m_new
+                if split:
+                    high = pr.astype(kv_dtype)
+                    low = (pr - high.astype(f32)).astype(kv_dtype)
+                    pv = sum(jax.lax.dot_general(
+                        half, vbuf[slot, g], (((1,), (0,)), ((), ())),
+                        preferred_element_type=f32) for half in (high, low))
+                else:
+                    pv = jax.lax.dot_general(
+                        pr, vbuf[slot, g], (((1,), (0,)), ((), ())),
+                        precision=precision, preferred_element_type=f32)
+                acc_ref[g] = jnp.tile(alpha, (1, d // 128)) * acc_ref[g] + pv
+
+        jax.lax.fori_loop(0, n_blocks, block, None)
+        for head in range(h):
+            g, r = divmod(head, groups)
+            mine = slice(r * tq, (r + 1) * tq)
+            total = l_ref[g, mine]                  # 0 where no block ran
+            o_ref[0, :, head * d:(head + 1) * d] = (
+                acc_ref[g, mine] / jnp.tile(
+                    jnp.where(total > 0, total, 1.0), (1, d // 128))
+            ).astype(o_ref.dtype)
+
+    q_block = pl.BlockSpec((1, tq, h * d),
+                           lambda b, i, starts, lens, tabs: (b, i, 0))
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(bp, t // tq),
+            in_specs=[q_block, pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=q_block,
+            scratch_shapes=[
+                pltpu.VMEM((kvh, rows, d), qk_dtype),
+                pltpu.VMEM((slots, kvh, tk, d), kv_dtype),
+                pltpu.VMEM((slots, kvh, tk, d), kv_dtype),
+                pltpu.SemaphoreType.DMA((slots,)),
+                pltpu.SemaphoreType.DMA((slots,)),
+                pltpu.VMEM((kvh, rows, 128), f32),         # m
+                pltpu.VMEM((kvh, rows, 128), f32),         # l
+                pltpu.VMEM((kvh, rows, d), f32),           # acc
+            ]),
+        out_shape=jax.ShapeDtypeStruct((bp, t, h * d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_PREFILL_VMEM_BYTES),
+        interpret=interpret,
+        name="paged_prefill_attention",
+    )(q_starts.astype(jnp.int32), seq_lens.astype(jnp.int32),
+      page_tables.astype(jnp.int32).reshape(-1), q.reshape(bp, t, h * d),
+      k_pages, v_pages)
+    return out.reshape(bp, t, h, d)
+
+
+def _pallas_prefill(q, k_pages, v_pages, page_tables, q_starts, seq_lens):
+    from dynamo_tpu.engine.kernels import KV_SPEC, REP_SPEC, per_tp_shard
+
+    chunk_spec = jax.sharding.PartitionSpec(None, None, "tp")
+    return per_tp_shard(
+        paged_prefill_attention,
+        (chunk_spec, KV_SPEC, KV_SPEC, REP_SPEC, REP_SPEC, REP_SPEC),
+        chunk_spec)(q, k_pages, v_pages, page_tables, q_starts, seq_lens)
 
 
 def ragged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,

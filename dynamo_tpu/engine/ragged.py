@@ -1,9 +1,10 @@
 """Ragged paged attention: one kernel path for every batch shape.
 
-The engine historically split attention across three entries —
-`paged_attention_decode` for decode bursts, a vmapped quadratic
-`prefill_attention` for chunks, and `mixed_attention` glue for fused
-steps — and every entry carried its own padding: decode lanes pad to
+The engine splits attention across three entries —
+`paged_attention_decode` for decode bursts, `paged_attention_prefill`
+for rounds of chunks (each with a kernel of its own in
+engine/attention.py), and `mixed_attention` glue for fused
+steps — and every entry carries its own padding: decode lanes pad to
 the pow2 batch width, chunks pad to `(Bp, T_bucket)` rectangles, and
 the compile shapes bucket on `(decode width, chunk tokens, k_steps, …)`
 tuples (the CompileTracker shape zoo).

@@ -33,7 +33,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from dynamo_tpu.engine.attention import paged_attention_decode, prefill_attention
+from dynamo_tpu.engine.attention import (paged_attention_decode,
+                                         paged_attention_prefill)
 from dynamo_tpu.engine.quant import qm
 
 
@@ -274,9 +275,12 @@ def paged_forward(params: dict, k_cache: tuple, v_cache: tuple,
                   ) -> tuple[jax.Array, tuple, tuple]:
     """Paged multi-token forward shared by prefill and spec-verify
     (traceable): writes the chunk's KV into the paged caches, attends
-    causally against cache + chunk, returns the FINAL-NORMED hidden
-    states for every position ((Bp, T, E), k_cache, v_cache) — callers
-    pick which positions to project through lm_head."""
+    causally against cache + chunk (`paged_attention_prefill`: on the
+    TPU the `paged_prefill_attention` kernel over the KV blocks each
+    q tile can see; a spec-verify's few rows are a shape it declines,
+    counted and served by the XLA einsum), returns the FINAL-NORMED
+    hidden states for every position ((Bp, T, E), k_cache, v_cache) —
+    callers pick which positions to project through lm_head."""
     from dynamo_tpu.engine.attention import use_pallas
     from dynamo_tpu.engine.kernels import (
         kv_write_supported,
@@ -329,11 +333,9 @@ def paged_forward(params: dict, k_cache: tuple, v_cache: tuple,
                 kc, vc = _write_kv(kc, vc, flat(k), flat(v), f_pages,
                                    f_offs, f_valid)
         with jax.named_scope("attn_core"):
-            attn = jax.vmap(
-                lambda q1, pt, pos1, sl: prefill_attention(
-                    q1, kc, vc, pt, q_positions=pos1, seq_len=sl,
-                    page_size=cfg.page_size)
-            )(q, page_tables, positions, seq_lens)         # (Bp, T, H, D)
+            attn = paged_attention_prefill(
+                q, kc, vc, page_tables, cached_lens, seq_lens,
+                page_size=cfg.page_size)                   # (Bp, T, H, D)
         with jax.named_scope("attn_out"):
             x = x + qm(attn.reshape(Bp, T, -1), lp["wo"])
         with jax.named_scope("mlp"):

@@ -196,7 +196,7 @@ def _pp_prefill_paged_local(params, kc_all, vc_all, tokens_c,
     page_tables (B, max_pages); cached_lens/seq_lens (B,). Returns
     ((1, B, V) last-token logits — real on the last stage, kc, vc).
     """
-    from dynamo_tpu.engine.attention import prefill_attention
+    from dynamo_tpu.engine.attention import paged_attention_prefill
 
     stage = lax.axis_index(axis)
     C, B, Tc = tokens_c.shape
@@ -238,11 +238,9 @@ def _pp_prefill_paged_local(params, kc_all, vc_all, tokens_c,
             k = rope(k, positions, cfg.rope_theta)
             kc, vc = _write_kv(kc, vc, flat(k), flat(v), flat(page_ids),
                                flat(offsets), flat(new_valid))
-            attn = jax.vmap(
-                lambda q1, pt, pos1, sl: prefill_attention(
-                    q1, kc, vc, pt, q_positions=pos1, seq_len=sl,
-                    page_size=P_)
-            )(q, page_tables, positions, seq_lens)          # (B, Tc, H, D)
+            attn = paged_attention_prefill(
+                q, kc, vc, page_tables, positions[:, 0], seq_lens,
+                page_size=P_)                               # (B, Tc, H, D)
             x = x + qm(attn.reshape(B, Tc, -1), lp["wo"])
             hn = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
             x = x + _mlp(hn, lp, cfg)

@@ -12,6 +12,7 @@ time (every xdist worker imports this file; one runs it).
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -156,24 +157,43 @@ def pallas_impl(monkeypatch):
     monkeypatch.setattr(attention, "_impl", "pallas")
 
 
+# widths of the benchmark's two configurations (benchmarks/chip/configs/)
+WIDTHS = {
+    "llama3-8b": dict(),
+    "mistral-7b": dict(vocab_size=32768, rope_theta=1e6),
+    "qwen2.5-7b": dict(vocab_size=152064, hidden_size=3584,
+                       intermediate_size=18944, num_heads=28, num_kv_heads=4,
+                       rope_theta=1e6, attention_bias=True),
+}
+
+
 @pytest.fixture
-def model(sds, one_chip):
-    """(cfg, params, k_cache, v_cache) as shapes on the described chip:
-    int8 weights exactly as `--quantize int8` serves them."""
+def model_of(sds):
+    """(cfg, params, k_cache, v_cache) as shapes on the described chip,
+    2 layers: int8 weights exactly as `--quantize int8` serves them."""
     from dynamo_tpu.engine.quant import quantize_params
     from dynamo_tpu.models.llama import (LlamaConfig, init_cache,
                                          init_params)
 
-    cfg = LlamaConfig.llama3_8b(num_layers=2, max_pages_per_seq=MAX_PAGES)
+    def make(widths="llama3-8b", max_pages=MAX_PAGES):
+        cfg = LlamaConfig.llama3_8b(num_layers=2, max_pages_per_seq=max_pages,
+                                    **WIDTHS[widths])
 
-    def on_chip(tree):
-        return jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)
+        def on_chip(tree):
+            return jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)
 
-    params = on_chip(jax.eval_shape(
-        lambda k: quantize_params(init_params(k, cfg), mode="int8"),
-        jax.random.PRNGKey(0)))
-    kc, vc = on_chip(jax.eval_shape(lambda: init_cache(cfg, PAGES)))
-    return cfg, params, kc, vc
+        params = on_chip(jax.eval_shape(
+            lambda k: quantize_params(init_params(k, cfg), mode="int8"),
+            jax.random.PRNGKey(0)))
+        kc, vc = on_chip(jax.eval_shape(lambda: init_cache(cfg, PAGES)))
+        return cfg, params, kc, vc
+
+    return make
+
+
+@pytest.fixture
+def model(model_of):
+    return model_of()
 
 
 def test_engine_decode_burst_holds_the_kernels(sds, model, pallas_impl):
@@ -196,25 +216,39 @@ def test_engine_decode_burst_holds_the_kernels(sds, model, pallas_impl):
     assert mem.temp_size_in_bytes < 1 << 30
 
 
-def test_engine_prefill_chunk_holds_the_page_write(sds, model, pallas_impl):
+@pytest.mark.parametrize("widths,bp,t,max_pages,temp_limit", [
+    ("llama3-8b", 1, 128, MAX_PAGES, 32 << 20),
+    # the rounds of the benchmark's cells; temporaries measured in PR 32
+    # (10 / 193 / 63 MB; the einsum's f32 scores took 299 / 2492 / 697)
+    ("qwen2.5-7b", 1, 512, 288, 32 << 20),
+    ("qwen2.5-7b", 8, 512, 288, 256 << 20),
+    ("mistral-7b", 8, 256, 128, 128 << 20),
+], ids=["llama3-8b-1x128", "qwen-1x512", "qwen-8x512", "mistral-8x256"])
+def test_engine_prefill_chunk_holds_the_page_write(
+        sds, model_of, pallas_impl, widths, bp, t, max_pages, temp_limit):
     from dynamo_tpu.models.llama import prefill_batch
 
-    cfg, params, kc, vc = model
-    bp, t, i32 = 1, 128, jnp.int32
+    cfg, params, kc, vc = model_of(widths, max_pages)
+    i32 = jnp.int32
     compiled = prefill_batch.lower(
-        params, kc, vc, sds((bp, t), i32), sds((bp, MAX_PAGES), i32),
+        params, kc, vc, sds((bp, t), i32), sds((bp, max_pages), i32),
         sds((bp,), i32), sds((bp,), i32), cfg, aligned=True).compile()
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") >= cfg.num_layers
+    # per layer: the page KV write and the prefill attention
+    assert text.count("tpu_custom_call") >= 2 * cfg.num_layers
     assert text.count("kv_write_pages") >= cfg.num_layers
+    assert text.count("paged_prefill_attention") >= cfg.num_layers
     assert not _missing(text, LAYER_SCOPES)
-    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+    # no score tensor over the whole context, whatever leads it
+    assert not re.search(rf"f32\[[0-9,]*{t},{max_pages * PAGE}\]", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < temp_limit
 
 
-def test_tensor_parallel_decode_burst_splits_the_kernels(
-        topo, no_persistent_cache, pallas_impl):
+@pytest.mark.parametrize("step", ["decode_burst", "prefill_chunk"])
+def test_tensor_parallel_steps_split_the_kernels(
+        topo, no_persistent_cache, pallas_impl, step):
     """tp=4 over the described 2x2: GSPMD cannot partition a Mosaic
-    kernel, so the step must run them per shard (kernels.per_tp_shard)
+    kernel, so the steps must run them per shard (kernels.per_tp_shard)
     and still reduce over tp."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -222,7 +256,8 @@ def test_tensor_parallel_decode_burst_splits_the_kernels(
     from dynamo_tpu.engine.sharding import (cache_sharding, make_mesh,
                                             param_sharding)
     from dynamo_tpu.models.llama import (LlamaConfig, decode_multi_step,
-                                         init_cache, init_params)
+                                         init_cache, init_params,
+                                         prefill_batch)
 
     cfg = LlamaConfig.llama3_8b(num_layers=2, max_pages_per_seq=MAX_PAGES)
     mesh = make_mesh(dp=1, tp=4, devices=list(topo.devices))
@@ -249,11 +284,19 @@ def test_tensor_parallel_decode_burst_splits_the_kernels(
 
     b, i32, u32, f32 = 8, jnp.int32, jnp.uint32, jnp.float32
     with jax.set_mesh(mesh):
-        text = decode_multi_step.lower(
-            params, kc, vc, sds((b,), i32), sds((b,), i32),
-            sds((b, MAX_PAGES), i32), sds((b,), jnp.bool_), sds((b,), u32),
-            sds((b,), u32), sds((b,), f32), sds((b,), f32), sds((b,), i32),
-            cfg, 8, topk_lp=0).compile().as_text()
+        if step == "decode_burst":
+            lowered = decode_multi_step.lower(
+                params, kc, vc, sds((b,), i32), sds((b,), i32),
+                sds((b, MAX_PAGES), i32), sds((b,), jnp.bool_),
+                sds((b,), u32), sds((b,), u32), sds((b,), f32),
+                sds((b,), f32), sds((b,), i32), cfg, 8, topk_lp=0)
+            attention = "paged_decode_attention"
+        else:
+            lowered = prefill_batch.lower(
+                params, kc, vc, sds((b, 256), i32), sds((b, MAX_PAGES), i32),
+                sds((b,), i32), sds((b,), i32), cfg, aligned=True)
+            attention = "paged_prefill_attention"
+        text = lowered.compile().as_text()
     assert text.count("tpu_custom_call") >= 2 * cfg.num_layers
-    assert text.count("paged_decode_attention") >= cfg.num_layers
+    assert text.count(attention) >= cfg.num_layers
     assert "all-reduce" in text

@@ -240,40 +240,17 @@ async def drive(dep: Deployment, config: dict, traffic: dict, seed: int,
 # -- reduction ---------------------------------------------------------------
 
 
-def reduce_client(run: dict, loop: str) -> dict:
-    """Samples of the window, by the clock of this process."""
-    w0, w1 = run["w0"], run["w1"]
-    res = [r for r in run["results"] if r.phase in ("ramp", "window")]
-    in_win = [r for r in res if w0 <= r.due < w1]
-    if loop == "closed":
-        # callers come back only when a request ends: the attempts of the
-        # window are the requests that ended in it (those cut by its end
-        # are neither completed nor failed)
-        ended = [r for r in res
-                 if w0 <= r.done < w1 and r.error != "cancelled"]
-    else:
-        ended = in_win
-    failed = [r for r in ended if not r.ok]
-    attempted = len(ended)
-    firsts = [r for r in in_win if r.frames
-              and (r.ok or r.error == "cancelled")]
-    done = [r for r in ended if r.ok and r.tokens > 1]
-    late = sorted(r.sent - r.due for r in in_win)
-    return {
-        "attempted": attempted, "failed": len(failed),
-        "failures": sorted({str(r.error or r.finish) for r in failed})[:5],
-        "ttft_s": [r.frames[0][0] - r.due for r in firsts],
-        "ttft_from_send_s": [r.frames[0][0] - r.sent for r in firsts],
-        "tpot_s": [(r.frames[-1][0] - r.frames[0][0]) / (r.tokens - 1)
-                   for r in done],
-        "window_tokens": sum(n for r in res for t, n in r.frames
-                             if w0 <= t < w1),
-        "completed": len(done), "window_s": w1 - w0,
-        "frames_per_request": (sum(len(r.frames) for r in done)
-                               / max(1, len(done))),
-        "late_ms_p50": 1e3 * late[len(late) // 2] if late else 0.0,
-        "late_ms_max": 1e3 * late[-1] if late else 0.0,
-    }
+def reduce_client(run: dict, loop: str, log_dir: str, about: dict) -> dict:
+    """Samples of the window, by the clock of this process. The records
+    they are taken from are kept beside the run's logs: every request of
+    ramp and window, so that any statistic can be taken from a finished
+    run (`lib/endtoend.py`)."""
+    window_s = run["w1"] - run["w0"]
+    records = endtoend.samples(run["results"], run["w0"])
+    with open(os.path.join(log_dir, "client_samples.json"), "w") as f:
+        json.dump({**about, "loop": loop, "window_s": window_s,
+                   "setup_s": run["setup_s"], "requests": records}, f)
+    return endtoend.reduce(records, window_s, loop)
 
 
 def live_at(results: list, t: float) -> tuple[int, int]:
@@ -414,7 +391,8 @@ def measure(args) -> tuple:
         memory = dep.ask_worker("memory")
         device = dep.device
         load_s = dep.load_s
-    cli = reduce_client(run_, traffic["loop"])
+    cli = reduce_client(run_, traffic["loop"], log_dir, {
+        "workload": args.workload, "seed": args.seed, "trace": args.trace})
     # the worker has exited and its memory peak is read: the chip is free
     # for the reference
     t = time.monotonic()

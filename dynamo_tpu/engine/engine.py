@@ -33,7 +33,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from dynamo_tpu.engine.metrics import EngineMetrics
-from dynamo_tpu.engine.pages import PagePool
+from dynamo_tpu.engine.pages import (PagePool, kv_block_shape,
+                                     kv_layer_shape)
 from dynamo_tpu.engine.memory import is_resource_exhausted, record_oom
 from dynamo_tpu.engine.profiler import recorder_from_env
 from dynamo_tpu.engine.sampling import sample_tokens_lp
@@ -466,8 +467,8 @@ class TpuEngine:
                 is_leaf=lambda x: not isinstance(x, dict))
             # paged KV stacked (L, KVH, N, P, D), layer axis over pp —
             # each stage holds its slice's pages only
-            shape = (mcfg.num_layers, mcfg.num_kv_heads, cfg.num_pages,
-                     mcfg.page_size, mcfg.head_dim)
+            shape = (mcfg.num_layers,
+                     *kv_layer_shape(mcfg, cfg.num_pages))
             mk_cache = jax.jit(
                 lambda: jnp.zeros(shape, mcfg.dtype),
                 out_shardings=NamedSharding(cfg.pp_mesh,
@@ -887,8 +888,7 @@ class TpuEngine:
                 data = ktp["kv_data"]
                 plen = int(ktp["prefill_len"])
                 n_pages = (plen + mcfg.page_size - 1) // mcfg.page_size
-                want = (2, mcfg.num_layers, mcfg.num_kv_heads, n_pages,
-                        mcfg.page_size, mcfg.head_dim)
+                want = kv_block_shape(mcfg, n_pages)
                 if not (0 < plen < len(req.token_ids)) \
                         or tuple(data.shape) != want:
                     # a malformed import must fail THIS request, not reach

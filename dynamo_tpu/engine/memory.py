@@ -67,6 +67,8 @@ import time
 from collections import deque
 from typing import Any, Callable, Optional
 
+# a sizing helper like those below; its answer lives with the KV geometry
+from dynamo_tpu.engine.pages import kv_page_bytes  # noqa: F401
 from dynamo_tpu.runtime.metrics import Gauge, MetricsRegistry
 
 logger = logging.getLogger(__name__)
@@ -667,12 +669,6 @@ def predict_weights_bytes(cfg, quantize=False) -> int:
     embed = 2 * cfg.vocab_size * h * 2           # embed + lm_head, bf16
     norms = (2 * cfg.num_layers + 1) * h * 2
     return int(body + embed + norms)
-
-
-def kv_page_bytes(cfg, dtype_itemsize: int = 2) -> int:
-    """Bytes one KV page reserves on device (k + v, all layers)."""
-    return (2 * cfg.num_layers * cfg.num_kv_heads * cfg.page_size
-            * cfg.head_dim * dtype_itemsize)
 
 
 def predict_workspace_bytes(cfg, max_batch: int,

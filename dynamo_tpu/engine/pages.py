@@ -20,6 +20,7 @@ Invariants:
 from __future__ import annotations
 
 import itertools
+import math
 from collections import OrderedDict
 from dataclasses import dataclass, field
 from typing import Callable, Optional
@@ -32,6 +33,34 @@ from dynamo_tpu.protocols import (
 )
 
 EventSink = Callable[[KvCacheEvent], None]
+
+
+# ---------------------------------------------------------------------------
+# KV geometry: the ONE place a model configuration becomes the cache's
+# shapes and bytes. init_cache, the pp cache, the KV-import check, KVBM's
+# tier blocks and the memory ledger all ask here; a new cache format (a
+# latent a token, per-layer geometry) changes these three answers.
+# ---------------------------------------------------------------------------
+
+
+def kv_layer_shape(cfg, num_pages: int) -> tuple:
+    """(KVH, N, P, D): one layer's K (or V) cache of `num_pages` pages —
+    the layout the paged-attention kernels want."""
+    return (cfg.num_kv_heads, num_pages, cfg.page_size, cfg.head_dim)
+
+
+def kv_block_shape(cfg, n_pages: Optional[int] = None) -> tuple:
+    """[k; v] of all layers on the wire and in the tiers:
+    (2, L, KVH, P, D) for one block, (2, L, KVH, n, P, D) for a run of
+    `n_pages` pages (a disaggregated prefill's export)."""
+    kvh, n, p, d = kv_layer_shape(cfg, n_pages)
+    run = () if n_pages is None else (n,)
+    return (2, cfg.num_layers, kvh, *run, p, d)
+
+
+def kv_page_bytes(cfg, dtype_itemsize: int = 2) -> int:
+    """Bytes one KV page reserves on device (k + v, all layers)."""
+    return math.prod(kv_block_shape(cfg)) * dtype_itemsize
 
 
 class BlockStateInvalid(RuntimeError):

@@ -42,6 +42,7 @@ from typing import Optional
 
 import numpy as np
 
+from dynamo_tpu.engine.pages import kv_block_shape, kv_page_bytes
 from dynamo_tpu.kvbm.tiers import TieredStore
 from dynamo_tpu.runtime.tracing import tracer
 from dynamo_tpu.tokens import TokenBlockSequence
@@ -275,10 +276,8 @@ class KvbmManager:
                     itemsize = cache[0].dtype.itemsize
             except Exception:
                 pass
-            n = itemsize
-            for dim in self.block_shape():
-                n *= dim
-            self._block_nbytes_cached = n
+            self._block_nbytes_cached = kv_page_bytes(
+                self.engine.model_cfg, itemsize)
         return self._block_nbytes_cached
 
     def _effective_queue_depth(self) -> int:
@@ -608,8 +607,7 @@ class KvbmManager:
 
     def block_shape(self) -> tuple:
         """(2, L, KVH, P, D) — the wire/tier shape of one block."""
-        m = self.engine.model_cfg
-        return (2, m.num_layers, m.num_kv_heads, m.page_size, m.head_dim)
+        return kv_block_shape(self.engine.model_cfg)
 
     # -- remote onboard (G4 → G1) -------------------------------------------
 

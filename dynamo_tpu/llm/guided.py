@@ -319,10 +319,14 @@ def compile_regex(pattern: str, deadline_s: float = 15.0) -> ByteDfa:
     """deadline_s bounds CPU for the whole compile: guided_regex is
     user-supplied via the API, and pathological (but state-cap-legal)
     patterns make subset construction + minimization superlinear — a
-    wall-clock budget is the only bound that holds for every shape."""
+    time budget is the only bound that holds for every shape. It is
+    counted on THIS thread's CPU clock (`time.thread_time`), not the
+    wall's: the bound is on the work a pattern may cost, and a busy
+    host must not turn a legal grammar (JSON mode: ~6 s) into a
+    refused one."""
     import time as _time
 
-    t_end = _time.monotonic() + deadline_s
+    t_end = _time.thread_time() + deadline_s
     nfa, start, accept = _RegexParser(pattern).parse()
 
     def closure(states: frozenset) -> frozenset:
@@ -341,7 +345,7 @@ def compile_regex(pattern: str, deadline_s: float = 15.0) -> ByteDfa:
     rows: list[np.ndarray] = []
     i = 0
     while i < len(order):
-        if i % 64 == 0 and _time.monotonic() > t_end:
+        if i % 64 == 0 and _time.thread_time() > t_end:
             raise GrammarError(
                 f"regex compile exceeded {deadline_s:.0f}s "
                 f"(pattern too complex)")
@@ -385,7 +389,7 @@ def minimize(dfa: ByteDfa, t_end: Optional[float] = None) -> ByteDfa:
     block = dfa.accepting.astype(np.int64).copy()
     import time as _time
     while True:
-        if t_end is not None and _time.monotonic() > t_end:
+        if t_end is not None and _time.thread_time() > t_end:
             raise GrammarError("regex compile exceeded deadline during "
                                "minimization (pattern too complex)")
         # signature: (block, blocks of the 256 successors)

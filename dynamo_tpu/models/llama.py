@@ -35,6 +35,7 @@ from jax import lax
 
 from dynamo_tpu.engine.attention import (paged_attention_decode,
                                          paged_attention_prefill)
+from dynamo_tpu.engine.pages import kv_layer_shape
 from dynamo_tpu.engine.quant import qm
 
 
@@ -147,7 +148,7 @@ def init_cache(cfg: LlamaConfig, num_pages: int
     (KVH, num_pages, page_size, D). Per-layer (not L-stacked) so every
     step's write is an in-place update — see module docstring. Page 0 is
     scratch."""
-    shape = (cfg.num_kv_heads, num_pages, cfg.page_size, cfg.head_dim)
+    shape = kv_layer_shape(cfg, num_pages)
     return (tuple(jnp.zeros(shape, dtype=cfg.dtype)
                   for _ in range(cfg.num_layers)),
             tuple(jnp.zeros(shape, dtype=cfg.dtype)
@@ -250,6 +251,112 @@ def qkv_proj(hn: jax.Array, lp: dict, cfg: LlamaConfig
     return q, k, v
 
 
+def block_qkv(x: jax.Array, lp: dict, positions: jax.Array,
+              cfg: LlamaConfig) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """First half of THE transformer block, written once: what stands
+    before the attention core (pre-norm, q/k/v projection, split into
+    heads, RoPE). Every forward flavor (paged prefill / decode / mixed /
+    ragged, dense, sp ring, pp stages, MoE) is `block_qkv`, its own core
+    (its KV write and its attention, inline in its layer loop), then
+    `block_out`. The core is NOT handed in as a callback: every Python
+    frame between a jitted entry and the Pallas kernels it calls inline
+    per layer costs tracing time at every start (measured on the chip's
+    host, PERF.md §6 PR 35: four frames, +24% a decode trace).
+    x: (..., E) with positions broadcastable to x.shape[:-1]; returns
+    q (..., H, D) and k, v (..., KVH, D) (the LOCAL head counts under a
+    manual tp shard)."""
+    with jax.named_scope("attn_qkv"):
+        hn = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
+        q, k, v = qkv_proj(hn, lp, cfg)
+        heads = x.shape[:-1] + (-1, cfg.head_dim)
+        q, k, v = q.reshape(heads), k.reshape(heads), v.reshape(heads)
+        if x.ndim == 2:
+            # flat rows (one token each): rope wants a T axis
+            q = rope(q[:, None], positions[:, None], cfg.rope_theta)[:, 0]
+            k = rope(k[:, None], positions[:, None], cfg.rope_theta)[:, 0]
+        else:
+            q = rope(q, positions, cfg.rope_theta)
+            k = rope(k, positions, cfg.rope_theta)
+    return q, k, v
+
+
+def block_out(x: jax.Array, attn: jax.Array, lp: dict, cfg: LlamaConfig,
+              ffn=_mlp, reduce=lambda y: y) -> jax.Array:
+    """Second half of THE transformer block: what follows the attention
+    core (wo + residual, post-norm, FFN + residual). A flavor's true
+    differences are parameters of its own call, never a branch on the
+    caller in here: `ffn(h, lp, cfg)` is the FFN the caller chose
+    (default: the config's own, `_mlp`); `reduce` is the caller's
+    reduction over a manual tp shard (sp's psum), applied to both
+    partial sums."""
+    with jax.named_scope("attn_out"):
+        x = x + reduce(qm(attn.reshape(x.shape[:-1] + (-1,)), lp["wo"]))
+    with jax.named_scope("mlp"):
+        hn = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
+        x = x + reduce(ffn(hn, lp, cfg))
+    return x
+
+
+def _chunk_kv(page_tables: jax.Array, cached_lens: jax.Array,
+              seq_lens: jax.Array, T: int, cfg: LlamaConfig,
+              aligned: bool):
+    """A prefill chunk sub-batch's bookkeeping: positions (Bp, T) and
+    `write(kc, vc, k, v)`, which stores the chunk's (Bp, T, KVH, D) K/V
+    into the paged caches (whole pages through
+    kernels.paged_kv_write_pages when `aligned` allows, else row by
+    row; rows past seq_len go to scratch page 0)."""
+    from dynamo_tpu.engine.attention import use_pallas
+    from dynamo_tpu.engine.kernels import (
+        kv_write_supported,
+        paged_kv_write_pages,
+    )
+
+    Bp, P = page_tables.shape[0], cfg.page_size
+    positions = cached_lens[:, None] + jnp.arange(T)[None, :]
+    new_valid = positions < seq_lens[:, None]              # (Bp, T)
+    page_ids = jnp.take_along_axis(
+        page_tables, positions // P, axis=1)               # (Bp, T)
+    offsets = positions % P
+
+    def flat(a):
+        return a.reshape((Bp * T,) + a.shape[2:])
+
+    f_pages, f_offs, f_valid = flat(page_ids), flat(offsets), flat(new_valid)
+    page_path = (aligned and T % P == 0 and use_pallas()
+                 and kv_write_supported(P, cfg.head_dim))
+    if page_path:
+        # one destination page id per (seq, page-slot); slots entirely past
+        # seq_len go to scratch 0
+        slot_pages = jnp.where(new_valid[:, ::P], page_ids[:, ::P],
+                               0).reshape(-1)             # (Bp*T/P,)
+
+    def to_blocks(a):                      # (Bp,T,KVH,D) → (Bp*T/P,KVH,P,D)
+        a = a.reshape((Bp, T // P, P) + a.shape[2:])
+        return jnp.swapaxes(a, 2, 3).reshape(
+            (Bp * (T // P), a.shape[3], P, a.shape[4]))
+
+    def write(kc, vc, k, v):
+        with jax.named_scope("kv_write"):
+            if page_path:
+                return paged_kv_write_pages(
+                    kc, vc, to_blocks(k), to_blocks(v), slot_pages)
+            return _write_kv(kc, vc, flat(k), flat(v), f_pages, f_offs,
+                             f_valid)
+
+    return positions, write
+
+
+def _decode_kv(page_tables: jax.Array, positions: jax.Array,
+               valid: jax.Array, cfg: LlamaConfig):
+    """A decode sub-batch's bookkeeping: where each lane's one new K/V
+    row goes (page_ids, offsets (B,)) and the attention lengths (B,;
+    0 for padding lanes)."""
+    page_ids = jnp.take_along_axis(
+        page_tables, (positions // cfg.page_size)[:, None], axis=1)[:, 0]
+    offsets = positions % cfg.page_size
+    return page_ids, offsets, jnp.where(valid, positions + 1, 0)
+
+
 def prefill_step(params: dict, k_cache: tuple, v_cache: tuple,
                  tokens: jax.Array, page_table: jax.Array,
                  cached_len: jax.Array, seq_len: jax.Array,
@@ -281,66 +388,20 @@ def paged_forward(params: dict, k_cache: tuple, v_cache: tuple,
     counted and served by the XLA einsum), returns the FINAL-NORMED
     hidden states for every position ((Bp, T, E), k_cache, v_cache) —
     callers pick which positions to project through lm_head."""
-    from dynamo_tpu.engine.attention import use_pallas
-    from dynamo_tpu.engine.kernels import (
-        kv_write_supported,
-        paged_kv_write_pages,
-    )
-
-    Bp, T = tokens.shape
     x = params["embed"][tokens]                            # (Bp, T, E)
-    positions = cached_lens[:, None] + jnp.arange(T)[None, :]
-    new_valid = positions < seq_lens[:, None]              # (Bp, T)
-    page_ids = jnp.take_along_axis(
-        page_tables, positions // cfg.page_size, axis=1)   # (Bp, T)
-    offsets = positions % cfg.page_size
-
-    def flat(a):
-        return a.reshape((Bp * T,) + a.shape[2:])
-
-    f_pages, f_offs, f_valid = flat(page_ids), flat(offsets), flat(new_valid)
-    P = cfg.page_size
-    page_path = (aligned and T % P == 0 and use_pallas()
-                 and kv_write_supported(P, cfg.head_dim))
-    if page_path:
-        # one destination page id per (seq, page-slot); slots entirely past
-        # seq_len go to scratch 0
-        slot_pages = jnp.where(new_valid[:, ::P], page_ids[:, ::P],
-                               0).reshape(-1)             # (Bp*T/P,)
-
-        def to_blocks(a):                                  # (Bp,T,KVH,D) →
-            a = a.reshape(Bp, T // P, P, cfg.num_kv_heads, cfg.head_dim)
-            return jnp.swapaxes(a, 2, 3).reshape(
-                Bp * (T // P), cfg.num_kv_heads, P, cfg.head_dim)
+    positions, write = _chunk_kv(page_tables, cached_lens, seq_lens,
+                                 tokens.shape[1], cfg, aligned)
 
     new_k, new_v = [], []
     for l in range(cfg.num_layers):
         lp = _layer_params(params, l)
-        kc, vc = k_cache[l], v_cache[l]
-        with jax.named_scope("attn_qkv"):
-            hn = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
-            q, k, v = qkv_proj(hn, lp, cfg)
-            q = q.reshape(Bp, T, cfg.num_heads, cfg.head_dim)
-            k = k.reshape(Bp, T, cfg.num_kv_heads, cfg.head_dim)
-            v = v.reshape(Bp, T, cfg.num_kv_heads, cfg.head_dim)
-            q = rope(q, positions, cfg.rope_theta)
-            k = rope(k, positions, cfg.rope_theta)
-        with jax.named_scope("kv_write"):
-            if page_path:
-                kc, vc = paged_kv_write_pages(
-                    kc, vc, to_blocks(k), to_blocks(v), slot_pages)
-            else:
-                kc, vc = _write_kv(kc, vc, flat(k), flat(v), f_pages,
-                                   f_offs, f_valid)
+        q, k, v = block_qkv(x, lp, positions, cfg)
+        kc, vc = write(k_cache[l], v_cache[l], k, v)
         with jax.named_scope("attn_core"):
             attn = paged_attention_prefill(
                 q, kc, vc, page_tables, cached_lens, seq_lens,
                 page_size=cfg.page_size)                   # (Bp, T, H, D)
-        with jax.named_scope("attn_out"):
-            x = x + qm(attn.reshape(Bp, T, -1), lp["wo"])
-        with jax.named_scope("mlp"):
-            hn = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
-            x = x + _mlp(hn, lp, cfg)
+        x = block_out(x, attn, lp, cfg)
         new_k.append(kc)
         new_v.append(vc)
 
@@ -385,35 +446,21 @@ def _decode_once(params: dict, k_cache: tuple, v_cache: tuple,
                  page_tables: jax.Array, valid: jax.Array,
                  cfg: LlamaConfig) -> tuple[jax.Array, tuple, tuple]:
     """One decode iteration body (traced; shared by single/multi-step)."""
-    B = tokens.shape[0]
     x = params["embed"][tokens]                            # (B, E)
-    page_ids = jnp.take_along_axis(
-        page_tables, (positions // cfg.page_size)[:, None], axis=1)[:, 0]
-    offsets = positions % cfg.page_size
-    lengths = jnp.where(valid, positions + 1, 0)
+    page_ids, offsets, lengths = _decode_kv(page_tables, positions, valid,
+                                            cfg)
 
     new_k, new_v = [], []
     for l in range(cfg.num_layers):
         lp = _layer_params(params, l)
-        kc, vc = k_cache[l], v_cache[l]
-        with jax.named_scope("attn_qkv"):
-            hn = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
-            q, k, v = qkv_proj(hn, lp, cfg)
-            q = q.reshape(B, cfg.num_heads, cfg.head_dim)
-            k = k.reshape(B, cfg.num_kv_heads, cfg.head_dim)
-            v = v.reshape(B, cfg.num_kv_heads, cfg.head_dim)
-            q = rope(q[:, None], positions[:, None], cfg.rope_theta)[:, 0]
-            k = rope(k[:, None], positions[:, None], cfg.rope_theta)[:, 0]
+        q, k, v = block_qkv(x, lp, positions, cfg)
         with jax.named_scope("kv_write"):
-            kc, vc = _write_kv(kc, vc, k, v, page_ids, offsets, valid)
+            kc, vc = _write_kv(k_cache[l], v_cache[l], k, v, page_ids,
+                               offsets, valid)
         with jax.named_scope("attn_core"):
             attn = paged_attention_decode(
                 q, kc, vc, lengths, page_tables, page_size=cfg.page_size)
-        with jax.named_scope("attn_out"):
-            x = x + qm(attn.reshape(B, -1), lp["wo"])
-        with jax.named_scope("mlp"):
-            hn = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
-            x = x + _mlp(hn, lp, cfg)
+        x = block_out(x, attn, lp, cfg)
         new_k.append(kc)
         new_v.append(vc)
 
@@ -515,77 +562,30 @@ def _mixed_forward(params: dict, k_cache: tuple, v_cache: tuple,
     interleaving cannot perturb either side's numerics vs the
     stand-alone steps. Returns (chunk hidden (Bp, T, E) final-normed,
     decode hidden (B, E) final-normed, k_cache, v_cache)."""
-    from dynamo_tpu.engine.attention import mixed_attention, use_pallas
-    from dynamo_tpu.engine.kernels import (
-        kv_write_supported,
-        paged_kv_write_pages,
-    )
+    from dynamo_tpu.engine.attention import mixed_attention
 
-    Bp, T = ch_tokens.shape
-    B = d_tokens.shape[0]
-    # chunk-side bookkeeping (as paged_forward)
     xc = params["embed"][ch_tokens]                        # (Bp, T, E)
-    c_positions = ch_cached[:, None] + jnp.arange(T)[None, :]
-    new_valid = c_positions < ch_seq_lens[:, None]
-    page_ids = jnp.take_along_axis(
-        ch_tables, c_positions // cfg.page_size, axis=1)
-    offsets = c_positions % cfg.page_size
-
-    def flat(a):
-        return a.reshape((Bp * T,) + a.shape[2:])
-
-    f_pages, f_offs, f_valid = flat(page_ids), flat(offsets), flat(new_valid)
-    P = cfg.page_size
-    page_path = (aligned and T % P == 0 and use_pallas()
-                 and kv_write_supported(P, cfg.head_dim))
-    if page_path:
-        slot_pages = jnp.where(new_valid[:, ::P], page_ids[:, ::P],
-                               0).reshape(-1)
-
-        def to_blocks(a):
-            a = a.reshape(Bp, T // P, P, cfg.num_kv_heads, cfg.head_dim)
-            return jnp.swapaxes(a, 2, 3).reshape(
-                Bp * (T // P), cfg.num_kv_heads, P, cfg.head_dim)
-
-    # decode-side bookkeeping (as _decode_once)
+    c_positions, write_c = _chunk_kv(ch_tables, ch_cached, ch_seq_lens,
+                                     ch_tokens.shape[1], cfg, aligned)
     xd = params["embed"][d_tokens]                         # (B, E)
-    d_page_ids = jnp.take_along_axis(
-        d_tables, (d_positions // cfg.page_size)[:, None], axis=1)[:, 0]
-    d_offsets = d_positions % cfg.page_size
-    d_lengths = jnp.where(d_valid, d_positions + 1, 0)
+    d_page_ids, d_offsets, d_lengths = _decode_kv(d_tables, d_positions,
+                                                  d_valid, cfg)
 
     new_k, new_v = [], []
     for l in range(cfg.num_layers):
         lp = _layer_params(params, l)
-        kc, vc = k_cache[l], v_cache[l]
-        hn = rms_norm(xc, lp["attn_norm"], cfg.rms_eps)
-        q, k, v = qkv_proj(hn, lp, cfg)
-        q = q.reshape(Bp, T, cfg.num_heads, cfg.head_dim)
-        k = k.reshape(Bp, T, cfg.num_kv_heads, cfg.head_dim)
-        v = v.reshape(Bp, T, cfg.num_kv_heads, cfg.head_dim)
-        q = rope(q, c_positions, cfg.rope_theta)
-        k = rope(k, c_positions, cfg.rope_theta)
-        hnd = rms_norm(xd, lp["attn_norm"], cfg.rms_eps)
-        qd, kd, vd = qkv_proj(hnd, lp, cfg)
-        qd = qd.reshape(B, cfg.num_heads, cfg.head_dim)
-        kd = kd.reshape(B, cfg.num_kv_heads, cfg.head_dim)
-        vd = vd.reshape(B, cfg.num_kv_heads, cfg.head_dim)
-        qd = rope(qd[:, None], d_positions[:, None], cfg.rope_theta)[:, 0]
-        kd = rope(kd[:, None], d_positions[:, None], cfg.rope_theta)[:, 0]
-        if page_path:
-            kc, vc = paged_kv_write_pages(
-                kc, vc, to_blocks(k), to_blocks(v), slot_pages)
-        else:
-            kc, vc = _write_kv(kc, vc, flat(k), flat(v), f_pages, f_offs,
-                               f_valid)
-        kc, vc = _write_kv(kc, vc, kd, vd, d_page_ids, d_offsets, d_valid)
-        attn_d, attn_c = mixed_attention(
-            qd, q, kc, vc, d_lengths, d_tables, ch_tables, c_positions,
-            ch_seq_lens, page_size=cfg.page_size)
-        xc = xc + qm(attn_c.reshape(Bp, T, -1), lp["wo"])
-        xc = xc + _mlp(rms_norm(xc, lp["mlp_norm"], cfg.rms_eps), lp, cfg)
-        xd = xd + qm(attn_d.reshape(B, -1), lp["wo"])
-        xd = xd + _mlp(rms_norm(xd, lp["mlp_norm"], cfg.rms_eps), lp, cfg)
+        q, k, v = block_qkv(xc, lp, c_positions, cfg)
+        qd, kd, vd = block_qkv(xd, lp, d_positions, cfg)
+        kc, vc = write_c(k_cache[l], v_cache[l], k, v)
+        with jax.named_scope("kv_write"):
+            kc, vc = _write_kv(kc, vc, kd, vd, d_page_ids, d_offsets,
+                               d_valid)
+        with jax.named_scope("attn_core"):
+            attn_d, attn_c = mixed_attention(
+                qd, q, kc, vc, d_lengths, d_tables, ch_tables, c_positions,
+                ch_seq_lens, page_size=cfg.page_size)
+        xc = block_out(xc, attn_c, lp, cfg)
+        xd = block_out(xd, attn_d, lp, cfg)
         new_k.append(kc)
         new_v.append(vc)
 
@@ -708,27 +708,21 @@ def ragged_prefill_decode(params: dict, k_cache: tuple, v_cache: tuple,
         topk_logprobs,
     )
 
-    Tb = tokens.shape[0]
     x = params["embed"][tokens]                            # (Tb, E)
     qpos = jnp.where(valid, positions, -1).astype(jnp.int32)
 
     new_k, new_v = [], []
     for l in range(cfg.num_layers):
         lp = _layer_params(params, l)
-        kc, vc = k_cache[l], v_cache[l]
-        hn = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
-        q, k, v = qkv_proj(hn, lp, cfg)
-        q = q.reshape(Tb, cfg.num_heads, cfg.head_dim)
-        k = k.reshape(Tb, cfg.num_kv_heads, cfg.head_dim)
-        v = v.reshape(Tb, cfg.num_kv_heads, cfg.head_dim)
-        q = rope(q, positions, cfg.rope_theta)
-        k = rope(k, positions, cfg.rope_theta)
-        kc, vc = _write_kv(kc, vc, k, v, page_ids, offsets, valid)
-        attn = ragged_attention(q, kc, vc, qpos, token_lanes, lane_tables,
-                                page_size=cfg.page_size)   # (Tb, H, D)
-        x = x + qm(attn.reshape(Tb, -1), lp["wo"])
-        hn = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
-        x = x + _mlp(hn, lp, cfg)
+        q, k, v = block_qkv(x, lp, positions, cfg)
+        with jax.named_scope("kv_write"):
+            kc, vc = _write_kv(k_cache[l], v_cache[l], k, v, page_ids,
+                               offsets, valid)
+        with jax.named_scope("attn_core"):
+            attn = ragged_attention(q, kc, vc, qpos, token_lanes,
+                                    lane_tables,
+                                    page_size=cfg.page_size)   # (Tb, H, D)
+        x = block_out(x, attn, lp, cfg)
         new_k.append(kc)
         new_v.append(vc)
 
@@ -837,30 +831,32 @@ def decode_multi_step_guided(params: dict, k_cache, v_cache,
     return out, k_cache, v_cache
 
 
-def dense_attention(x: jax.Array, lp: dict, positions: jax.Array,
-                    mask: jax.Array, cfg: "LlamaConfig") -> jax.Array:
-    """One layer's attention sub-block over a dense (unpaged) sequence:
-    pre-norm, RoPE'd GQA attention under ``mask``, wo projection,
-    residual add. Shared by the cache-free forwards (MoE parity forward,
-    pipeline-parallel stages) so the attention math exists exactly once
-    outside the paged path. x: (B, T, E); mask: (T, T) bool."""
-    B, T, _ = x.shape
-    H, KVH, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
-    q, k, v = qkv_proj(h, lp, cfg)
-    q = rope(q.reshape(B, T, H, D), positions, cfg.rope_theta)
-    k = rope(k.reshape(B, T, KVH, D), positions, cfg.rope_theta)
-    v = v.reshape(B, T, KVH, D)
+def dense_attend(q: jax.Array, k: jax.Array, v: jax.Array,
+                 mask: jax.Array) -> jax.Array:
+    """The attention core of the cache-free forwards (embeddings, MoE
+    parity forward, pipeline-parallel stages): GQA attention of a dense
+    (unpaged) sequence under ``mask`` (T, T) bool, so the dense
+    attention math exists exactly once outside the paged path.
+    q: (B, T, H, D); k, v: (B, T, KVH, D)."""
+    H, KVH, D = q.shape[-2], k.shape[-2], q.shape[-1]
     if KVH != H:
         k = jnp.repeat(k, H // KVH, axis=2)
         v = jnp.repeat(v, H // KVH, axis=2)
-    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k,
-                        preferred_element_type=jnp.float32)
-    scores = scores / jnp.sqrt(jnp.float32(D))
-    scores = jnp.where(mask[None, None], scores, -1e30)
-    attn = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(scores, axis=-1),
-                      v.astype(jnp.float32)).astype(x.dtype)
-    return x + qm(attn.reshape(B, T, H * D), lp["wo"])
+    with jax.named_scope("attn_core"):
+        scores = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                            preferred_element_type=jnp.float32)
+        scores = scores / jnp.sqrt(jnp.float32(D))
+        scores = jnp.where(mask[None, None], scores, -1e30)
+        return jnp.einsum(
+            "bhqk,bkhd->bqhd", jax.nn.softmax(scores, axis=-1),
+            v.astype(jnp.float32)).astype(q.dtype)
+
+
+def dense_layer(x: jax.Array, lp: dict, positions: jax.Array,
+                mask: jax.Array, cfg: LlamaConfig, **out_kw) -> jax.Array:
+    """One cache-free layer: the block around `dense_attend`."""
+    q, k, v = block_qkv(x, lp, positions, cfg)
+    return block_out(x, dense_attend(q, k, v, mask), lp, cfg, **out_kw)
 
 
 @partial(jax.jit, static_argnames=("cfg",))
@@ -870,10 +866,10 @@ def embed_batch(params: dict, tokens: jax.Array, lengths: jax.Array,
     valid lengths → (B, E) L2-normalized vectors.
 
     Dense cache-free forward (embeddings never decode, so no paged KV):
-    per-layer attention via the shared `dense_attention` block, final
-    rms_norm, masked mean over valid positions. Serves `/v1/embeddings`
-    for the real engine (openai.rs:1125 parity; the reference delegates
-    to an embedding engine — we own ours)."""
+    `dense_layer` per layer, final rms_norm, masked
+    mean over valid positions. Serves `/v1/embeddings` for the real
+    engine (openai.rs:1125 parity; the reference delegates to an
+    embedding engine — we own ours)."""
     B, T = tokens.shape
     positions = jnp.arange(T)[None, :]
     valid = positions < lengths[:, None]                    # (B, T)
@@ -881,9 +877,7 @@ def embed_batch(params: dict, tokens: jax.Array, lengths: jax.Array,
     mask = jnp.tril(jnp.ones((T, T), bool))
     x = params["embed"][tokens]
     for l in range(cfg.num_layers):
-        lp = _layer_params(params, l)
-        x = dense_attention(x, lp, positions, mask, cfg)
-        x = x + _mlp(rms_norm(x, lp["mlp_norm"], cfg.rms_eps), lp, cfg)
+        x = dense_layer(x, _layer_params(params, l), positions, mask, cfg)
     h = rms_norm(x, params["final_norm"], cfg.rms_eps).astype(jnp.float32)
     h = jnp.where(valid[..., None], h, 0.0)
     pooled = h.sum(axis=1) / jnp.maximum(

@@ -37,31 +37,26 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from dynamo_tpu.engine.quant import qm
 from dynamo_tpu.models.llama import (
     LlamaConfig,
+    _decode_kv,
     _layer_params,
-    _mlp,
     _write_kv,
-    dense_attention,
-    qkv_proj,
+    block_out,
+    block_qkv,
+    dense_layer,
     rms_norm,
-    rope,
 )
 
 
 def _stage_layers(params_local: dict, x: jax.Array, positions: jax.Array,
                   cfg: LlamaConfig) -> jax.Array:
     """Run this stage's layer slice over activations x (B, T, E)."""
-    B, T, _ = x.shape
+    T = x.shape[1]
     mask = jnp.tril(jnp.ones((T, T), bool))
-    n_local = params_local["attn_norm"].shape[0]
 
     def one_layer(x, lp):
-        x = dense_attention(x, lp, positions, mask, cfg)
-        x = x + _mlp(rms_norm(x, lp["mlp_norm"], cfg.rms_eps), lp,
-                     cfg)
-        return x, None
+        return dense_layer(x, lp, positions, mask, cfg), None
 
     x, _ = lax.scan(one_layer, x, params_local)
-    assert x.shape[0] == B and n_local >= 1
     return x
 
 
@@ -229,21 +224,16 @@ def _pp_prefill_paged_local(params, kc_all, vc_all, tokens_c,
         for l in range(L_local):
             lp = _layer_params(params, l)
             kc, vc = kc_all[l], vc_all[l]
-            hn = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
-            q, k, v = qkv_proj(hn, lp, cfg)
-            q = q.reshape(B, Tc, cfg.num_heads, cfg.head_dim)
-            k = k.reshape(B, Tc, cfg.num_kv_heads, cfg.head_dim)
-            v = v.reshape(B, Tc, cfg.num_kv_heads, cfg.head_dim)
-            q = rope(q, positions, cfg.rope_theta)
-            k = rope(k, positions, cfg.rope_theta)
-            kc, vc = _write_kv(kc, vc, flat(k), flat(v), flat(page_ids),
-                               flat(offsets), flat(new_valid))
-            attn = paged_attention_prefill(
-                q, kc, vc, page_tables, positions[:, 0], seq_lens,
-                page_size=P_)                               # (B, Tc, H, D)
-            x = x + qm(attn.reshape(B, Tc, -1), lp["wo"])
-            hn = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
-            x = x + _mlp(hn, lp, cfg)
+            q, k, v = block_qkv(x, lp, positions, cfg)
+            with jax.named_scope("kv_write"):
+                kc, vc = _write_kv(kc, vc, flat(k), flat(v),
+                                   flat(page_ids), flat(offsets),
+                                   flat(new_valid))
+            with jax.named_scope("attn_core"):
+                attn = paged_attention_prefill(
+                    q, kc, vc, page_tables, positions[:, 0], seq_lens,
+                    page_size=P_)                           # (B, Tc, H, D)
+            x = block_out(x, attn, lp, cfg)
             new_k.append(kc)
             new_v.append(vc)
         kc_all = jnp.stack(new_k)
@@ -386,28 +376,20 @@ def _pp_decode_local(params, k_cache, v_cache, tokens0, positions,
         valid_m = lax.dynamic_index_in_dim(valid, m, 0, False) & active
 
         x_in = jnp.where(stage == 0, params["embed"][tok_m], x_recv)
-        page_ids = jnp.take_along_axis(
-            tbl_m, (pos_m // cfg.page_size)[:, None], axis=1)[:, 0]
-        offsets = pos_m % cfg.page_size
-        lengths = jnp.where(valid_m, pos_m + 1, 0)
+        page_ids, offsets, lengths = _decode_kv(tbl_m, pos_m, valid_m, cfg)
         x = x_in
         new_k, new_v = [], []
         for l in range(L_local):
             lp = _layer_params(params, l)
             kc, vc = kc_all[l], vc_all[l]
-            hn = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
-            q, k, v = qkv_proj(hn, lp, cfg)
-            q = q.reshape(Bm, cfg.num_heads, cfg.head_dim)
-            k = k.reshape(Bm, cfg.num_kv_heads, cfg.head_dim)
-            v = v.reshape(Bm, cfg.num_kv_heads, cfg.head_dim)
-            q = rope(q[:, None], pos_m[:, None], cfg.rope_theta)[:, 0]
-            k = rope(k[:, None], pos_m[:, None], cfg.rope_theta)[:, 0]
-            kc, vc = _write_kv(kc, vc, k, v, page_ids, offsets, valid_m)
-            attn = paged_attention_decode(
-                q, kc, vc, lengths, tbl_m, page_size=cfg.page_size)
-            x = x + qm(attn.reshape(Bm, -1), lp["wo"])
-            hn = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
-            x = x + _mlp(hn, lp, cfg)
+            q, k, v = block_qkv(x, lp, pos_m, cfg)
+            with jax.named_scope("kv_write"):
+                kc, vc = _write_kv(kc, vc, k, v, page_ids, offsets,
+                                   valid_m)
+            with jax.named_scope("attn_core"):
+                attn = paged_attention_decode(
+                    q, kc, vc, lengths, tbl_m, page_size=cfg.page_size)
+            x = block_out(x, attn, lp, cfg)
             new_k.append(kc)
             new_v.append(vc)
         kc_all = jnp.stack(new_k)

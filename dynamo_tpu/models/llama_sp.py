@@ -34,10 +34,9 @@ from dynamo_tpu.engine.ring_attention import ring_attention_local
 from dynamo_tpu.models.llama import (
     LlamaConfig,
     _layer_params,
-    _swiglu,
-    qkv_proj,
+    block_out,
+    block_qkv,
     rms_norm,
-    rope,
 )
 
 
@@ -58,7 +57,7 @@ def _sp_forward_local(params: dict, tokens: jax.Array, cfg: LlamaConfig,
 
     idx = lax.axis_index(axis)
     sp_size = lax.psum(1, axis)
-    B, Tc = tokens.shape
+    Tc = tokens.shape[1]
     if layout == "zigzag":
         positions = zigzag_positions(idx, Tc, sp_size)[None, :]
     else:
@@ -78,22 +77,15 @@ def _sp_forward_local(params: dict, tokens: jax.Array, cfg: LlamaConfig,
     def reduce_tp(y):
         return lax.psum(y, tp_axis) if tp_axis else y
 
-    D = cfg.head_dim
     ks, vs = [], []
     for l in range(cfg.num_layers):
         lp = _layer_params(params, l)
-        h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
-        q, k, v = qkv_proj(h, lp, cfg)
-        q = rope(q.reshape(B, Tc, -1, D), positions, cfg.rope_theta)
-        k = rope(k.reshape(B, Tc, -1, D), positions, cfg.rope_theta)
-        v = v.reshape(B, Tc, -1, D)
+        q, k, v = block_qkv(x, lp, positions, cfg)
         ks.append(k)
         vs.append(v)
         attn = ring_attention_local(q, k, v, axis, causal=True,
                                     layout=layout)
-        x = x + reduce_tp(qm(attn.reshape(B, Tc, -1), lp["wo"]))
-        hn = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
-        x = x + reduce_tp(_swiglu(hn, lp))
+        x = block_out(x, attn, lp, cfg, reduce=reduce_tp)
     xf = rms_norm(x[:, -1], params["final_norm"], cfg.rms_eps)
     logits = qm(xf, params["lm_head"]).astype(jnp.float32)
     return logits[None], jnp.stack(ks), jnp.stack(vs)

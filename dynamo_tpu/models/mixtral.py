@@ -35,7 +35,8 @@ from jax.sharding import PartitionSpec as P
 from dynamo_tpu.engine.quant import qm
 from dynamo_tpu.models.llama import (
     LlamaConfig,
-    dense_attention,
+    _layer_params,
+    dense_layer,
     rms_norm,
 )
 
@@ -224,10 +225,6 @@ def moe_mlp_reference(h: jax.Array, lp: dict, cfg: MoeConfig) -> jax.Array:
     return out.reshape(hn.shape)
 
 
-def _layer_params(params: dict, l: int) -> dict:
-    return jax.tree.map(lambda w: w[l], params["layers"])
-
-
 @partial(jax.jit, static_argnames=("cfg", "dispatch", "capacity_factor"))
 def moe_forward(params: dict, tokens: jax.Array, cfg: MoeConfig,
                 dispatch: str = "dense",
@@ -240,7 +237,7 @@ def moe_forward(params: dict, tokens: jax.Array, cfg: MoeConfig,
     capacity_factor tunes drop rate vs FLOPs)."""
     if dispatch not in ("dense", "capacity"):
         raise ValueError(f"unknown dispatch mode {dispatch!r}")
-    B, T = tokens.shape
+    T = tokens.shape[1]
     positions = jnp.arange(T)[None, :]
     x = params["embed"][tokens]
     mask = jnp.tril(jnp.ones((T, T), bool))
@@ -249,10 +246,8 @@ def moe_forward(params: dict, tokens: jax.Array, cfg: MoeConfig,
     else:
         mlp = partial(moe_mlp_capacity, capacity_factor=capacity_factor)
     for l in range(cfg.num_layers):
-        lp = _layer_params(params, l)
-        x = dense_attention(x, lp, positions, mask, cfg)
-        x = x + mlp(rms_norm(x, lp["mlp_norm"], cfg.rms_eps), lp,
-                    cfg).astype(x.dtype)
+        x = dense_layer(x, _layer_params(params, l), positions, mask, cfg,
+                        ffn=mlp)
     xf = rms_norm(x[:, -1], params["final_norm"], cfg.rms_eps)
     return qm(xf, params["lm_head"]).astype(jnp.float32)
 

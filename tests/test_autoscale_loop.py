@@ -381,13 +381,13 @@ async def _run_autoscale_gate(duration_s: float, base_rps: float) -> None:
         await store_server.stop()
 
 
-async def test_autoscale_loop_smoke():
+async def test_autoscale_loop_smoke(frozen_heap):
     """`make autoscale-smoke` body: the full closed loop in ~20 s."""
     await _run_autoscale_gate(duration_s=12.0, base_rps=15.0)
 
 
 @pytest.mark.slow
-async def test_autoscale_loop_soak():
+async def test_autoscale_loop_soak(frozen_heap):
     """Longer diurnal day, same gate — catches slow drifts (leaked
     workers, revision stalls) the smoke's single cycle can miss."""
     await _run_autoscale_gate(duration_s=40.0, base_rps=12.0)

@@ -467,7 +467,7 @@ async def test_debug_control_unarmed_503(monkeypatch):
         await rt.close()
 
 
-async def test_control_loop_smoke(monkeypatch, tmp_path):
+async def test_control_loop_smoke(monkeypatch, tmp_path, frozen_heap):
     """`make control-smoke` body: the autoscale SLA gate with every
     controller armed. Gate: no fast_burn/breach after warmup, zero
     non-abandoned streams dropped, >=1 action from each controller, and

@@ -294,6 +294,12 @@ async def test_corrupt_frame_logged_and_counted(caplog):
         codec.write_frame(writer, msg)
         writer.write(struct.pack(">I", 4) + b"\xc1\xc1\xc1\xc1")
         await writer.drain()
+        # stay connected until the CLIENT drops the link (that is what
+        # the test asserts), then close this end: since Python 3.12
+        # `Server.wait_closed()` below waits for every connection, and a
+        # handler that returns with its writer open keeps one forever
+        await reader.read()
+        writer.close()
 
     server = await asyncio.start_server(fake_server, "127.0.0.1", 0)
     port = server.sockets[0].getsockname()[1]

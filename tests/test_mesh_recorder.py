@@ -145,10 +145,8 @@ def test_tp2_llama_layers_hlo_matches_megatron_formula(cpu_mesh_devices):
     from dynamo_tpu.models.llama import (
         LlamaConfig,
         _layer_params,
-        _mlp,
-        dense_attention,
+        dense_layer,
         init_params,
-        rms_norm,
     )
 
     # KVH == H so GQA head-repeat can't force its own collective; f32
@@ -169,10 +167,7 @@ def test_tp2_llama_layers_hlo_matches_megatron_formula(cpu_mesh_devices):
         positions = jnp.arange(T)[None, :]
         mask = jnp.tril(jnp.ones((T, T), bool))
         for l in range(cfg.num_layers):
-            lp = _layer_params(p, l)
-            h = dense_attention(h, lp, positions, mask, cfg)
-            h = h + _mlp(rms_norm(h, lp["mlp_norm"], cfg.rms_eps),
-                         lp, cfg)
+            h = dense_layer(h, _layer_params(p, l), positions, mask, cfg)
         return h
 
     fn = jax.jit(layers_fwd,

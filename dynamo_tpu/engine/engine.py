@@ -124,6 +124,15 @@ def _sp_writeback(k_cache: tuple, v_cache: tuple, k_all, v_all,
     return new_k, new_v
 
 
+@jax.jit
+def _first_tokens_into(tokens, sampled, take, col) -> "jax.Array":
+    """A burst's (B,) input tokens with the lanes in `take` filled from
+    a first-token sampler's packed output (row 0 = ids, f32), column
+    `col`: on the device, so the burst is launched before the sampler
+    is synced."""
+    return jnp.where(take, sampled[0, col].astype(jnp.int32), tokens)
+
+
 def _topk_list(ids_vec, lps_vec, width: int) -> list:
     """[[token_id, logprob], ...] from parallel packed top-k vectors —
     the ONE unpacker for every burst flavor's packed rows (prefill,
@@ -1345,7 +1354,10 @@ class TpuEngine:
         other (_chunk_rounds), so a prompt waits for those ahead of it
         and not for those behind; sequences that end in one round share
         one device call + ONE host sync, so a batch of one-chunk prompts
-        is one round and one group."""
+        is one round and one group. Where the lanes allow it the next
+        decode burst is launched behind the samplers before any of them
+        is synced (_chain_burst): the first tokens reach the host while
+        it runs."""
         pending = [s for s in self._running if not s.prefilled]
         if not pending:
             return False
@@ -1403,10 +1415,67 @@ class TpuEngine:
             max(len(s.prompt) - s.cached_len, 0) for s in pending))
         async with self._device_lock:
             await asyncio.to_thread(prefill_all)
+            chained = by_sequence and await self._chain_burst(firsts)
+            # a lane its first token ends is overshoot in the chained
+            # burst, which still writes to its pages
+            deferred = self._inflight["deferred"] if chained else None
             for group, sampled, tk in firsts:
                 # ONE host sync a group; later rounds are still running
                 packed = await asyncio.to_thread(self._host_sync, sampled)
-                self._emit_first_tokens(group, packed, tk, draft_done=True)
+                self._defer_releases = deferred
+                try:
+                    self._emit_first_tokens(group, packed, tk,
+                                            draft_done=True)
+                finally:
+                    self._defer_releases = None
+        return True
+
+    async def _chain_burst(self, firsts: list) -> bool:
+        """Launch the decode burst that follows a prefill wave behind
+        the wave's first-token samplers, before any is synced: lanes
+        already decoding enter from host state, the wave's lanes
+        (`firsts`: _prefill_pending's groups) where their first token
+        will leave them, that token taken from the sampler's output on
+        the device. The host would build the same inputs one sync
+        later. True when the burst is in flight; False leaves the
+        caller's sync-then-_decode_iter order, which is every case the
+        speculative burst leaves too (a lane that needs the constrained
+        burst or is cancelled, a burst in flight, a pool that cannot
+        cover the lanes without a preemption) and the ragged round. A
+        lane its first token ends is overshoot in this one burst:
+        _emit_burst skips it and its pages wait in the burst's
+        `deferred`. Call under the device lock."""
+        cfg = self.config
+        batch = list(self._running)
+        fresh = {id(s) for group, _, _ in firsts for s in group}
+        k = cfg.decode_steps_per_sync
+        if (self._inflight is not None or not cfg.pipeline_bursts
+                or self._ragged_active()
+                or any(s.needs_constrained or s.ctx.is_cancelled()
+                       for s in batch)
+                # every lane ends with its first token: all overshoot
+                or not any(s.max_tokens - s.generated > (id(s) in fresh)
+                           for s in batch)
+                or not all(self._cover(
+                    s, (len(s.prompt) if id(s) in fresh else s.pos)
+                    + k - 1) for s in batch)):
+            return False
+        b = cfg.max_batch_size
+        with self._span("decode_prep"):
+            lanes = self._decode_lane_arrays(batch, fresh)
+            lane_of = {id(s): i for i, s in enumerate(batch)}
+            merges = []
+            for group, sampled, _ in firsts:
+                take = np.zeros(b, dtype=bool)
+                col = np.zeros(b, dtype=np.int32)
+                for j, s in enumerate(group):
+                    take[lane_of[id(s)]] = True
+                    col[lane_of[id(s)]] = j
+                merges.append((sampled, take, col))
+        tk = self.TOPK_WIDTH if any(s.wants_topk for s in batch) else 0
+        await asyncio.to_thread(
+            self._launch_burst, batch, lanes, k, tk, merges)
+        self.metrics.chained_refills.inc()
         return True
 
     def _first_token_packed(self, pending: list[_Seq], last_logits):
@@ -1418,19 +1487,28 @@ class TpuEngine:
 
     def _first_token_dispatch(self, pending: list[_Seq], last_logits):
         """Launch the sampling of every just-prefilled sequence's FIRST
-        token in one device call: pad the last-token logits to the
-        fixed max_batch_size width (so sampling compiles exactly once),
-        overlay grammar masks and penalties, run sample_tokens_lp.
-        Returns (sampled on the device (2 + 2*tk, width), tk) without
-        waiting. Call under the device lock, in a thread. Shared by the
+        token in one device call, max_batch_size wide (rows past the
+        group repeat its first). last_logits[id(s)] = (a round's (Bp, V)
+        logits, s's row in it). A group out of ONE round (every group
+        of the by-sequence order) hands that array and a row vector to
+        the sampler, which gathers inside the program: one launch, one
+        program a prefill width. Rows out of several rounds (lockstep
+        order of draft and pp engines) are sliced and stacked first.
+        Grammar masks and penalties overlay the gathered rows. Returns
+        (sampled on the device (2 + 2*tk, width), tk) without waiting.
+        Call under the device lock, in a thread. Shared by the
         all-at-once prefill and the budgeted scheduler's completions so
         first-token semantics can never diverge."""
         cfg, mcfg = self.config, self.model_cfg
         width = cfg.max_batch_size
         with self._span("sample_first"):
-            stack = [last_logits[id(s)] for s in pending]
-            while len(stack) < width:
-                stack.append(stack[0])
+            srcs = [last_logits[id(s)] for s in pending]
+            srcs += [srcs[0]] * (width - len(srcs))
+            logits, rows = srcs[0][0], None
+            if all(a is logits for a, _ in srcs):
+                rows = np.asarray([r for _, r in srcs], dtype=np.int32)
+            else:
+                logits = jax.numpy.stack([a[r] for a, r in srcs])
             guided_mask = None
             if any(s.guided is not None for s in pending):
                 # first sampled token must already respect the grammar
@@ -1452,19 +1530,20 @@ class TpuEngine:
                 vals += [vals[0]] * (width - len(pending))
                 return np.asarray(vals, dtype=dtype)
 
-            logits_stack = jax.numpy.stack(stack)
+            if rows is not None and (penalty_args is not None
+                                     or guided_mask is not None):
+                logits, rows = logits[rows], None
             if penalty_args is not None:
                 from dynamo_tpu.engine.sampling import apply_penalties
 
                 rep_a, freq_a, pres_a, pc, oc = penalty_args
-                logits_stack = apply_penalties(
-                    logits_stack, jax.numpy.asarray(pc),
+                logits = apply_penalties(
+                    logits, jax.numpy.asarray(pc),
                     jax.numpy.asarray(oc),
                     jax.numpy.asarray(rep_a), jax.numpy.asarray(freq_a),
                     jax.numpy.asarray(pres_a))
             if guided_mask is not None:
-                logits_stack = logits_stack + jax.numpy.asarray(
-                    guided_mask)
+                logits = logits + jax.numpy.asarray(guided_mask)
             tk = (self.TOPK_WIDTH
                   if any(s.wants_topk for s in pending) else 0)
             lane_arrays = (
@@ -1474,14 +1553,16 @@ class TpuEngine:
                 arr(lambda s: s.req.sampling.top_p, np.float32),
                 arr(lambda s: s.req.sampling.top_k, np.int32),
                 arr(lambda s: s.req.sampling.min_p, np.float32))
-        trk = self.metrics.compile.track("sample_first", (width, tk))
+        # the sampler is one program a height of the logits it is handed
+        trk = self.metrics.compile.track(
+            "sample_first", (width, tk, logits.shape[0]))
         led = self.memory_ledger
         if led is not None:
             led.on_dispatch(trk.entry, trk.shape, compiled=trk.compiled)
         with trk:
             sampled = self._mesh_dispatch(
-                trk, sample_tokens_lp, logits_stack, *lane_arrays, topk_lp=tk,
-                span_tokens=len(pending))
+                trk, sample_tokens_lp, logits, *lane_arrays, rows=rows,
+                topk_lp=tk, span_tokens=len(pending))
         rec = self.step_recorder
         if rec is not None:
             rec.record("sample_first", trk.shape, trk.elapsed_s,
@@ -1676,26 +1757,8 @@ class TpuEngine:
                 ch_seq_lens[i] = off + n
 
             b = cfg.max_batch_size
-            tokens = np.zeros(b, dtype=np.int32)
-            positions = np.zeros(b, dtype=np.int32)
-            page_tables = np.zeros((b, mcfg.max_pages_per_seq),
-                                   dtype=np.int32)
-            valid = np.zeros(b, dtype=bool)
-            seeds = np.zeros(b, dtype=np.uint32)
-            steps = np.zeros(b, dtype=np.uint32)
-            temps = np.zeros(b, dtype=np.float32)
-            top_ps = np.ones(b, dtype=np.float32)
-            top_ks = np.zeros(b, dtype=np.int32)
-            for i, s in enumerate(batch):
-                tokens[i] = s.next_token
-                positions[i] = s.pos
-                page_tables[i, :len(s.pages)] = s.pages
-                valid[i] = True
-                seeds[i] = s.seed
-                steps[i] = s.generated
-                temps[i] = s.req.sampling.temperature
-                top_ps[i] = s.req.sampling.top_p
-                top_ks[i] = s.req.sampling.top_k
+            (tokens, positions, page_tables, valid, seeds, steps, temps,
+             top_ps, top_ks) = self._decode_lane_arrays(batch)
             tk = self.TOPK_WIDTH if any(s.wants_topk for s in batch) else 0
 
         trk = self.metrics.compile.track(
@@ -1751,7 +1814,7 @@ class TpuEngine:
             offsets[id(s)] += chunk_lens[i]
             s.prefill_pos = offsets[id(s)]
             if s.prefill_pos >= len(s.prompt):
-                done_logits[id(s)] = ch_logits[i]
+                done_logits[id(s)] = (ch_logits, i)
         self._emit_burst(batch, packed, k_steps, tk)
         await self._finish_first_tokens(picks, done_logits)
         return True
@@ -1836,7 +1899,7 @@ class TpuEngine:
         for i, s in enumerate(picks):
             offsets[id(s)] += takes[i]
             if offsets[id(s)] >= len(s.prompt):
-                done[id(s)] = logits[i]
+                done[id(s)] = (logits, i)
         return done
 
     # -- decode -------------------------------------------------------------
@@ -1873,6 +1936,112 @@ class TpuEngine:
                     runnable.remove(s)
                     break
                 s.pages.append(pid)
+
+    def _cover(self, seq: _Seq, last_pos: int) -> bool:
+        """Grow seq.pages to hold position `last_pos` without preempting
+        anyone. False when the page table or the pool cannot; pages
+        taken so far stay attached (no leak: the lane's next burst
+        wants them anyway)."""
+        mcfg = self.model_cfg
+        need = last_pos // mcfg.page_size + 1
+        if need > mcfg.max_pages_per_seq:
+            return False
+        while len(seq.pages) < need:
+            pid = self.pool.allocate_page()
+            if pid is None:
+                return False
+            seq.pages.append(pid)
+        return True
+
+    def _decode_lane_arrays(self, batch: list[_Seq], fresh=()) -> tuple:
+        """The nine per-lane inputs of a decode burst, max_batch_size
+        wide, in the order every decode entry takes them: tokens,
+        positions, page_tables, valid, seeds, steps, temps, top_ps,
+        top_ks. A lane whose id is in `fresh` has been prefilled on the
+        device but its first token is not on the host yet: it enters
+        where _emit_first_tokens will leave it (position len(prompt),
+        one step on) and its token is left 0 for the device to fill."""
+        b = self.config.max_batch_size
+        tokens = np.zeros(b, dtype=np.int32)
+        positions = np.zeros(b, dtype=np.int32)
+        page_tables = np.zeros((b, self.model_cfg.max_pages_per_seq),
+                               dtype=np.int32)
+        valid = np.zeros(b, dtype=bool)
+        seeds = np.zeros(b, dtype=np.uint32)
+        steps = np.zeros(b, dtype=np.uint32)
+        temps = np.zeros(b, dtype=np.float32)
+        top_ps = np.ones(b, dtype=np.float32)
+        top_ks = np.zeros(b, dtype=np.int32)
+        for i, s in enumerate(batch):
+            new = id(s) in fresh
+            tokens[i] = 0 if new else s.next_token
+            positions[i] = len(s.prompt) if new else s.pos
+            page_tables[i, :len(s.pages)] = s.pages
+            valid[i] = True
+            seeds[i] = s.seed
+            steps[i] = s.generated + new
+            temps[i] = s.req.sampling.temperature
+            top_ps[i] = s.req.sampling.top_p
+            top_ks[i] = s.req.sampling.top_k
+        return (tokens, positions, page_tables, valid, seeds, steps,
+                temps, top_ps, top_ks)
+
+    def _launch_burst(self, batch: list[_Seq], lanes: tuple,
+                      k_steps: int, tk: int, firsts=()) -> None:
+        """Dispatch the plain fused burst over `lanes`
+        (_decode_lane_arrays) WITHOUT syncing and make it the burst in
+        flight. `firsts` = (sampled, take, col) a first-token sampler
+        not yet synced: the lanes in `take` get their input token on
+        the device, from column `col` of its packed output. Call under
+        the device lock, in a thread (a first call compiles)."""
+        cfg, mcfg = self.config, self.model_cfg
+        b = cfg.max_batch_size
+        (tokens, positions, page_tables, valid, seeds, steps, temps,
+         top_ps, top_ks) = lanes
+        trk = self.metrics.compile.track("decode_burst", (b, k_steps, tk))
+        led = self.memory_ledger
+        if led is not None:
+            led.on_dispatch(trk.entry, trk.shape, compiled=trk.compiled)
+        tokens_dev = jax.numpy.asarray(tokens)
+        if cfg.mesh is not None:
+            # placed as the tokens made on the device arrive (a
+            # sampler's or a burst's output, replicated over the mesh):
+            # the entry is lowered per input placement, and a burst
+            # built from the host must not meet a program of its own
+            from jax.sharding import NamedSharding, PartitionSpec
+
+            tokens_dev = jax.device_put(
+                tokens_dev, NamedSharding(cfg.mesh, PartitionSpec()))
+        for sampled, take, col in firsts:
+            tokens_dev = _first_tokens_into(tokens_dev, sampled, take, col)
+        with trk:
+            packed, self.k_cache, self.v_cache = self._mesh_dispatch(
+                trk, decode_multi_step,
+                self.params, self.k_cache, self.v_cache, tokens_dev,
+                jax.numpy.asarray(positions),
+                jax.numpy.asarray(page_tables),
+                jax.numpy.asarray(valid), jax.numpy.asarray(seeds),
+                jax.numpy.asarray(steps), jax.numpy.asarray(temps),
+                jax.numpy.asarray(top_ps), jax.numpy.asarray(top_ks),
+                mcfg, k_steps, topk_lp=tk,
+                span_tokens=len(batch) * k_steps)
+        rec = self.step_recorder
+        if rec is not None:
+            # pipelined: the dispatch returns without a host sync,
+            # so this is dispatch-only time (synced=False); the
+            # honest device wait records as `burst_sync` when
+            # _pipeline_consume pulls the results
+            rec.record("decode_burst", trk.shape, trk.elapsed_s,
+                       good_tokens=len(batch) * k_steps,
+                       work_tokens=b * k_steps, lanes=len(batch),
+                       width=b, tokens=len(batch) * k_steps,
+                       compiled=trk.compiled, synced=False)
+        self._mark_decode_compile(batch, trk)
+        self._inflight = {
+            "k": k_steps, "batch": batch, "packed": packed,
+            "positions": positions, "valid": valid, "seeds": seeds,
+            "steps": steps, "temps": temps, "top_ps": top_ps,
+            "top_ks": top_ks, "tk": tk, "deferred": []}
 
     async def _decode_iter(self) -> bool:
         if self._inflight is not None:
@@ -1931,27 +2100,10 @@ class TpuEngine:
             # mid-flight — the interleaving the budgeted scheduler
             # exists to create (every path below dispatches a burst)
             self.metrics.decode_steps_during_prefill.inc(k_steps)
-        max_pages = mcfg.max_pages_per_seq
         with self._span("decode_prep"):
-            tokens = np.zeros(b, dtype=np.int32)
-            positions = np.zeros(b, dtype=np.int32)
-            page_tables = np.zeros((b, max_pages), dtype=np.int32)
-            valid = np.zeros(b, dtype=bool)
-            seeds = np.zeros(b, dtype=np.uint32)
-            steps = np.zeros(b, dtype=np.uint32)
-            temps = np.zeros(b, dtype=np.float32)
-            top_ps = np.ones(b, dtype=np.float32)
-            top_ks = np.zeros(b, dtype=np.int32)
-            for i, s in enumerate(batch):
-                tokens[i] = s.next_token
-                positions[i] = s.pos
-                page_tables[i, :len(s.pages)] = s.pages
-                valid[i] = True
-                seeds[i] = s.seed
-                steps[i] = s.generated
-                temps[i] = s.req.sampling.temperature
-                top_ps[i] = s.req.sampling.top_p
-                top_ks[i] = s.req.sampling.top_k
+            lanes = self._decode_lane_arrays(batch)
+        (tokens, positions, page_tables, valid, seeds, steps, temps,
+         top_ps, top_ks) = lanes
 
         if use_spec:
             from dynamo_tpu.engine.spec import spec_decode_multi_step
@@ -2153,46 +2305,9 @@ class TpuEngine:
             # before pulling this one's results). Dispatch runs in a
             # thread: a first-call XLA trace/compile would otherwise
             # freeze the event loop for seconds.
-            def dispatch():
-                return self._mesh_dispatch(
-                    trk, decode_multi_step,
-                    self.params, self.k_cache, self.v_cache,
-                    jax.numpy.asarray(tokens),
-                    jax.numpy.asarray(positions),
-                    jax.numpy.asarray(page_tables),
-                    jax.numpy.asarray(valid), jax.numpy.asarray(seeds),
-                    jax.numpy.asarray(steps), jax.numpy.asarray(temps),
-                    jax.numpy.asarray(top_ps),
-                    jax.numpy.asarray(top_ks), mcfg, k_steps,
-                    topk_lp=tk, span_tokens=len(batch) * k_steps)
-
-            trk = self.metrics.compile.track(
-                "decode_burst", (b, k_steps, tk))
-            led = self.memory_ledger
-            if led is not None:
-                led.on_dispatch(trk.entry, trk.shape,
-                                compiled=trk.compiled)
             async with self._device_lock:
-                with trk:
-                    packed_dev, self.k_cache, self.v_cache = \
-                        await asyncio.to_thread(dispatch)
-            rec = self.step_recorder
-            if rec is not None:
-                # pipelined: the dispatch returns without a host sync,
-                # so this is dispatch-only time (synced=False); the
-                # honest device wait records as `burst_sync` when
-                # _pipeline_consume pulls the results
-                rec.record("decode_burst", trk.shape, trk.elapsed_s,
-                           good_tokens=len(batch) * k_steps,
-                           work_tokens=b * k_steps, lanes=len(batch),
-                           width=b, tokens=len(batch) * k_steps,
-                           compiled=trk.compiled, synced=False)
-            self._mark_decode_compile(batch, trk)
-            self._inflight = {
-                "k": k_steps, "batch": batch, "packed": packed_dev,
-                "positions": positions, "valid": valid, "seeds": seeds,
-                "steps": steps, "temps": temps, "top_ps": top_ps,
-                "top_ks": top_ks, "tk": tk, "deferred": []}
+                await asyncio.to_thread(
+                    self._launch_burst, batch, lanes, k_steps, tk)
             return await self._pipeline_consume()
 
         def run_burst():
@@ -2394,7 +2509,7 @@ class TpuEngine:
             self.params, self.k_cache, self.v_cache,
             jax.numpy.asarray(tokens), jax.numpy.asarray(tables),
             cached, seq_lens, mcfg, cfg.pp_mesh, chunk)
-        last_logits = {id(s): logits[i] for i, s in enumerate(pending)}
+        last_logits = {id(s): (logits, i) for i, s in enumerate(pending)}
         return self.k_cache, self.v_cache, last_logits
 
     def _sp_bulk_prefill(self, pending: list[_Seq],
@@ -2704,7 +2819,7 @@ class TpuEngine:
             offsets[id(s)] += chunk_lens[i]
             s.prefill_pos = offsets[id(s)]
             if s.prefill_pos >= len(s.prompt):
-                done_logits[id(s)] = ch_logits[i]
+                done_logits[id(s)] = (ch_logits, i)
         self._emit_burst(batch, packed, 1, tk)
         await self._finish_first_tokens(picks, done_logits)
         return True
@@ -2750,7 +2865,7 @@ class TpuEngine:
             for i, s in enumerate(active):
                 offsets[id(s)] += chunk_lens[i]
                 if offsets[id(s)] >= target_len_of(s):
-                    done[id(s)] = ch_logits[i]
+                    done[id(s)] = (ch_logits, i)
             return kc, vc, done, sum(chunk_lens)
         # rounds are grouped by page-alignment of the cached
         # offset: mid-page starts (disagg imports) need the row
@@ -2807,7 +2922,7 @@ class TpuEngine:
         for i, s in enumerate(active):
             offsets[id(s)] += chunk_lens[i]
             if offsets[id(s)] >= target_len_of(s):
-                done[id(s)] = logits_b[i]
+                done[id(s)] = (logits_b, i)
         return kc, vc, done, sum(chunk_lens)
 
     # -- guided decoding ----------------------------------------------------
@@ -3102,21 +3217,7 @@ class TpuEngine:
                     and any(s.max_tokens - s.generated > k
                             for s in batch))
         if can_spec:
-            ok = True
-            for s in batch:
-                need = (s.pos + 2 * k - 1) // mcfg.page_size + 1
-                if need > mcfg.max_pages_per_seq:
-                    ok = False
-                    break
-                while len(s.pages) < need:
-                    pid = self.pool.allocate_page()
-                    if pid is None:
-                        ok = False   # pages stay attached; no leak
-                        break
-                    s.pages.append(pid)
-                if not ok:
-                    break
-            if ok:
+            if all(self._cover(s, s.pos + 2 * k - 1) for s in batch):
                 b = cfg.max_batch_size
                 with self._span("decode_prep"):
                     page_tables2 = np.zeros((b, mcfg.max_pages_per_seq),

@@ -115,6 +115,10 @@ class EngineMetrics:
         self.pipelined_bursts = c(
             "dynamo_engine_pipelined_bursts_total",
             "speculatively-dispatched decode bursts")
+        self.chained_refills = c(
+            "dynamo_engine_chained_refills_total",
+            "decode bursts launched behind a first-token sampler "
+            "before it was synced")
         self.mixed_steps = c(
             "dynamo_engine_mixed_steps_total",
             "fused prefill-chunk + decode-burst steps")
@@ -169,7 +173,8 @@ class EngineMetrics:
                   self.offload_drain, self.prefill_seconds,
                   self.decode_seconds, self.tokens_emitted,
                   self.prefill_emitted, self.prefill_new_tokens,
-                  self.pipelined_bursts, self.mixed_steps,
+                  self.pipelined_bursts, self.chained_refills,
+                  self.mixed_steps,
                   self.decode_steps_during_prefill,
                   self.goodput_tokens, self.padded_tokens,
                   self.dispatch_gap, self.device_info):
@@ -198,6 +203,7 @@ class EngineMetrics:
             "prefill_emitted": int(self.prefill_emitted.get()),
             "tokens_emitted": int(self.tokens_emitted.get()),
             "pipelined_bursts": int(self.pipelined_bursts.get()),
+            "chained_refills": int(self.chained_refills.get()),
             "prefill_chunks": self.prefill_chunk.count,
             "decode_steps_during_prefill":
                 int(self.decode_steps_during_prefill.get()),

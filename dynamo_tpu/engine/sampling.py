@@ -203,19 +203,25 @@ def topk_logprobs(logits: jax.Array, k: int) -> tuple[jax.Array,
 
 
 def _sample_tokens_lp_traced(logits, seeds, steps, temperature, top_p,
-                             top_k, min_p=None, topk_lp: int = 0):
+                             top_k, min_p=None, rows=None,
+                             topk_lp: int = 0):
     """sample_tokens + chosen-token logprob (+ optional top-k
     alternatives), PACKED (2 + 2*topk_lp, B) f32 (token ids exact in
     f32; one host transfer instead of two). Rows: [sampled, chosen_lp, topk ids...,
-    topk lps...]."""
+    topk lps...]. `rows` (B,) i32, when given, picks the B rows to
+    sample out of a taller `logits` inside the program (a prefill
+    round's (Bp, V) output as it left the round: no slice or stack
+    launched ahead of the sampler)."""
+    if rows is not None:
+        logits = logits[rows]
     sampled = sample_tokens_traced(logits, seeds, steps, temperature,
                                    top_p, top_k, min_p)
-    rows = [sampled.astype(jnp.float32), chosen_logprob(logits, sampled)]
+    packed = [sampled.astype(jnp.float32), chosen_logprob(logits, sampled)]
     if topk_lp:
         ids, vals = topk_logprobs(logits, topk_lp)
-        rows += [ids[:, i] for i in range(topk_lp)]
-        rows += [vals[:, i] for i in range(topk_lp)]
-    return jnp.stack(rows)
+        packed += [ids[:, i] for i in range(topk_lp)]
+        packed += [vals[:, i] for i in range(topk_lp)]
+    return jnp.stack(packed)
 
 
 sample_tokens_lp = jax.jit(_sample_tokens_lp_traced,

@@ -113,10 +113,24 @@ def _repeat_kv(x: jax.Array, groups: int, axis: int) -> jax.Array:
     return jnp.repeat(x, groups, axis=axis) if groups > 1 else x
 
 
+def visible(s_pos: jax.Array, q_pos: jax.Array, attn_block: int
+            ) -> jax.Array:
+    """Key `s_pos` is visible to query `q_pos` iff s_pos // attn_block <=
+    q_pos // attn_block: every key up to the end of the query's own
+    block. Block 1 is the causal rule, written as it always was so that
+    it lowers to what it lowered to. THE visibility rule of the XLA path
+    and of the prefill kernel."""
+    if attn_block == 1:
+        return s_pos <= q_pos
+    return s_pos < (q_pos // attn_block + 1) * attn_block
+
+
 def prefill_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                       page_table: jax.Array, q_positions: jax.Array,
-                      seq_len: jax.Array, page_size: int) -> jax.Array:
-    """Causal attention for one sequence's prefill, reading K/V from pages.
+                      seq_len: jax.Array, page_size: int,
+                      attn_block: int = 1) -> jax.Array:
+    """Causal attention for one sequence's prefill, reading K/V from pages
+    (`attn_block` > 1: causal by blocks of that many positions).
 
     q: (T, H, D); k_pages/v_pages: (KVH, N, P, D); page_table: (max_pages,);
     q_positions: (T,) absolute positions; seq_len: scalar valid length.
@@ -136,7 +150,7 @@ def prefill_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     scores = jnp.einsum("thd,hsd->hts", q.astype(jnp.float32),
                         k.astype(jnp.float32)) / (d ** 0.5)
     s_pos = jnp.arange(k.shape[1])
-    mask = (s_pos[None, :] <= q_positions[:, None]) \
+    mask = visible(s_pos[None, :], q_positions[:, None], attn_block) \
         & (s_pos[None, :] < seq_len)                       # (T, S)
     scores = jnp.where(mask[None], scores, _NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)
@@ -445,9 +459,11 @@ def _pallas_decode(q, k_pages, v_pages, lengths, page_tables):
 def paged_attention_prefill(q: jax.Array, k_pages: jax.Array,
                             v_pages: jax.Array, page_tables: jax.Array,
                             q_starts: jax.Array, seq_lens: jax.Array,
-                            page_size: int) -> jax.Array:
+                            page_size: int, attn_block: int = 1
+                            ) -> jax.Array:
     """Causal attention of a round of prefill chunks against the pages
-    that already hold them.
+    that already hold them; with `attn_block` > 1 causal by blocks
+    (`visible`), every key of a row's own block visible to it.
 
     q: (Bp, T, H, D), row i of a sequence at position `q_starts + i`;
     k_pages/v_pages: (KVH, N, P, D); page_tables: (Bp, max_pages);
@@ -464,12 +480,12 @@ def paged_attention_prefill(q: jax.Array, k_pages: jax.Array,
             _note_fallback("chunk_shape")
         else:
             return _pallas_prefill(q, k_pages, v_pages, page_tables,
-                                   q_starts, seq_lens)
+                                   q_starts, seq_lens, attn_block)
     positions = q_starts[:, None] + jnp.arange(t)[None, :]
     return jax.vmap(
         lambda q1, pt, pos1, sl: prefill_attention(
             q1, k_pages, v_pages, pt, q_positions=pos1, seq_len=sl,
-            page_size=page_size)
+            page_size=page_size, attn_block=attn_block)
     )(q, page_tables, positions, seq_lens)
 
 
@@ -517,10 +533,12 @@ def prefill_geometry(kvh: int, groups: int, chunk: int, page_size: int,
 # jitted so that the layers of a step share one trace and one lowering of
 # the kernel: inline, 28 layers' worth cost a prefill program 6 s of
 # lowering at every start, compile cache or not (PERF.md §6, PR 32)
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("attn_block", "interpret"))
 def paged_prefill_attention(q, k_pages, v_pages, page_tables, q_starts,
-                            seq_lens, *, interpret=False):
+                            seq_lens, *, attn_block=1, interpret=False):
     """The prefill attention kernel (operands as `paged_attention_prefill`).
+    `attn_block` > 1: a row sees up to the end of its own block
+    (`visible`), so a tile walks up to the block end of its last row.
 
     Grid (sequence, q tile). A tile of queries at positions `[p0, p1)`
     walks KV blocks `0 .. cdiv(min(p1, seq_len), block) - 1` of its
@@ -563,7 +581,10 @@ def paged_prefill_attention(q, k_pages, v_pages, page_tables, q_starts,
         seq_len = len_ref[lane]
         p0 = start_ref[lane] + pl.program_id(1) * tq
         # positions a row of this tile can see: none for a tile of padding
-        seen = jnp.where(p0 < seq_len, jnp.minimum(p0 + tq, seq_len), 0)
+        # (the tile's last row sees to the end of its block)
+        p1 = p0 + tq if attn_block == 1 else (
+            (p0 + tq - 1) // attn_block + 1) * attn_block
+        seen = jnp.where(p0 < seq_len, jnp.minimum(p1, seq_len), 0)
         n_blocks = pl.cdiv(seen, tk)
         last = pl.cdiv(seen, p) - 1                     # last visible page
 
@@ -598,7 +619,8 @@ def paged_prefill_attention(q, k_pages, v_pages, page_tables, q_starts,
             slot = j % slots
             start(j + slots - 1, (j + slots - 1) % slots)
             s_pos = j * tk + jax.lax.broadcasted_iota(jnp.int32, (1, tk), 1)
-            mask = (s_pos <= q_pos) & (s_pos < seq_len)     # (rows, tk)
+            mask = (visible(s_pos, q_pos, attn_block)
+                    & (s_pos < seq_len))                    # (rows, tk)
             # one wait a cache for the block's ppb page copies: a wait
             # counts the bytes of its destination, here the whole slot
             pltpu.make_async_copy(kbuf.at[slot], kbuf.at[slot],
@@ -676,12 +698,14 @@ def paged_prefill_attention(q, k_pages, v_pages, page_tables, q_starts,
     return out.reshape(bp, t, h, d)
 
 
-def _pallas_prefill(q, k_pages, v_pages, page_tables, q_starts, seq_lens):
+def _pallas_prefill(q, k_pages, v_pages, page_tables, q_starts, seq_lens,
+                    attn_block=1):
     from dynamo_tpu.engine.kernels import KV_SPEC, REP_SPEC, per_tp_shard
 
     chunk_spec = jax.sharding.PartitionSpec(None, None, "tp")
     return per_tp_shard(
-        paged_prefill_attention,
+        functools.partial(paged_prefill_attention, attn_block=attn_block)
+        if attn_block != 1 else paged_prefill_attention,
         (chunk_spec, KV_SPEC, KV_SPEC, REP_SPEC, REP_SPEC, REP_SPEC),
         chunk_spec)(q, k_pages, v_pages, page_tables, q_starts, seq_lens)
 

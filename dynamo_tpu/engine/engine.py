@@ -270,6 +270,14 @@ class TpuEngineConfig:
     # tests/test_tenancy.py). Ignored when DYN_TENANCY arms the fair
     # scheduler, which scans tenant heads instead.
     admit_lookahead: int = 0
+    # Block diffusion (a model with `attn_block` = B > 1, SDAR): forwards
+    # that denoise a block before the one that commits it (B a multiple of
+    # it: each fixes B / steps positions), and which masked positions a
+    # forward fixes: "sequential" (the leftmost) or
+    # "low_confidence_static" (those whose best token is most probable).
+    # Deployment choices, the worker's --dllm-* flags.
+    dllm_denoising_steps: int = 0
+    dllm_unmasking_strategy: str = "sequential"
 
 
 @dataclass
@@ -296,6 +304,9 @@ class _Seq:
     guided_state: int = 0                 # authoritative DFA state (host)
     out_counter: dict = field(default_factory=dict)  # token -> emit count
     next_token: int = -1                  # sampled, KV not yet written
+    # block diffusion: ids already known at the head of the lane's next
+    # block (a prompt's tail past the last whole block), not yet committed
+    given: list[int] = field(default_factory=list)
     _hist: Optional[tuple] = None         # (len(prompt), (V,) histogram)
 
     @property
@@ -427,6 +438,10 @@ class TpuEngine:
             raise ValueError(
                 "dense-family mesh serving shards over 'tp'; an "
                 "('ep',) mesh is for MoE models")
+        self._dllm = mcfg.attn_block > 1
+        if self._dllm:
+            self._check_block_diffusion()
+
         def place_owned(p, owned: bool):
             """Host (numpy) checkpoints must land on device ONCE at
             init: a numpy leaf passed to a jitted step re-uploads on
@@ -505,7 +520,8 @@ class TpuEngine:
                     lambda key: init_params(key, mcfg),
                     out_shardings=param_sharding(
                         cfg.mesh, mcfg.attention_bias,
-                        moe=bool(getattr(mcfg, "num_experts", 0))),
+                        moe=bool(getattr(mcfg, "num_experts", 0)),
+                        qk_norm=mcfg.qk_norm),
                 )(jax.random.PRNGKey(cfg.rng_seed))
                 self.params = params
             else:
@@ -791,11 +807,66 @@ class TpuEngine:
         # sequence and corrupt it)
         self._inflight: Optional[dict] = None
         self._defer_releases: Optional[list] = None
+        # rows a real token position sends through the routed expert
+        # dispatch (0: a dense model, or experts sharded over 'ep', which
+        # keep the dense mask): what dynamo_moe_routed_rows_total counts
+        self._routed_per_token = 0
+        if getattr(mcfg, "num_experts", 0) and cfg.mesh is None:
+            self._routed_per_token = (mcfg.experts_per_token
+                                      * mcfg.num_layers)
         # disagg: finished prefill-only sequences whose pages are pinned
         # until the decode worker pulls them (transfer_id -> (pages, len,
         # deadline)); reaped by the scheduler loop after transfer_ttl.
         self._transfers: dict[str, tuple[list[int], int, float]] = {}
         self.transfer_ttl = 60.0
+
+    def _check_block_diffusion(self) -> None:
+        """A block-diffusion engine serves through prefill rounds and the
+        block burst only; what does not compose is refused at start, each
+        with its reason."""
+        cfg, mcfg = self.config, self.model_cfg
+        blk, steps = mcfg.attn_block, cfg.dllm_denoising_steps
+        if steps < 1 or blk % steps:
+            raise ValueError(
+                f"dllm_denoising_steps={steps} must divide the block "
+                f"length {blk}: each step fixes block / steps positions")
+        if cfg.dllm_unmasking_strategy not in (
+                "sequential", "low_confidence_static"):
+            raise ValueError(
+                "dllm_unmasking_strategy must be sequential or "
+                "low_confidence_static, not "
+                f"{cfg.dllm_unmasking_strategy!r}")
+        if not 0 <= mcfg.mask_token_id < mcfg.vocab_size:
+            raise ValueError(
+                f"block diffusion needs the checkpoint's mask_token_id "
+                f"inside the vocabulary, not {mcfg.mask_token_id}")
+        for size, what in ((mcfg.page_size, "page size"),
+                           (cfg.prefill_chunk, "prefill_chunk"),
+                           (cfg.decode_steps_per_sync,
+                            "decode_steps_per_sync")):
+            if size % blk:
+                raise ValueError(
+                    f"{what} {size} must be a multiple of the block "
+                    f"length {blk}: pages, chunks and bursts hold whole "
+                    "blocks")
+        if cfg.draft_model is not None:
+            raise ValueError(
+                "block diffusion does not compose with a draft model: a "
+                "block step already yields several tokens a forward")
+        if cfg.pp_mesh is not None or cfg.sp_mesh is not None:
+            raise ValueError(
+                "block diffusion is not served over pp / sp meshes: "
+                "their prefill and decode entries know one token a step")
+        if cfg.prefill_chunk_budget > 0:
+            raise ValueError(
+                "block diffusion does not compose with "
+                "prefill_chunk_budget: the mixed step fuses a chunk with "
+                "a one-token decode step")
+        if ragged_enabled():
+            raise ValueError(
+                "block diffusion does not compose with the ragged "
+                "attention path (DYN_ATTENTION_IMPL=ragged): its rows "
+                "are causal by token")
 
     @property
     def perf(self) -> dict:
@@ -813,6 +884,8 @@ class TpuEngine:
         requests overflow max_pages_per_seq mid-decode."""
         cfg = self.config
         la = cfg.decode_steps_per_sync
+        if self._dllm:
+            return la          # whole blocks, one burst at a time
         if cfg.pipeline_bursts:
             la = 2 * cfg.decode_steps_per_sync   # one burst in flight
         if cfg.draft_model is not None:
@@ -837,6 +910,23 @@ class TpuEngine:
                 token_ids=[], finish_reason=FINISH_ERROR,
                 extra={"error": "empty prompt"}).to_dict()
             return
+        if self._dllm:
+            sp = req.sampling
+            refused = (
+                "guided decoding" if sp.guided
+                else "top_logprobs alternatives" if sp.top_logprobs > 0
+                else "min_p and sampling penalties" if (
+                    sp.min_p > 0.0 or sp.repetition_penalty != 1.0
+                    or sp.frequency_penalty != 0.0
+                    or sp.presence_penalty != 0.0)
+                else "a KV import or export" if req.kv_transfer_params
+                else "embeddings" if req.extra.get("embed") else None)
+            if refused:
+                yield EngineOutput(
+                    token_ids=[], finish_reason=FINISH_ERROR,
+                    extra={"error": f"block diffusion does not serve "
+                                    f"{refused}"}).to_dict()
+                return
         guided_tables = None
         guided_key = None
         if req.sampling.guided:
@@ -1116,7 +1206,9 @@ class TpuEngine:
                         await asyncio.gather(
                             *(self.kvbm.onboard_remote(s) for s in fresh))
                 t0 = time.perf_counter()
-                if self.config.prefill_chunk_budget > 0:
+                if self._dllm:
+                    progressed = await self._prefill_blocks()
+                elif self.config.prefill_chunk_budget > 0:
                     progressed = await self._prefill_budgeted()
                 else:
                     progressed = await self._prefill_pending()
@@ -1562,7 +1654,7 @@ class TpuEngine:
         with trk:
             sampled = self._mesh_dispatch(
                 trk, sample_tokens_lp, logits, *lane_arrays, rows=rows,
-                topk_lp=tk, span_tokens=len(pending))
+                topk_lp=tk, span_tokens=len(pending), routed_tokens=0)
         rec = self.step_recorder
         if rec is not None:
             rec.record("sample_first", trk.shape, trk.elapsed_s,
@@ -2044,6 +2136,8 @@ class TpuEngine:
             "top_ks": top_ks, "tk": tk, "deferred": []}
 
     async def _decode_iter(self) -> bool:
+        if self._dllm:
+            return await self._block_decode()
         if self._inflight is not None:
             return await self._pipeline_consume()
         runnable = [s for s in self._running if s.prefilled]
@@ -2364,12 +2458,147 @@ class TpuEngine:
         self._emit_burst(batch, packed, k_steps, tk)
         return True
 
+    # -- block diffusion ---------------------------------------------------
+
+    async def _prefill_blocks(self) -> bool:
+        """Prefill of a block-diffusion engine: every admitted prompt's
+        WHOLE blocks, block-causally, in the batched chunk rounds every
+        engine uses. The tail past the last whole block (len % B ids) is
+        the known head of the lane's first block and is committed with it.
+        Prefill yields no token, so nothing is sampled and nothing is
+        synced: the block burst that follows is launched behind the
+        rounds."""
+        pending = [s for s in self._running if not s.prefilled]
+        if not pending:
+            return False
+        mcfg = self.model_cfg
+        blk = mcfg.attn_block
+
+        def whole(s: _Seq) -> int:
+            return len(s.prompt) - len(s.prompt) % blk
+
+        def rounds():
+            offsets = {id(s): min(s.cached_len, whole(s)) for s in pending}
+            self.k_cache, self.v_cache, _ = self._chunk_rounds(
+                self.params, mcfg, self.k_cache, self.v_cache, pending,
+                offsets, tokens_of=lambda s: s.prompt,
+                target_len_of=whole)
+
+        self.metrics.prefill_new_tokens.inc(sum(
+            max(whole(s) - s.cached_len, 0) for s in pending))
+        async with self._device_lock:
+            await asyncio.to_thread(rounds)
+        for seq in pending:
+            seq.token_seq = TokenBlockSequence(
+                mcfg.page_size, seq.prompt[:whole(seq)])
+            for block in seq.token_seq.blocks:
+                self.pool.register_page(
+                    seq.pages[block.block_index], block.seq_hash,
+                    block.local_hash, block.parent_seq_hash)
+            seq.given = list(seq.prompt[whole(seq):])
+            seq.prefilled = True
+            seq.prefill_pos = len(seq.prompt)
+        return True
+
+    async def _block_decode(self) -> bool:
+        """The decode burst of a block-diffusion engine: every runnable
+        lane advances decode_steps_per_sync / B blocks in one dispatch
+        (models/llama.py block_decode_multi_step), one host sync, then
+        each lane's tokens go out in one frame. A step stays one token a
+        lane, so a burst is decode_steps_per_sync tokens a lane at
+        (denoising steps + 1) / B forwards a token."""
+        from dynamo_tpu.models.llama import block_decode_multi_step
+
+        runnable = [s for s in self._running if s.prefilled]
+        if not runnable:
+            return False
+        cfg, mcfg = self.config, self.model_cfg
+        blk, steps = mcfg.attn_block, cfg.dllm_denoising_steps
+        k_steps = cfg.decode_steps_per_sync
+        n_blocks = k_steps // blk
+        with self._span("decode_prep"):
+            self._prep_decode_lanes(runnable, k_steps)
+        if not runnable:
+            return False
+        b = cfg.max_batch_size
+        batch = runnable[:b]
+        with self._span("decode_prep"):
+            # a lane enters at its first uncommitted position; no sampled
+            # token and no step count go in (a draw is seeded by position)
+            (_, positions, page_tables, valid, seeds, _, temps, top_ps,
+             top_ks) = self._decode_lane_arrays(batch)
+            given = np.zeros((b, blk), dtype=np.int32)
+            n_given = np.zeros(b, dtype=np.int32)
+            for i, s in enumerate(batch):
+                given[i, :len(s.given)] = s.given
+                n_given[i] = len(s.given)
+        trk = self.metrics.compile.track(
+            "decode_burst", (b, k_steps, blk, steps))
+        led = self.memory_ledger
+        if led is not None:
+            led.on_dispatch(trk.entry, trk.shape, compiled=trk.compiled)
+        forwards = len(batch) * n_blocks * (steps + 1)
+
+        def run_burst():
+            packed, kc, vc = self._mesh_dispatch(
+                trk, block_decode_multi_step,
+                self.params, self.k_cache, self.v_cache,
+                jax.numpy.asarray(given), jax.numpy.asarray(n_given),
+                jax.numpy.asarray(positions),
+                jax.numpy.asarray(page_tables), jax.numpy.asarray(valid),
+                jax.numpy.asarray(seeds), jax.numpy.asarray(temps),
+                jax.numpy.asarray(top_ps), jax.numpy.asarray(top_ks),
+                mcfg, n_blocks, steps, cfg.dllm_unmasking_strategy,
+                span_tokens=len(batch) * k_steps,
+                routed_tokens=forwards * blk)
+            return self._host_sync(packed), kc, vc         # ONE host sync
+
+        async with self._device_lock:
+            with trk:
+                packed, self.k_cache, self.v_cache = \
+                    await asyncio.to_thread(run_burst)
+        rec = self.step_recorder
+        if rec is not None:
+            rec.record(trk.entry, trk.shape, trk.elapsed_s,
+                       good_tokens=len(batch) * k_steps,
+                       work_tokens=b * k_steps, lanes=len(batch),
+                       width=b, tokens=len(batch) * k_steps,
+                       compiled=trk.compiled)
+        self.metrics.block_forwards.inc(
+            len(batch) * n_blocks * steps, kind="denoise")
+        self.metrics.block_forwards.inc(len(batch) * n_blocks,
+                                        kind="commit")
+        self.metrics.blocks.inc(len(batch) * n_blocks)
+        self._mark_decode_compile(batch, trk)
+        with self._span("emit"):
+            ids = packed[0].astype(np.int32)         # (k_steps, B)
+            for i, s in enumerate(batch):
+                if s.finished or s not in self._running:
+                    continue
+                # the burst's blocks are committed on the device, known
+                # head and all: token_seq follows, and a page whose last
+                # block it completes is registered
+                for t in ids[:, i]:
+                    block = s.token_seq.append(int(t))
+                    if block is not None:
+                        self.pool.register_page(
+                            s.pages[block.block_index], block.seq_hash,
+                            block.local_hash, block.parent_seq_hash)
+                head, s.given = len(s.given), []
+                # past max_tokens or a stop token the rest of the burst
+                # is overshoot, discarded here as after any burst
+                self._emit_lane(s, ids[head:, i], packed[1, head:, i],
+                                append_inputs=False)
+        return True
+
     def _mesh_dispatch(self, trk, fn, *args, span_tokens: int = 0,
-                       **kwargs):
+                       routed_tokens: Optional[int] = None, **kwargs):
         """The one place every jitted dispatch passes through, on the
         thread that runs it. Armed (DYN_STEP_PROFILE) the call sits under
         a `dispatch` host span labelled as CompileTracker labels it, with
-        `span_tokens` = the round's real token positions; the sites
+        `span_tokens` = the round's real token positions (they also count
+        as rows through an MoE model's routed dispatch, unless the site
+        says how many did: `routed_tokens`, 0 for a sampler); the sites
         convert their inputs (`jnp.asarray`) before they get here, so
         those transfers are outside the span. Mesh-recorder shim too. Off
         (mesh_recorder is None, the default): one attribute check, then
@@ -2380,6 +2609,9 @@ class TpuEngine:
         call consumes are never touched — then the dispatch runs and
         its cached collective bytes fold into the per-entry comm
         budget."""
+        if self._routed_per_token:
+            self.metrics.moe_routed_rows.inc(self._routed_per_token * (
+                span_tokens if routed_tokens is None else routed_tokens))
         srec = self.step_recorder
         # ONE frame and one call site armed or not: the persistent
         # compile cache's key follows the source lines of the call stack
@@ -3576,7 +3808,11 @@ class TpuEngine:
             self._running.remove(seq)
         self.pool.release_sequence(seq.pages)
         seq.pages = []
-        seq.prompt = seq.token_seq.tokens + [seq.next_token]
+        # block diffusion: what is committed plus the next block's known
+        # head (there is no sampled-but-unwritten token)
+        seq.prompt = seq.token_seq.tokens + (
+            seq.given if self._dllm else [seq.next_token])
+        seq.given = []
         seq.prompt_hashes = TokenBlockSequence(
             self.model_cfg.page_size, seq.prompt).seq_hashes()
         seq.token_seq = TokenBlockSequence(self.model_cfg.page_size)

@@ -105,6 +105,61 @@ def _paged_kv_write(kc, vc, k, v, page_ids, offsets):
     return out_kc, out_vc
 
 
+def paged_kv_write_block(kc: jax.Array, vc: jax.Array, k: jax.Array,
+                         v: jax.Array, page_ids: jax.Array,
+                         offsets: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """kc/vc: (KVH, N, P, D); k/v: (L, R, KVH, D), the R rows of a lane's
+    block, which lie in one page (the page size is a multiple of the block
+    length); page_ids/offsets: (L,) the page and the block's first row.
+
+    `paged_kv_write` with R rows to a program: one page in and out a lane
+    instead of one a row (a block step writes lanes x R rows a layer).
+    """
+    block_spec = jax.sharding.PartitionSpec(None, None, "tp")
+    return per_tp_shard(
+        _paged_kv_write_block,
+        (KV_SPEC, KV_SPEC, block_spec, block_spec, REP_SPEC, REP_SPEC),
+        (KV_SPEC, KV_SPEC))(kc, vc, k, v, page_ids, offsets)
+
+
+def _paged_kv_write_block(kc, vc, k, v, page_ids, offsets):
+    pl, pltpu = _pltpu()
+    kvh, n_pages, p, d = kc.shape
+    lanes, rows = k.shape[:2]
+
+    def kernel(pid_ref, off_ref, k_ref, v_ref, kc_in, vc_in,
+               kc_out, vc_out):
+        # as `_paged_kv_write`: no sublane-unaligned dynamic store, so each
+        # new row is blended into the page block under a mask
+        off = off_ref[pl.program_id(0)]
+        row = jax.lax.broadcasted_iota(jnp.int32, (1, 1, p, 1), 2)
+        k_page, v_page = kc_in[...], vc_in[...]
+        for j in range(rows):
+            mask = row == off + j
+            k_page = jnp.where(mask, k_ref[0, j][:, None, None, :], k_page)
+            v_page = jnp.where(mask, v_ref[0, j][:, None, None, :], v_page)
+        kc_out[...] = k_page
+        vc_out[...] = v_page
+
+    page_block = pl.BlockSpec(
+        (kvh, 1, p, d),
+        lambda i, pid_ref, off_ref: (0, pid_ref[i], 0, 0))
+    rows_block = pl.BlockSpec((1, rows, kvh, d),
+                              lambda i, pid_ref, off_ref: (i, 0, 0, 0))
+    out_kc, out_vc = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(lanes,),
+            in_specs=[rows_block, rows_block, page_block, page_block],
+            out_specs=[page_block, page_block]),
+        out_shape=[jax.ShapeDtypeStruct(kc.shape, kc.dtype),
+                   jax.ShapeDtypeStruct(vc.shape, vc.dtype)],
+        input_output_aliases={4: 0, 5: 1},  # kc/vc updated in place
+        name="kv_write_block",
+    )(page_ids.astype(jnp.int32), offsets.astype(jnp.int32), k, v, kc, vc)
+    return out_kc, out_vc
+
+
 def paged_kv_write_pages(kc: jax.Array, vc: jax.Array,
                          k_blocks: jax.Array, v_blocks: jax.Array,
                          page_ids: jax.Array

@@ -125,6 +125,20 @@ class EngineMetrics:
         self.decode_steps_during_prefill = c(
             "dynamo_engine_decode_steps_during_prefill_total",
             "decode steps interleaved while requests were prefilling")
+        # Block diffusion (engine.py `_block_decode`): a lane's block costs
+        # `denoise` forwards plus one `commit`; counted a lane a forward,
+        # so forwards over the burst's tokens is the cost of a token.
+        self.block_forwards = c(
+            "dynamo_engine_block_forwards_total",
+            "forwards of a block-diffusion burst, a lane a forward, by "
+            "kind (denoise / commit)")
+        self.blocks = c(
+            "dynamo_engine_blocks_total",
+            "blocks denoised and committed, a lane a block")
+        self.moe_routed_rows = c(
+            "dynamo_moe_routed_rows_total",
+            "rows the routed expert dispatch sent to experts: real "
+            "token positions x experts per token x layers")
         # Step-profiler attribution (engine/profiler.py). Constructed
         # unconditionally so names are stable in /metrics and telemetry
         # snapshots; they only move when DYN_STEP_PROFILE arms the
@@ -176,6 +190,7 @@ class EngineMetrics:
                   self.pipelined_bursts, self.chained_refills,
                   self.mixed_steps,
                   self.decode_steps_during_prefill,
+                  self.block_forwards, self.blocks, self.moe_routed_rows,
                   self.goodput_tokens, self.padded_tokens,
                   self.dispatch_gap, self.device_info):
             registry.register(m)

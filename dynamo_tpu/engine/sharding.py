@@ -52,7 +52,8 @@ def make_mesh(dp: int = 1, tp: int = 1,
 
 
 def param_specs(attention_bias: bool = False,
-                moe: bool = False, moe_tp: bool = False) -> dict:
+                moe: bool = False, moe_tp: bool = False,
+                qk_norm: bool = False) -> dict:
     """PartitionSpecs matching init_params' pytree structure.
     `attention_bias` (Qwen2 family) adds bq/bk/bv rows — biases shard
     like their weight's OUTPUT dim (megatron column-parallel).
@@ -67,6 +68,12 @@ def param_specs(attention_bias: bool = False,
     Mixtral-8x7B multi-host shape) attention/embeddings additionally
     shard megatron-style over "tp" while the router stays replicated;
     otherwise everything non-expert replicates."""
+    if qk_norm:
+        # (L, D) per-head norm weights: every shard needs all of D
+        out = param_specs(attention_bias, moe, moe_tp)
+        out["layers"].update({"q_norm": P(None, None),
+                              "k_norm": P(None, None)})
+        return out
     if moe:
         if moe_tp:
             base = param_specs(attention_bias)
@@ -129,7 +136,8 @@ def specs_for(params: dict, mesh: Optional[Mesh] = None) -> dict:
     specs = param_specs(
         attention_bias="bq" in params["layers"],
         moe="router" in params["layers"],
-        moe_tp=mesh is not None and "tp" in mesh.axis_names)
+        moe_tp=mesh is not None and "tp" in mesh.axis_names,
+        qk_norm="q_norm" in params["layers"])
     specs["layers"] = {k: specs["layers"][k] for k in params["layers"]}
     return specs
 
@@ -144,12 +152,13 @@ def cache_spec(mesh: Optional[Mesh] = None) -> P:
 
 
 def param_sharding(mesh: Mesh, attention_bias: bool = False,
-                   moe: bool = False) -> dict:
+                   moe: bool = False, qk_norm: bool = False) -> dict:
     """NamedSharding tree matching init_params' structure."""
     return jax.tree.map(
         lambda s: NamedSharding(mesh, s),
         param_specs(attention_bias, moe=moe,
-                    moe_tp=moe and "tp" in mesh.axis_names),
+                    moe_tp=moe and "tp" in mesh.axis_names,
+                    qk_norm=qk_norm),
         is_leaf=lambda x: isinstance(x, P))
 
 

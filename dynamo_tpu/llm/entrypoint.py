@@ -431,6 +431,8 @@ def build_tpu_engine(model: str, served_name: Optional[str] = None, *,
                      prefill_batch_widths=None,
                      pipeline_parallel_size: int = 1,
                      pp_microbatches: int = 0,
+                     dllm_denoising_steps: int = 0,
+                     dllm_unmasking_strategy: str = "sequential",
                      **model_overrides):
     """(TpuEngine, ModelDeploymentCard) for a real checkpoint.
 
@@ -456,6 +458,12 @@ def build_tpu_engine(model: str, served_name: Optional[str] = None, *,
 
     path = resolve_model(model)
     cfg = config_from_hf(path, **model_overrides)
+    if cfg.mask_token_id >= 0 and cfg.attn_block == 1:
+        # a checkpoint that names a mask id generates by diffusion over
+        # blocks, and how long a block is is the deployment's to say
+        raise ValueError(
+            f"{path} is a block-diffusion checkpoint (mask_token_id "
+            f"{cfg.mask_token_id}): give --dllm-block-length")
     if random_init:
         params = None
     elif mesh is None:
@@ -540,7 +548,9 @@ def build_tpu_engine(model: str, served_name: Optional[str] = None, *,
                         sp_layout=sp_layout,
                         prefill_batch_widths=prefill_batch_widths,
                         pp_mesh=pp_mesh,
-                        pp_microbatches=pp_microbatches or 2),
+                        pp_microbatches=pp_microbatches or 2,
+                        dllm_denoising_steps=dllm_denoising_steps,
+                        dllm_unmasking_strategy=dllm_unmasking_strategy),
         params=params, draft_params=draft_params,
         token_bytes=token_bytes, eos_token_id=eos_id)
     if kvbm_host_blocks:

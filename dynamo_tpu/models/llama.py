@@ -56,6 +56,19 @@ class LlamaConfig:
     # layer dict gains bq/bk/bv leaves and every forward adds them via
     # qkv_proj — one switch covers paged, dense, sp, and pp paths.
     attention_bias: bool = False
+    # Qwen3-family attention: RMS norm over each head's D of q and k,
+    # one weight vector a layer each (q_norm / k_norm leaves), before
+    # RoPE. Off lowers to exactly what stood here before.
+    qk_norm: bool = False
+    # Visibility by block (block diffusion, SDAR): key j is visible to
+    # query i iff j // attn_block <= i // attn_block. 1 is the causal
+    # rule. Above 1 the engine generates by denoising blocks of this
+    # many tokens (engine/engine.py `_block_decode`), and the page size
+    # must be a multiple of it so that prefix pages stay whole blocks.
+    attn_block: int = 1
+    # id that stands for a position not yet fixed (the checkpoint's
+    # config.json `mask_token_id`; a model fact, read by the block burst)
+    mask_token_id: int = -1
     # paged KV cache geometry
     page_size: int = 16
     max_pages_per_seq: int = 512          # context = page_size * this
@@ -134,12 +147,24 @@ def init_params(rng: jax.Array, cfg: LlamaConfig) -> dict:
         layers["bq"] = dense(next(k), E, L, H * D)
         layers["bk"] = dense(next(k), E, L, KVH * D)
         layers["bv"] = dense(next(k), E, L, KVH * D)
+    if cfg.qk_norm:
+        # a key of its own: the twelve above are spoken for
+        layers.update(init_qk_norm(jax.random.split(rng, 13)[12], cfg))
     return {
         "embed": embed,
         "layers": layers,
         "final_norm": norm(E),
         "lm_head": lm_head,
     }
+
+
+def init_qk_norm(rng: jax.Array, cfg: LlamaConfig) -> dict:
+    """q_norm / k_norm leaves (L, D) f32, uneven on purpose: a norm
+    weight of all ones would let a dropped multiply pass the tests."""
+    kq, kk = jax.random.split(rng)
+    shape = (cfg.num_layers, cfg.head_dim)
+    return {"q_norm": 1.0 + 0.1 * jax.random.normal(kq, shape, jnp.float32),
+            "k_norm": 1.0 + 0.1 * jax.random.normal(kk, shape, jnp.float32)}
 
 
 def init_cache(cfg: LlamaConfig, num_pages: int
@@ -231,8 +256,18 @@ def _mlp(h: jax.Array, lp: dict, cfg: "LlamaConfig") -> jax.Array:
 
 def _layer_params(params: dict, l: int) -> dict:
     """Static slice of layer l's weights from the L-stacked param arrays
-    (free: XLA fuses the slice into the consuming matmul reads)."""
-    return jax.tree.map(lambda w: w[l], params["layers"])
+    (free: XLA fuses the slice into the consuming matmul reads). A Pallas
+    kernel is no such consumer: handed a slice it is handed a copy. So an
+    MoE layer also carries `expert_stacks` = (the expert stacks of all
+    layers, l), which the routed dispatch's kernel indexes by layer itself
+    (engine/moe_gmm.py); the slices stay for every other reader and are
+    dead code where nobody reads them."""
+    layers = params["layers"]
+    lp = jax.tree.map(lambda w: w[l], layers)
+    if "router" in layers:
+        lp["expert_stacks"] = (
+            {k: layers[k] for k in ("w_gate", "w_up", "w_down")}, l)
+    return lp
 
 
 def qkv_proj(hn: jax.Array, lp: dict, cfg: LlamaConfig
@@ -270,6 +305,9 @@ def block_qkv(x: jax.Array, lp: dict, positions: jax.Array,
         q, k, v = qkv_proj(hn, lp, cfg)
         heads = x.shape[:-1] + (-1, cfg.head_dim)
         q, k, v = q.reshape(heads), k.reshape(heads), v.reshape(heads)
+        if cfg.qk_norm:
+            q = rms_norm(q, lp["q_norm"], cfg.rms_eps)
+            k = rms_norm(k, lp["k_norm"], cfg.rms_eps)
         if x.ndim == 2:
             # flat rows (one token each): rope wants a T axis
             q = rope(q[:, None], positions[:, None], cfg.rope_theta)[:, 0]
@@ -400,7 +438,8 @@ def paged_forward(params: dict, k_cache: tuple, v_cache: tuple,
         with jax.named_scope("attn_core"):
             attn = paged_attention_prefill(
                 q, kc, vc, page_tables, cached_lens, seq_lens,
-                page_size=cfg.page_size)                   # (Bp, T, H, D)
+                page_size=cfg.page_size,
+                attn_block=cfg.attn_block)                 # (Bp, T, H, D)
         x = block_out(x, attn, lp, cfg)
         new_k.append(kc)
         new_v.append(vc)
@@ -542,6 +581,185 @@ def decode_multi_step(params: dict, k_cache: jax.Array, v_cache: jax.Array,
                      dtype=jnp.float32)
     _, k_cache, v_cache, out = lax.fori_loop(
         0, num_steps, body, (tokens, k_cache, v_cache, out0))
+    return out, k_cache, v_cache
+
+
+def _block_forward(params: dict, k_cache: tuple, v_cache: tuple,
+                   ids: jax.Array, pos0: jax.Array, page_tables: jax.Array,
+                   valid: jax.Array, cfg: LlamaConfig, head: bool):
+    """One forward of a block step: each lane's `attn_block` rows at
+    positions pos0 .. pos0 + B - 1 (pos0 a multiple of B). Their K,V are
+    written first (rewritten by every forward of the block: rollback
+    through the page table is free), then all B rows see one and the same
+    key set, everything up to the block's end, so they ride
+    `paged_attention_decode` as B x groups query rows a kv head with
+    lengths = pos0 + B: no kernel of their own. ids: (L, B); pos0, valid:
+    (L,). Returns (logits (L, B, V) f32, or None without `head`, caches)."""
+    from dynamo_tpu.engine.attention import use_pallas
+    from dynamo_tpu.engine.kernels import (kv_write_supported,
+                                           paged_kv_write_block)
+
+    lanes, blk = ids.shape
+    x = params["embed"][ids]                               # (L, B, E)
+    positions = pos0[:, None] + jnp.arange(blk)[None, :]   # (L, B)
+    page_ids, offsets, _ = _decode_kv(page_tables, pos0, valid, cfg)
+    lengths = jnp.where(valid, pos0 + blk, 0)
+    safe_pages = jnp.where(valid, page_ids, 0)
+    safe_offs = jnp.where(valid, offsets, 0)
+    kernel_write = use_pallas() and kv_write_supported(cfg.page_size,
+                                                       cfg.head_dim)
+
+    kvh, g = cfg.num_kv_heads, cfg.num_heads // cfg.num_kv_heads
+
+    new_k, new_v = [], []
+    for l in range(cfg.num_layers):
+        lp = _layer_params(params, l)
+        q, k, v = block_qkv(x, lp, positions, cfg)
+        with jax.named_scope("kv_write"):
+            if kernel_write:
+                kc, vc = paged_kv_write_block(
+                    k_cache[l], v_cache[l], k, v, safe_pages, safe_offs)
+            else:
+                flat = (lanes * blk,) + k.shape[2:]
+                kc, vc = _write_kv(
+                    k_cache[l], v_cache[l], k.reshape(flat),
+                    v.reshape(flat),
+                    jnp.repeat(page_ids, blk),
+                    (offsets[:, None] + jnp.arange(blk)).reshape(-1),
+                    jnp.repeat(valid, blk))
+        with jax.named_scope("attn_core"):
+            # a kv head's rows: its B x groups queries, one after another
+            q2 = q.reshape(lanes, blk, kvh, g, -1).transpose(
+                0, 2, 1, 3, 4).reshape(lanes, kvh * blk * g, -1)
+            attn = paged_attention_decode(
+                q2, kc, vc, lengths, page_tables, page_size=cfg.page_size)
+            attn = attn.reshape(lanes, kvh, blk, g, -1).transpose(
+                0, 2, 1, 3, 4).reshape(q.shape)
+        x = block_out(x, attn, lp, cfg)
+        new_k.append(kc)
+        new_v.append(vc)
+
+    logits = None
+    if head:
+        with jax.named_scope("lm_head"):
+            x = rms_norm(x, params["final_norm"], cfg.rms_eps)
+            logits = qm(x, params["lm_head"]).astype(jnp.float32)
+    return logits, tuple(new_k), tuple(new_v)
+
+
+def unmask_choice(masked: jax.Array, confidence: jax.Array, count: int,
+                  strategy: str) -> jax.Array:
+    """(L, B) bool: the `count` masked positions of each block a denoising
+    step fixes. `sequential`: the leftmost. `low_confidence_static`: those
+    whose best token has the highest probability, the leftmost of equals.
+    Fewer than `count` masked: all of them."""
+    if strategy == "sequential":
+        rank = jnp.cumsum(masked, axis=-1) - 1
+    else:
+        score = jnp.where(masked, confidence, -jnp.inf)
+        cols = jnp.arange(masked.shape[-1])
+        ahead = (score[:, None, :] > score[:, :, None]) | (
+            (score[:, None, :] == score[:, :, None])
+            & (cols[None, None, :] < cols[None, :, None]))
+        rank = jnp.sum(ahead, axis=-1)
+    return masked & (rank < count)
+
+
+@partial(jax.jit, static_argnames=("cfg", "num_blocks", "denoise_steps",
+                                   "strategy"), donate_argnums=(1, 2))
+def block_decode_multi_step(params: dict, k_cache: tuple, v_cache: tuple,
+                            given: jax.Array, n_given: jax.Array,
+                            positions: jax.Array, page_tables: jax.Array,
+                            valid: jax.Array, seeds: jax.Array,
+                            temperature: jax.Array, top_p: jax.Array,
+                            top_k: jax.Array, cfg: LlamaConfig,
+                            num_blocks: int, denoise_steps: int,
+                            strategy: str
+                            ) -> tuple[jax.Array, tuple, tuple]:
+    """The decode burst of a block-diffusion model (`cfg.attn_block` = B
+    > 1): `num_blocks` blocks a lane with ONE host round-trip, as
+    `decode_multi_step` is `num_steps` tokens a lane.
+
+    A block covers positions [p, p + B), p a multiple of B. Its state is
+    the ids known there and `cfg.mask_token_id` elsewhere; whether a
+    position is masked is carried as a boolean and never read off the id.
+    Each of the `denoise_steps` forwards runs the block's state against
+    the clean K,V of everything before it, rewrites the block's own K,V
+    in place, and fixes B / denoise_steps of the masked positions
+    (`unmask_choice`) with a token sampled from that position's logits
+    (**logits at position i predict the token at position i**: no shift).
+    One more forward over the final ids commits the block's K,V as the
+    context of later blocks; it needs no logits and runs no lm_head.
+
+    given: (L, B) ids already known at the head of each lane's FIRST block
+    (a prompt's tail), n_given: (L,) how many; positions: (L,) where the
+    first block starts. Pages for positions .. positions + num_blocks * B
+    - 1 are pre-allocated. A token drawn at absolute position p of a lane
+    uses the lane's seed at step p, so a stream does not depend on how
+    bursts cut it. Returns (packed (2, num_blocks * B, L) f32: ids and the
+    log-probability of the untempered logits of the step that fixed each,
+    position-major as `decode_multi_step` packs its steps; the given
+    positions carry their ids and 0; caches)."""
+    from dynamo_tpu.engine.sampling import sample_tokens_traced
+
+    lanes, blk = given.shape
+    per_step = blk // denoise_steps
+    cols = jnp.arange(blk)
+
+    def rep(a):
+        return jnp.repeat(a, blk)
+
+    def sample(logits, pos):
+        """tokens, their log-probabilities and each row's confidence (the
+        log-probability of its best token), (L, B) each."""
+        flat = logits.reshape(lanes * blk, -1)
+        # the candidate set (a top-k over the vocabulary a row) only where
+        # some lane draws: a greedy batch is an argmax
+        toks = lax.cond(
+            jnp.any(temperature > 0),
+            lambda: sample_tokens_traced(
+                flat, rep(seeds), pos.reshape(-1), rep(temperature),
+                rep(top_p), rep(top_k)),
+            lambda: jnp.argmax(flat, axis=-1).astype(jnp.int32))
+        logp = jax.nn.log_softmax(flat, axis=-1)
+        chosen = jnp.take_along_axis(logp, toks[:, None], axis=-1)[:, 0]
+        shape = (lanes, blk)
+        return (toks.reshape(shape), chosen.reshape(shape),
+                jnp.max(logp, axis=-1).reshape(shape))
+
+    def block(b, carry):
+        kc, vc, out = carry
+        pos0 = positions + b * blk
+        pos = pos0[:, None] + cols[None, :]
+        known = (cols[None, :] < n_given[:, None]) & (b == 0)
+        ids = jnp.where(known, given, cfg.mask_token_id).astype(jnp.int32)
+
+        def denoise(_, c):
+            ids, known, lps, kc, vc = c
+            with jax.named_scope("denoise_step"):
+                logits, kc, vc = _block_forward(
+                    params, kc, vc, ids, pos0, page_tables, valid, cfg,
+                    head=True)
+                with jax.named_scope("sample"):
+                    toks, chosen, conf = sample(logits, pos)
+                    fix = unmask_choice(~known, conf, per_step, strategy)
+                    ids = jnp.where(fix, toks, ids)
+                    lps = jnp.where(fix, chosen, lps)
+            return ids, known | fix, lps, kc, vc
+
+        ids, _, lps, kc, vc = lax.fori_loop(
+            0, denoise_steps, denoise,
+            (ids, known, jnp.zeros((lanes, blk), jnp.float32), kc, vc))
+        with jax.named_scope("block_commit"):
+            _, kc, vc = _block_forward(
+                params, kc, vc, ids, pos0, page_tables, valid, cfg,
+                head=False)
+        rows = jnp.stack([ids.T.astype(jnp.float32), lps.T])  # (2, B, L)
+        return kc, vc, lax.dynamic_update_slice(out, rows, (0, b * blk, 0))
+
+    out0 = jnp.zeros((2, num_blocks * blk, lanes), dtype=jnp.float32)
+    k_cache, v_cache, out = lax.fori_loop(
+        0, num_blocks, block, (k_cache, v_cache, out0))
     return out, k_cache, v_cache
 
 

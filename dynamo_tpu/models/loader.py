@@ -47,6 +47,18 @@ logger = logging.getLogger(__name__)
 # this — the prefetcher contract (reads replay the order exactly)
 # breaks if any copy drifts.
 MOE_FFN = (("w_gate", "w1"), ("w_up", "w3"), ("w_down", "w2"))
+# The Qwen3-MoE layout (qwen3_moe, sdar_moe): the router is `mlp.gate`, an
+# expert's projections carry the dense MLP's names.
+QWEN3_MOE_FFN = (("w_gate", "gate_proj"), ("w_up", "up_proj"),
+                 ("w_down", "down_proj"))
+
+
+def _moe_layout(idx) -> tuple[str, tuple]:
+    """(prefix under `model.layers.{}.` of the router and the experts, the
+    FFN key mapping) of the MoE layout this checkpoint is written in."""
+    if "model.layers.0.mlp.gate.weight" in idx:
+        return "mlp.", QWEN3_MOE_FFN
+    return "block_sparse_moe.", MOE_FFN
 
 
 def resolve_model(name_or_path: str) -> str:
@@ -78,7 +90,7 @@ def config_from_hf(path: str, **overrides: Any) -> LlamaConfig:
     with open(os.path.join(path, "config.json")) as f:
         hf = json.load(f)
     arch = (hf.get("architectures") or ["LlamaForCausalLM"])[0]
-    known = ("llama", "mistral", "mixtral", "qwen2")
+    known = ("llama", "mistral", "mixtral", "qwen2", "qwen3moe", "sdar")
     if not any(f in arch.lower() for f in known):
         logger.warning("loading %s with the llama-family loader", arch)
     hidden = hf["hidden_size"]
@@ -100,7 +112,26 @@ def config_from_hf(path: str, **overrides: Any) -> LlamaConfig:
                                    "qwen2" in arch.lower())),
     )
     cls = LlamaConfig
-    if "mixtral" in arch.lower() or hf.get("num_local_experts"):
+    if hf.get("model_type") in ("qwen3_moe", "sdar_moe"):
+        # the Qwen3-MoE block: per-head q/k norm, every layer sparse, the
+        # top-k gates renormalised; SDAR generates by diffusion over blocks
+        # on top of it and names its mask id
+        from dynamo_tpu.models.mixtral import MoeConfig
+
+        if (hf.get("decoder_sparse_step", 1) != 1 or hf.get("mlp_only_layers")
+                or not hf.get("norm_topk_prob", True)
+                or hf.get("shared_expert_intermediate_size")):
+            raise ValueError(
+                f"{hf['model_type']} checkpoint at {path}: only the layout "
+                "with every layer sparse, no shared expert and "
+                "norm_topk_prob is served")
+        cls = MoeConfig
+        cfg.update(num_experts=int(hf["num_experts"]),
+                   experts_per_token=int(hf["num_experts_per_tok"]),
+                   intermediate_size=int(hf["moe_intermediate_size"]),
+                   qk_norm=True,
+                   mask_token_id=int(hf.get("mask_token_id", -1)))
+    elif "mixtral" in arch.lower() or hf.get("num_local_experts"):
         from dynamo_tpu.models.mixtral import MoeConfig
 
         n_exp = hf.get("num_local_experts")
@@ -197,7 +228,8 @@ def load_llama_params(path: str, cfg: LlamaConfig) -> dict:
         # w1 (gate) / w3 (up) / w2 (down), stacked to the (L, X, ...)
         # expert stacks mixtral.init_moe_params defines
         X = cfg.num_experts
-        bs = p + "block_sparse_moe."
+        prefix, ffn = _moe_layout(idx)
+        bs = p + prefix
 
         def stack_experts(w_fmt: str) -> np.ndarray:
             return np.stack([
@@ -205,7 +237,7 @@ def load_llama_params(path: str, cfg: LlamaConfig) -> dict:
                           for e in range(X)]) for i in range(L)])
 
         layers["router"] = stack(bs + "gate.weight")
-        for key, w in MOE_FFN:
+        for key, w in ffn:
             layers[key] = stack_experts(
                 "experts.{}." + w + ".weight")
     else:
@@ -224,6 +256,10 @@ def load_llama_params(path: str, cfg: LlamaConfig) -> dict:
             params["layers"][key] = np.stack(
                 [idx.get(p.format(i) + f"self_attn.{name}.bias")
                  .astype(w_dtype) for i in range(L)])
+    if cfg.qk_norm:
+        for key in ("q_norm", "k_norm"):
+            params["layers"][key] = stack_norm(
+                p + f"self_attn.{key}.weight")
     if "lm_head.weight" in idx:
         params["lm_head"] = dense("lm_head.weight")
     else:  # tie_word_embeddings
@@ -361,9 +397,8 @@ def load_llama_params_device(path: str, cfg: LlamaConfig,
     # a time like everything else (a host-side expert-stack build of an
     # 8x7B would need ~2x checkpoint RAM and tens of minutes of strided
     # transposes — exactly what this function exists to avoid)
-    bs = p + "block_sparse_moe."
-
-    from dynamo_tpu.engine.quant import QTensor
+    prefix, ffn = _moe_layout(idx)
+    bs = p + prefix
 
     # exact read order (the prefetcher replays it; EVERY read goes
     # through it — the safetensors handles must only be touched by the
@@ -371,12 +406,16 @@ def load_llama_params_device(path: str, cfg: LlamaConfig,
     order = [fmt.format(i) for fmt in names.values() for i in range(L)]
     if moe:
         order += [bs.format(i) + "gate.weight" for i in range(L)]
-        for _, w in MOE_FFN:
+        for _, w in ffn:
             order += [bs.format(i) + f"experts.{e}.{w}.weight"
                       for i in range(L)
                       for e in range(cfg.num_experts)]
-    for fmt in ("input_layernorm.weight",
-                "post_attention_layernorm.weight"):
+    norms = {"attn_norm": "input_layernorm.weight",
+             "mlp_norm": "post_attention_layernorm.weight"}
+    if cfg.qk_norm:
+        norms.update(q_norm="self_attn.q_norm.weight",
+                     k_norm="self_attn.k_norm.weight")
+    for fmt in norms.values():
         order += [p.format(i) + fmt for i in range(L)]
     if cfg.attention_bias:
         for name in ("q_proj", "k_proj", "v_proj"):
@@ -413,7 +452,7 @@ def load_llama_params_device(path: str, cfg: LlamaConfig,
     try:
         return _load_device_body(
             cfg, idx, pf, names, p, dense, throttle, state, q_layer,
-            quantize, quant_fn, bits, act_bits, L, _log)
+            quantize, quant_fn, bits, act_bits, L, _log, bs, ffn, norms)
     finally:
         # unblock + join the reader even when the prep loop raised
         # (device OOM mid-load must not leak a put-blocked thread
@@ -424,7 +463,7 @@ def load_llama_params_device(path: str, cfg: LlamaConfig,
 
 def _load_device_body(cfg, idx, pf, names, p, dense, throttle, state,
                       q_layer, quantize, quant_fn, bits, act_bits, L,
-                      _log) -> dict:
+                      _log, bs, ffn, norms) -> dict:
     import jax
     import jax.numpy as jnp
 
@@ -459,11 +498,10 @@ def _load_device_body(cfg, idx, pf, names, p, dense, throttle, state,
                 [dense(fmt.format(i)) for i in range(L)])
     if getattr(cfg, "num_experts", 0):
         X = cfg.num_experts
-        bs = p + "block_sparse_moe."
         _log.info("loading MoE router + %d experts x %d layers", X, L)
         layers["router"] = jnp.stack(
             [dense(bs.format(i) + "gate.weight") for i in range(L)])
-        for key, w in MOE_FFN:
+        for key, w in ffn:
             if quantize:
                 # per-(layer,expert) scales == quantizing the full
                 # stack: the reduction is over the contraction dim only
@@ -475,10 +513,9 @@ def _load_device_body(cfg, idx, pf, names, p, dense, throttle, state,
                     jnp.stack([dense(bs.format(i)
                                      + f"experts.{e}.{w}.weight")
                                for e in range(X)]) for i in range(L)])
-    for key, fmt in (("attn_norm", p + "input_layernorm.weight"),
-                     ("mlp_norm", p + "post_attention_layernorm.weight")):
+    for key, fmt in norms.items():
         layers[key] = jnp.stack(
-            [jnp.asarray(pf.get(fmt.format(i)), dtype=jnp.float32)
+            [jnp.asarray(pf.get(p.format(i) + fmt), dtype=jnp.float32)
              for i in range(L)])
     if cfg.attention_bias:
         # Qwen2 family: 1-D q/k/v biases (tiny — host stack is fine)

@@ -5,15 +5,17 @@ recipes, `recipes/deepseek-r1/sglang-wideep/tep16p-dep16d-disagg.yaml:
 60-63`) by delegating EP to the engine; here the engine is ours, so the
 expert layout is native. TPU-first formulation:
 
-- Routing is computed densely (softmax over router logits, top-k mask).
-- Expert FFNs are evaluated as ONE batched einsum over the expert axis
-  with a per-token weight mask — no gather/scatter, no dynamic shapes,
-  so XLA tiles it straight onto the MXU. Compute cost is num_experts/k×
-  the routed FLOPs; with the expert axis sharded over an "ep" mesh axis
-  GSPMD partitions that einsum so each chip only computes ITS experts,
-  then inserts one psum to combine — the classic all-gathered-activation
-  EP layout (good up to moderate expert counts; a capacity-based
-  all-to-all dispatch is the next step when expert count × tokens grows).
+- Routing: softmax over the top-k router logits (`moe_route`).
+- Where the experts are local (one device, a pp stage) the FFN is ROUTED:
+  rows sorted by expert, one grouped product a projection over the stack
+  (engine/moe_gmm.py), unsorted and combined. Static shapes (each
+  expert's run padded to whole row tiles), the routed FLOPs, nothing
+  dropped, and only the experts some token chose are read.
+- With the expert axis sharded over an "ep" mesh axis the FFN stays ONE
+  batched einsum over the expert axis with a per-token weight mask
+  (`_moe_mlp_dense`): GSPMD partitions it so each chip computes ITS
+  experts, then inserts one psum to combine. Compute is num_experts/k×
+  the routed FLOPs there (an exchange of routed rows is the next step).
 - Attention/norms/embedding reuse the Llama blocks unchanged.
 
 `ep_param_specs()` gives the PartitionSpecs (expert axis → "ep"); the
@@ -37,6 +39,7 @@ from dynamo_tpu.models.llama import (
     LlamaConfig,
     _layer_params,
     dense_layer,
+    init_qk_norm,
     rms_norm,
 )
 
@@ -81,6 +84,9 @@ def init_moe_params(rng: jax.Array, cfg: MoeConfig) -> dict:
         return (jax.random.normal(key, shape, dtype=jnp.float32)
                 * scale).astype(cfg.dtype)
 
+    # drawn last, so seeded inits of configs without it are unchanged
+    qk = init_qk_norm(jax.random.split(rng, 13)[12], cfg) \
+        if cfg.qk_norm else {}
     return {
         "embed": dense(next(k), E, cfg.vocab_size, E),
         "layers": {
@@ -94,6 +100,7 @@ def init_moe_params(rng: jax.Array, cfg: MoeConfig) -> dict:
             "w_gate": dense(next(k), E, L, X, E, F),
             "w_up": dense(next(k), E, L, X, E, F),
             "w_down": dense(next(k), F, L, X, F, E),
+            **qk,
         },
         "final_norm": norm(E),
         "lm_head": dense(next(k), E, E, cfg.vocab_size),
@@ -122,20 +129,81 @@ def _qe(subscripts: str, x: jax.Array, w) -> jax.Array:
     return jnp.einsum(subscripts, x, w)
 
 
+def _experts_sharded() -> bool:
+    """True while tracing under a mesh with an 'ep' axis of more than one
+    device (the engine dispatches inside `jax.set_mesh`): the expert axis
+    of the stacks is then split by GSPMD and the dense mask below is the
+    form it partitions."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return (not mesh.empty) and mesh.shape.get("ep", 1) > 1
+
+
+def moe_route(h: jax.Array, lp: dict, cfg: MoeConfig
+              ) -> tuple[jax.Array, jax.Array]:
+    """(gates (..., T, k) f32, experts (..., T, k) i32): each token's k
+    best experts by the router's logits and their weights, the softmax
+    over those k logits. That equals the softmax over all experts
+    renormalised over the k chosen (`norm_topk_prob`): exp(l_e) / sum over
+    the chosen of exp(l), whichever sum came first."""
+    router_logits = (h @ lp["router"]).astype(jnp.float32)  # (..., T, X)
+    topv, topi = jax.lax.top_k(router_logits, cfg.experts_per_token)
+    return jax.nn.softmax(topv, axis=-1), topi
+
+
 def moe_mlp(h: jax.Array, lp: dict, cfg: MoeConfig) -> jax.Array:
     """Top-k routed expert FFN. h: (..., T, E) → (..., T, E).
 
-    Dense-dispatch: every expert computes every token, the top-k softmax
-    weight mask zeroes the rest. The expert axis ('x' below) is the EP
-    sharding axis — under a mesh with the expert dims of w_gate/up/down
+    Routed dispatch wherever the experts are local (one device, a pp
+    stage's slice): the k x T routed rows are laid out expert by expert
+    and meet the stacks in one grouped product each for gate, up and down
+    (engine/moe_gmm.py), so the work is the routed work and an expert no
+    token chose is never read. No token is dropped. Expert stacks may be
+    int8 QTensors (weight-only, engine quantize="int8"), read as int8.
+    Under an 'ep' mesh the dense mask (`_moe_mlp_dense`) is kept: the
+    choice is by what the mesh is."""
+    if _experts_sharded():
+        return _moe_mlp_dense(h, lp, cfg)
+    from dynamo_tpu.engine.moe_gmm import (grouped_matmul, padded_rows,
+                                           route_layout, row_tile)
+
+    k, n_exp = cfg.experts_per_token, cfg.num_experts
+    flat = h.reshape(-1, h.shape[-1])                       # (T, E)
+    routed = flat.shape[0] * k
+    tile = row_tile(routed, n_exp, *lp["w_gate"].shape[-2:])
+    with jax.named_scope("moe_route"):
+        gates, topi = moe_route(flat, lp, cfg)              # (T, k)
+        pos, tile_expert, n_used, sizes = route_layout(
+            topi.reshape(-1), n_exp, tile)
+        # the token each row of the padded layout holds (padding: token 0,
+        # computed and never read)
+        src = jnp.zeros(padded_rows(routed, n_exp, tile), jnp.int32).at[
+            pos].set(jnp.arange(routed, dtype=jnp.int32) // k)
+    stacks, layer = lp.get("expert_stacks", (None, 0))
+
+    def product(rows, name):
+        return grouped_matmul(
+            rows, lp[name], tile_expert, n_used, sizes, tile,
+            layers=None if stacks is None else (stacks[name], layer))
+
+    with jax.named_scope("moe_experts"):
+        rows = flat[src]                                    # (M, E)
+        gate = jax.nn.silu(product(rows, "w_gate"))
+        down = product(gate * product(rows, "w_up"), "w_down")  # (M, E)
+    with jax.named_scope("moe_combine"):
+        per_token = down[pos].reshape(flat.shape[0], k, -1)
+        out = jnp.einsum("tke,tk->te", per_token.astype(jnp.float32),
+                         gates).astype(h.dtype)
+    return out.reshape(h.shape)
+
+
+def _moe_mlp_dense(h: jax.Array, lp: dict, cfg: MoeConfig) -> jax.Array:
+    """Dense dispatch: every expert computes every token, the top-k
+    softmax weight mask zeroes the rest. The expert axis ('x' below) is the
+    EP sharding axis — under a mesh with the expert dims of w_gate/up/down
     sharded over "ep", GSPMD computes each chip's experts locally and
-    psums the weighted combine. Expert stacks may be int8 QTensors
-    (weight-only; engine quantize="int8") — with ep=8 that puts
-    Mixtral-8x7B experts at ~5.9 GB/chip, inside a v5e."""
-    router_logits = (h @ lp["router"]).astype(jnp.float32)  # (..., T, X)
-    k = cfg.experts_per_token
-    topv, topi = jax.lax.top_k(router_logits, k)            # (..., T, k)
-    gates = jax.nn.softmax(topv, axis=-1)                   # (..., T, k)
+    psums the weighted combine. Compute is num_experts / k times the
+    routed work: the form for a sharded expert axis only."""
+    gates, topi = moe_route(h, lp, cfg)                     # (..., T, k)
     # scatter the k gate weights back to a dense (..., T, X) mask
     dense_w = jnp.sum(
         jax.nn.one_hot(topi, cfg.num_experts, dtype=jnp.float32)

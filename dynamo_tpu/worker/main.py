@@ -89,6 +89,20 @@ def parse_args(argv=None) -> argparse.Namespace:
     p.add_argument("--spec-iters-per-sync", type=int, default=8,
                    help="fused spec iterations per host sync (scales "
                         "burst length and the admission lookahead)")
+    p.add_argument("--dllm-block-length", type=int, default=0,
+                   help="block-diffusion models (SDAR): tokens a block; "
+                        "generation denoises one block at a time and "
+                        "attention is causal by blocks of this length. "
+                        "Required for such a checkpoint")
+    p.add_argument("--dllm-denoising-steps", type=int, default=0,
+                   help="forwards that denoise a block before the one "
+                        "that commits it (default: the block length, one "
+                        "position a step)")
+    p.add_argument("--dllm-unmasking-strategy", default="sequential",
+                   choices=["sequential", "low_confidence_static"],
+                   help="which masked positions a denoising step fixes: "
+                        "the leftmost, or those whose best token is most "
+                        "probable")
     p.add_argument("--sp-degree", type=int, default=0,
                    help="ring size for sequence-parallel long-prompt "
                         "prefill (0 = off; uses the first N local "
@@ -222,6 +236,8 @@ def build_engine_and_card(args: argparse.Namespace, event_sink, metrics_sink,
     overrides = {}
     if args.context_length is not None:
         overrides["max_pages_per_seq"] = max(1, args.context_length // 16)
+    if args.dllm_block_length:
+        overrides["attn_block"] = args.dllm_block_length
     engine, card = build_tpu_engine(
         args.model, served_name=args.served_model_name,
         num_pages=args.num_pages, max_batch_size=args.max_batch_size,
@@ -238,6 +254,9 @@ def build_engine_and_card(args: argparse.Namespace, event_sink, metrics_sink,
         spec_iters_per_sync=args.spec_iters_per_sync,
         sp_degree=args.sp_degree, sp_threshold=args.sp_threshold,
         sp_layout=args.sp_layout,
+        dllm_denoising_steps=(args.dllm_denoising_steps
+                              or args.dllm_block_length),
+        dllm_unmasking_strategy=args.dllm_unmasking_strategy,
         pipeline_parallel_size=args.pipeline_parallel_size,
         pp_microbatches=args.pp_microbatches, **overrides)
     if mesh is not None:

@@ -300,3 +300,79 @@ def test_tensor_parallel_steps_split_the_kernels(
     assert text.count("tpu_custom_call") >= 2 * cfg.num_layers
     assert text.count(attention) >= cfg.num_layers
     assert "all-reduce" in text
+
+
+# -- SDAR-30B-A3B widths: the block burst and a prefill round ----------------
+
+SDAR = dict(vocab_size=151936, hidden_size=2048, intermediate_size=768,
+            num_heads=32, num_kv_heads=4, head_dim=128, rope_theta=1e6,
+            rms_eps=1e-6, num_experts=128, experts_per_token=8,
+            qk_norm=True, attn_block=4, mask_token_id=151669,
+            max_pages_per_seq=65)
+
+
+def sdar_model(sds, layers=2, pages=4096):
+    """(cfg, params, k_cache, v_cache) as shapes on the described chip at
+    the benchmark's SDAR widths: int8 layers and expert stacks, bf16 head
+    (vocab above 65536), as `--quantize int8` serves them."""
+    from dynamo_tpu.engine.quant import quantize_params
+    from dynamo_tpu.models.llama import init_cache, init_params
+    from dynamo_tpu.models.mixtral import MoeConfig
+
+    cfg = MoeConfig(num_layers=layers, **SDAR)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda k: quantize_params(init_params(k, cfg), mode="int8"),
+        jax.random.PRNGKey(0)))
+    kc, vc = on_chip(jax.eval_shape(lambda: init_cache(cfg, pages)))
+    return cfg, params, kc, vc
+
+
+def test_sdar_block_burst_holds_its_kernels(sds, pallas_impl):
+    from dynamo_tpu.models.llama import block_decode_multi_step
+
+    cfg, params, kc, vc = sdar_model(sds)
+    assert params["layers"]["w_gate"].q.dtype == jnp.int8
+    b, i32, f32 = 64, jnp.int32, jnp.float32
+    compiled = block_decode_multi_step.lower(
+        params, kc, vc, sds((b, 4), i32), sds((b,), i32), sds((b,), i32),
+        sds((b, 65), i32), sds((b,), jnp.bool_), sds((b,), jnp.uint32),
+        sds((b,), f32), sds((b,), f32), sds((b,), i32), cfg, 2, 4,
+        "sequential").compile()
+    text = compiled.as_text()
+    # a denoising forward and the committing one, each per layer: the
+    # block's KV write, decode attention at 32 rows a kv head, and the
+    # three grouped products over the int8 expert stacks
+    assert text.count("kv_write_block") >= 2 * cfg.num_layers
+    assert text.count("paged_decode_attention") >= 2 * cfg.num_layers
+    assert text.count("moe_gmm") >= 2 * 3 * cfg.num_layers
+    assert not _missing(text, LAYER_SCOPES + (
+        "denoise_step", "block_commit", "moe_route", "moe_experts",
+        "moe_combine", "sample"))
+    # no copy of an expert stack, widened to bf16 or a layer's slice of the
+    # int8 one (the kernel indexes the layer itself): (128, 2048, 768) or
+    # its transpose
+    assert not re.search(r"(bf16|s8)\[128,(2048,768|768,2048)\]", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+@pytest.mark.parametrize("bp", [1, 16])
+def test_sdar_prefill_round(sds, pallas_impl, bp):
+    from dynamo_tpu.models.llama import prefill_batch
+
+    cfg, params, kc, vc = sdar_model(sds)
+    i32 = jnp.int32
+    compiled = prefill_batch.lower(
+        params, kc, vc, sds((bp, 512), i32), sds((bp, 65), i32),
+        sds((bp,), i32), sds((bp,), i32), cfg, aligned=True).compile()
+    text = compiled.as_text()
+    assert text.count("kv_write_pages") >= cfg.num_layers
+    assert text.count("paged_prefill_attention") >= cfg.num_layers
+    assert text.count("moe_gmm") >= 3 * cfg.num_layers
+    assert not re.search(r"(bf16|s8)\[128,(2048,768|768,2048)\]", text)
+    # 16 x 512 tokens x top-8 = 65536 routed rows, padded to 98304, of
+    # 2048 bf16 in and out of the experts: under 2.5 GB of temporaries
+    assert compiled.memory_analysis().temp_size_in_bytes < (5 << 29)

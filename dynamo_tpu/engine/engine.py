@@ -2506,7 +2506,10 @@ class TpuEngine:
         (models/llama.py block_decode_multi_step), one host sync, then
         each lane's tokens go out in one frame. A step stays one token a
         lane, so a burst is decode_steps_per_sync tokens a lane at
-        (denoising steps + 1) / B forwards a token."""
+        (denoising steps + 1) / B forwards a token. The burst is
+        launched, then waited for with the scheduler awake: whoever
+        arrives meanwhile is admitted and prefilled behind it
+        (_land_block_burst)."""
         from dynamo_tpu.models.llama import block_decode_multi_step
 
         runnable = [s for s in self._running if s.prefilled]
@@ -2539,27 +2542,37 @@ class TpuEngine:
             led.on_dispatch(trk.entry, trk.shape, compiled=trk.compiled)
         forwards = len(batch) * n_blocks * (steps + 1)
 
-        def run_burst():
-            packed, kc, vc = self._mesh_dispatch(
-                trk, block_decode_multi_step,
-                self.params, self.k_cache, self.v_cache,
-                jax.numpy.asarray(given), jax.numpy.asarray(n_given),
-                jax.numpy.asarray(positions),
-                jax.numpy.asarray(page_tables), jax.numpy.asarray(valid),
-                jax.numpy.asarray(seeds), jax.numpy.asarray(temps),
-                jax.numpy.asarray(top_ps), jax.numpy.asarray(top_ks),
-                mcfg, n_blocks, steps, cfg.dllm_unmasking_strategy,
-                span_tokens=len(batch) * k_steps,
-                routed_tokens=forwards * blk)
-            return self._host_sync(packed), kc, vc         # ONE host sync
-
-        async with self._device_lock:
+        def launch():
             with trk:
-                packed, self.k_cache, self.v_cache = \
-                    await asyncio.to_thread(run_burst)
+                return self._mesh_dispatch(
+                    trk, block_decode_multi_step,
+                    self.params, self.k_cache, self.v_cache,
+                    jax.numpy.asarray(given), jax.numpy.asarray(n_given),
+                    jax.numpy.asarray(positions),
+                    jax.numpy.asarray(page_tables),
+                    jax.numpy.asarray(valid), jax.numpy.asarray(seeds),
+                    jax.numpy.asarray(temps), jax.numpy.asarray(top_ps),
+                    jax.numpy.asarray(top_ks),
+                    mcfg, n_blocks, steps, cfg.dllm_unmasking_strategy,
+                    span_tokens=len(batch) * k_steps,
+                    routed_tokens=forwards * blk)
+
+        t_launch = time.perf_counter()
+        async with self._device_lock:
+            # A first call compiles for seconds and goes to a thread.
+            # Any other is launched from the loop's own thread: the emit
+            # has just handed the loop a frame a lane to send, and a
+            # launch in a thread of its own would share the GIL with
+            # that work, each of its input transfers waiting out a
+            # switch interval while the device waits for the burst
+            packed, self.k_cache, self.v_cache = (
+                await asyncio.to_thread(launch) if trk.compiled
+                else launch())
+        packed = await self._land_block_burst(packed)      # ONE host sync
         rec = self.step_recorder
         if rec is not None:
-            rec.record(trk.entry, trk.shape, trk.elapsed_s,
+            rec.record(trk.entry, trk.shape,
+                       time.perf_counter() - t_launch,
                        good_tokens=len(batch) * k_steps,
                        work_tokens=b * k_steps, lanes=len(batch),
                        width=b, tokens=len(batch) * k_steps,
@@ -2590,6 +2603,44 @@ class TpuEngine:
                 self._emit_lane(s, ids[head:, i], packed[1, head:, i],
                                 append_inputs=False)
         return True
+
+    async def _land_block_burst(self, packed) -> np.ndarray:
+        """Wait for the block burst in flight with the scheduler awake:
+        the sync runs in a thread, and whoever arrives meanwhile and
+        finds a lane and pages is admitted at once (_admit, its rules
+        unchanged). Their whole-block rounds go out as ONE batch, as the
+        loop's own prefill would make it (a round reads every expert's
+        weights whatever its width): behind the burst as soon as every
+        lane is taken, since nobody else can join the round then, and
+        otherwise when the burst lands, ahead of its emission, so the
+        device goes from the burst to the rounds while the host emits
+        and builds the next burst. A round writes only the new
+        sequences' pages, and none of them can belong to a lane of the
+        burst: a lane that ended was released when its own burst landed,
+        and nothing here preempts. An arrival _admit turns away (no
+        lane, no pages), or one that may need a remote onboard awaited,
+        waits for the landing and the loop's own admit, as every arrival
+        did."""
+        async def refill() -> None:
+            behind = sum(not s.prefilled for s in self._running)
+            if await self._prefill_blocks():
+                self.metrics.refills_behind_burst.inc(behind)
+
+        sync = asyncio.ensure_future(
+            asyncio.to_thread(self._host_sync, packed))
+        sync.add_done_callback(lambda _: self._wake.set())
+        local = self.kvbm is None or self.kvbm.remote is None
+        while not sync.done():
+            self._wake.clear()
+            if self._waiting and local:
+                with self._span("admit"):
+                    self._admit()
+                if len(self._running) >= self.config.max_batch_size:
+                    await refill()
+            await self._wake.wait()
+        packed = sync.result()
+        await refill()
+        return packed
 
     def _mesh_dispatch(self, trk, fn, *args, span_tokens: int = 0,
                        routed_tokens: Optional[int] = None, **kwargs):

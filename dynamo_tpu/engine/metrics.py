@@ -119,6 +119,10 @@ class EngineMetrics:
             "dynamo_engine_chained_refills_total",
             "decode bursts launched behind a first-token sampler "
             "before it was synced")
+        self.refills_behind_burst = c(
+            "dynamo_engine_refills_behind_burst_total",
+            "sequences admitted while a block burst was in flight and "
+            "prefilled behind it, ahead of its emission")
         self.mixed_steps = c(
             "dynamo_engine_mixed_steps_total",
             "fused prefill-chunk + decode-burst steps")
@@ -188,7 +192,7 @@ class EngineMetrics:
                   self.decode_seconds, self.tokens_emitted,
                   self.prefill_emitted, self.prefill_new_tokens,
                   self.pipelined_bursts, self.chained_refills,
-                  self.mixed_steps,
+                  self.refills_behind_burst, self.mixed_steps,
                   self.decode_steps_during_prefill,
                   self.block_forwards, self.blocks, self.moe_routed_rows,
                   self.goodput_tokens, self.padded_tokens,
@@ -219,6 +223,7 @@ class EngineMetrics:
             "tokens_emitted": int(self.tokens_emitted.get()),
             "pipelined_bursts": int(self.pipelined_bursts.get()),
             "chained_refills": int(self.chained_refills.get()),
+            "refills_behind_burst": int(self.refills_behind_burst.get()),
             "prefill_chunks": self.prefill_chunk.count,
             "decode_steps_during_prefill":
                 int(self.decode_steps_during_prefill.get()),

@@ -40,13 +40,10 @@ from dynamo_tpu.engine.profiler import recorder_from_env
 from dynamo_tpu.engine.sampling import sample_tokens_lp
 from dynamo_tpu.llm.perf import itl_percentile
 from dynamo_tpu.engine.attention import ragged_enabled
+from dynamo_tpu.models import family_module
 from dynamo_tpu.models.llama import (
     LlamaConfig,
-    decode_multi_step,
-    init_cache,
-    init_params,
     mixed_prefill_decode,
-    prefill_batch,
     ragged_prefill_decode,
 )
 from dynamo_tpu.protocols import (
@@ -307,6 +304,9 @@ class _Seq:
     # block diffusion: ids already known at the head of the lane's next
     # block (a prompt's tail past the last whole block), not yet committed
     given: list[int] = field(default_factory=list)
+    # the state slot a sequence of a model with recurrent layers owns from
+    # admission to its end (engine/pages.py SlotPool); 0: none
+    slot: int = 0
     _hist: Optional[tuple] = None         # (len(prompt), (V,) histogram)
 
     @property
@@ -399,6 +399,12 @@ class TpuEngine:
         # the caller's objects
         owned_params = params is None
         owned_draft = draft_params is None
+        self._dllm = mcfg.attn_block > 1
+        # a model with recurrent layers keeps a per-sequence state beside
+        # the pages, in slots; what does not know of it is refused
+        self.recurrent = bool(getattr(mcfg, "recurrent", False))
+        if self.recurrent:
+            self._check_recurrent()
         if getattr(mcfg, "num_experts", 0):
             # MoE serving layouts: single-device, pp_mesh (stage slices
             # carry their experts), an ('ep',) mesh (experts shard,
@@ -438,9 +444,20 @@ class TpuEngine:
             raise ValueError(
                 "dense-family mesh serving shards over 'tp'; an "
                 "('ep',) mesh is for MoE models")
-        self._dllm = mcfg.attn_block > 1
         if self._dllm:
             self._check_block_diffusion()
+        self.slots = None
+        cache_kw = {}
+        if self.recurrent:
+            from dynamo_tpu.engine.pages import SlotPool
+
+            self.slots = SlotPool(cfg.max_batch_size + 1)
+            cache_kw["num_slots"] = self.slots.num_slots
+        # the one place the entries are taken: by the configuration's class
+        entries = family_module(mcfg)
+        init_params, init_cache = entries.init_params, entries.init_cache
+        self._prefill_batch = entries.prefill_batch
+        self._decode_multi_step = entries.decode_multi_step
 
         def place_owned(p, owned: bool):
             """Host (numpy) checkpoints must land on device ONCE at
@@ -504,7 +521,8 @@ class TpuEngine:
             else:
                 params, owned_params = place_owned(params, owned_params)
             self.params = params
-            self.k_cache, self.v_cache = init_cache(mcfg, cfg.num_pages)
+            self.k_cache, self.v_cache = init_cache(mcfg, cfg.num_pages,
+                                                    **cache_kw)
         else:
             from dynamo_tpu.engine.sharding import (
                 cache_sharding,
@@ -583,7 +601,9 @@ class TpuEngine:
                 # that transient doubles ~9 GB of weights and OOMs the
                 # chip
                 return isinstance(p.get("lm_head"), QTensor) or any(
-                    isinstance(v, QTensor) for v in p["layers"].values())
+                    isinstance(v, QTensor) for v in jax.tree.leaves(
+                        p["layers"],
+                        is_leaf=lambda x: isinstance(x, QTensor)))
 
             # donation frees the bf16 buffers, but ONLY when the engine
             # created (or sharded-copied) them — donating caller-provided
@@ -699,6 +719,8 @@ class TpuEngine:
         # ForwardPassMetrics prefill/decode queues) — here the split is
         # measured at the source.
         self.metrics = EngineMetrics()
+        if self.slots is not None:
+            self.metrics.state_slots.set(self.slots.num_slots - 1)
         # Step flight recorder (engine/profiler.py): None unless
         # DYN_STEP_PROFILE is set — every hot-loop touch below is gated
         # on `is not None`, so off means zero allocation and a
@@ -775,12 +797,23 @@ class TpuEngine:
             self.memory_ledger.set_class(
                 "weights", params_footprint(self.params),
                 source="models/loader post-load footprint")
+            # the recurrent layers' state rides in the cache tuples and
+            # is a class of its own
+            state_bytes = 0
+            if self.recurrent:
+                from dynamo_tpu.engine.pages import state_slot_bytes
+
+                state_bytes = self.slots.num_slots * state_slot_bytes(
+                    mcfg, jnp.dtype(mcfg.dtype).itemsize)
+                self.memory_ledger.set_class(
+                    "state_slots", state_bytes,
+                    source="engine/pages.py SlotPool x state_slot_bytes")
             # provider, not a frozen number: k/v caches are donated and
             # replaced every step, and quantized KV swaps the dtype
             self.memory_ledger.provider(
                 "kv_pool",
                 lambda: sum(a.nbytes for a in self.k_cache)
-                + sum(a.nbytes for a in self.v_cache),
+                + sum(a.nbytes for a in self.v_cache) - state_bytes,
                 source="engine/pages.py PagePool reservation")
         # raw ITL samples (ms), capped FIFO — bench reads these for
         # exact percentiles; the wire carries only the histogram
@@ -812,13 +845,60 @@ class TpuEngine:
         # keep the dense mask): what dynamo_moe_routed_rows_total counts
         self._routed_per_token = 0
         if getattr(mcfg, "num_experts", 0) and cfg.mesh is None:
-            self._routed_per_token = (mcfg.experts_per_token
-                                      * mcfg.num_layers)
+            self._routed_per_token = mcfg.experts_per_token * getattr(
+                mcfg, "num_moe_layers", mcfg.num_layers)
         # disagg: finished prefill-only sequences whose pages are pinned
         # until the decode worker pulls them (transfer_id -> (pages, len,
         # deadline)); reaped by the scheduler loop after transfer_ttl.
         self._transfers: dict[str, tuple[list[int], int, float]] = {}
         self.transfer_ttl = 60.0
+
+    def _check_recurrent(self) -> None:
+        """A model with recurrent layers serves through prefill rounds and
+        the plain decode burst on one device. Every subsystem that reads
+        "a prefix of tokens is a set of pages" would have to carry the
+        state at that boundary too; until it does (state snapshots at
+        block boundaries) it is refused at start, each with its reason."""
+        cfg = self.config
+        if cfg.mesh is not None or cfg.pp_mesh is not None \
+                or cfg.sp_mesh is not None:
+            raise ValueError(
+                "a model with recurrent layers is served on one device: "
+                "tp / pp / sp / ep above 1 would split or move a "
+                "per-sequence state no mesh entry knows")
+        if cfg.draft_model is not None:
+            raise ValueError(
+                "a model with recurrent layers does not compose with a "
+                "draft model: a rejected draft token cannot be taken back "
+                "out of the state")
+        if self._dllm or cfg.dllm_denoising_steps:
+            raise ValueError(
+                "a model with recurrent layers is not served by block "
+                "diffusion (--dllm-*): a denoising forward would advance "
+                "the state it only reads")
+        if cfg.prefill_chunk_budget > 0:
+            raise ValueError(
+                "a model with recurrent layers does not compose with "
+                "prefill_chunk_budget: the mixed step has no slots")
+        if ragged_enabled():
+            raise ValueError(
+                "a model with recurrent layers does not compose with the "
+                "ragged attention path (DYN_ATTENTION_IMPL=ragged): its "
+                "flat rows have no slots")
+        logger.warning(
+            "model has recurrent layers: prefix reuse is OFF (every prompt "
+            "is prefilled whole, no KV event is published, the KV router "
+            "falls back to load); KVBM tiers and disaggregated transfers "
+            "are refused")
+
+    def refuse_if_recurrent(self, what: str) -> None:
+        """For what attaches to an engine after it is built (a KVBM tier, a
+        disaggregated role)."""
+        if self.recurrent:
+            raise ValueError(
+                f"a model with recurrent layers does not serve {what}: "
+                "the pages of a prefix are not its whole state (state "
+                "snapshots at block boundaries would turn this on)")
 
     def _check_block_diffusion(self) -> None:
         """A block-diffusion engine serves through prefill rounds and the
@@ -926,6 +1006,22 @@ class TpuEngine:
                     token_ids=[], finish_reason=FINISH_ERROR,
                     extra={"error": f"block diffusion does not serve "
                                     f"{refused}"}).to_dict()
+                return
+        if self.recurrent:
+            sp = req.sampling
+            refused = (
+                "guided decoding" if sp.guided
+                else "min_p and sampling penalties" if (
+                    sp.min_p > 0.0 or sp.repetition_penalty != 1.0
+                    or sp.frequency_penalty != 0.0
+                    or sp.presence_penalty != 0.0)
+                else "a KV import or export" if req.kv_transfer_params
+                else "embeddings" if req.extra.get("embed") else None)
+            if refused:
+                yield EngineOutput(
+                    token_ids=[], finish_reason=FINISH_ERROR,
+                    extra={"error": "a model with recurrent layers does "
+                                    f"not serve {refused}"}).to_dict()
                 return
         guided_tables = None
         guided_key = None
@@ -1136,6 +1232,7 @@ class TpuEngine:
                 token_ids=[], finish_reason=FINISH_CANCELLED).to_dict())
             s.queue.put_nowait(None)
             self.pool.release_sequence(s.pages)
+            self._give_slot(s)
         self._running.clear()
         self._waiting.clear()
 
@@ -1265,6 +1362,7 @@ class TpuEngine:
                 extra={"error": "engine step failed"}).to_dict())
             s.queue.put_nowait(None)
             self.pool.release_sequence(s.pages)
+            self._give_slot(s)
         self._running.clear()
         self._waiting.clear()
 
@@ -1373,15 +1471,22 @@ class TpuEngine:
                     > cfg.watermark * self.pool.capacity and self._running):
                 continue
             t_adm = time.perf_counter()
-            if cand.import_kv is not None:
-                # disagg import: fresh pages only (remote KV overwrites
-                # them); cached_len comes from the transfer, not hashing
+            if self.recurrent or cand.import_kv is not None:
+                # fresh pages only, without hashes. Disagg import: remote
+                # KV overwrites them and cached_len comes from the
+                # transfer, not hashing. A model with recurrent layers: no
+                # prefix reuse, and the slot the sequence keeps until its
+                # pages go
                 alloc = self._alloc_admission([], len(cand.prompt))
                 if alloc is None:
                     self.metrics.admission_stall.observe(
                         time.perf_counter() - t_adm)
                     continue
-                cand.pages, cand.cached_len = alloc[0], cand.import_kv[1]
+                cand.pages = alloc[0]
+                cand.cached_len = 0 if self.recurrent else cand.import_kv[1]
+                if self.recurrent:
+                    cand.slot = self.slots.take()
+                    self.metrics.state_slots_in_use.set(self.slots.in_use)
             else:
                 alloc = self._alloc_admission(hashes, len(cand.prompt))
                 if alloc is None:
@@ -1682,7 +1787,8 @@ class TpuEngine:
                 # every complete block this worker now holds (no-op for blocks
                 # matched from already-registered shared pages)
                 seq.token_seq = TokenBlockSequence(mcfg.page_size, seq.prompt)
-                for block in seq.token_seq.blocks:
+                for block in (() if self.recurrent
+                              else seq.token_seq.blocks):
                     self.pool.register_page(
                         seq.pages[block.block_index], block.seq_hash,
                         block.local_hash, block.parent_seq_hash)
@@ -2045,6 +2151,25 @@ class TpuEngine:
             seq.pages.append(pid)
         return True
 
+    def _slot_kw(self, seqs: list[_Seq], width: int) -> dict:
+        """`slots=` for an entry of a model with recurrent layers: each
+        sequence's state slot, `width` wide, rows past `seqs` at scratch
+        slot 0. Nothing for every other model, whose entries take none."""
+        if not self.recurrent:
+            return {}
+        slots = np.zeros(width, dtype=np.int32)
+        slots[:len(seqs)] = [s.slot for s in seqs]
+        return {"slots": jax.numpy.asarray(slots)}
+
+    def _give_slot(self, seq: _Seq) -> None:
+        """Return a sequence's state slot, wherever its pages go. No burst
+        in flight has to be waited for: the slot's next tenant starts from
+        zero in a first chunk launched behind it."""
+        if seq.slot:
+            self.slots.give(seq.slot)
+            seq.slot = 0
+            self.metrics.state_slots_in_use.set(self.slots.in_use)
+
     def _decode_lane_arrays(self, batch: list[_Seq], fresh=()) -> tuple:
         """The nine per-lane inputs of a decode burst, max_batch_size
         wide, in the order every decode entry takes them: tokens,
@@ -2108,7 +2233,7 @@ class TpuEngine:
             tokens_dev = _first_tokens_into(tokens_dev, sampled, take, col)
         with trk:
             packed, self.k_cache, self.v_cache = self._mesh_dispatch(
-                trk, decode_multi_step,
+                trk, self._decode_multi_step,
                 self.params, self.k_cache, self.v_cache, tokens_dev,
                 jax.numpy.asarray(positions),
                 jax.numpy.asarray(page_tables),
@@ -2116,7 +2241,7 @@ class TpuEngine:
                 jax.numpy.asarray(steps), jax.numpy.asarray(temps),
                 jax.numpy.asarray(top_ps), jax.numpy.asarray(top_ks),
                 mcfg, k_steps, topk_lp=tk,
-                span_tokens=len(batch) * k_steps)
+                span_tokens=len(batch) * k_steps, **self._slot_kw(batch, b))
         rec = self.step_recorder
         if rec is not None:
             # pipelined: the dispatch returns without a host sync,
@@ -2427,14 +2552,15 @@ class TpuEngine:
                     topk_lp=tk, span_tokens=len(batch) * k_steps)
                 return self._host_sync(sampled), kc, vc
             sampled, kc, vc = self._mesh_dispatch(
-                trk, decode_multi_step,
+                trk, self._decode_multi_step,
                 self.params, self.k_cache, self.v_cache,
                 jax.numpy.asarray(tokens), jax.numpy.asarray(positions),
                 jax.numpy.asarray(page_tables), jax.numpy.asarray(valid),
                 jax.numpy.asarray(seeds), jax.numpy.asarray(steps),
                 jax.numpy.asarray(temps), jax.numpy.asarray(top_ps),
                 jax.numpy.asarray(top_ks), mcfg, k_steps, topk_lp=tk,
-                span_tokens=len(batch) * k_steps)
+                span_tokens=len(batch) * k_steps,
+                **self._slot_kw(batch, b))
             return self._host_sync(sampled), kc, vc       # ONE host sync
 
         trk = self.metrics.compile.track(
@@ -3186,11 +3312,16 @@ class TpuEngine:
             led.on_dispatch(trk.entry, trk.shape, compiled=trk.compiled)
         with trk:
             logits_b, kc, vc = self._mesh_dispatch(
-                trk, prefill_batch,
+                trk, self._prefill_batch,
                 params_, kc, vc,
                 jax.numpy.asarray(toks), jax.numpy.asarray(tables),
                 jax.numpy.asarray(cached), jax.numpy.asarray(seq_lens),
-                model_cfg, aligned, span_tokens=sum(chunk_lens))
+                model_cfg, aligned, span_tokens=sum(chunk_lens),
+                **self._slot_kw(active, bp))
+        if self.recurrent:
+            # first chunks: a slot started from zero
+            self.metrics.state_resets.inc(
+                sum(1 for s in active if offsets[id(s)] == 0))
         self.metrics.prefill_chunk.observe(trk.elapsed_s)
         rec = self.step_recorder
         if rec is not None:
@@ -3518,7 +3649,7 @@ class TpuEngine:
                     tokens2 = inf["packed"][0, k - 1].astype(jnp.int32)
                     with trk2:
                         return self._mesh_dispatch(
-                            trk2, decode_multi_step,
+                            trk2, self._decode_multi_step,
                             self.params, self.k_cache, self.v_cache,
                             tokens2,
                             jax.numpy.asarray(inf["positions"] + k),
@@ -3530,7 +3661,8 @@ class TpuEngine:
                             jax.numpy.asarray(inf["top_ps"]),
                             jax.numpy.asarray(inf["top_ks"]),
                             mcfg, k, topk_lp=inf.get("tk", 0),
-                            span_tokens=len(batch) * k)
+                            span_tokens=len(batch) * k,
+                            **self._slot_kw(batch, b))
 
                 rec = self.step_recorder
                 t_d2 = time.perf_counter() if rec is not None else 0.0
@@ -3643,7 +3775,7 @@ class TpuEngine:
             if append_inputs:
                 # the step-k input token's KV is now on device
                 block = seq.token_seq.append(seq.next_token)
-                if block is not None:
+                if block is not None and not self.recurrent:
                     self.pool.register_page(
                         seq.pages[block.block_index], block.seq_hash,
                         block.local_hash, block.parent_seq_hash)
@@ -3710,6 +3842,7 @@ class TpuEngine:
             self._running.remove(seq)
         if seq in self._waiting:
             self._waiting.remove(seq)
+        self._give_slot(seq)
         if release_pages:
             if self._defer_releases is not None:
                 # an in-flight speculative burst still writes these pages
@@ -3859,6 +3992,7 @@ class TpuEngine:
             self._running.remove(seq)
         self.pool.release_sequence(seq.pages)
         seq.pages = []
+        self._give_slot(seq)
         # block diffusion: what is committed plus the next block's known
         # head (there is no sampled-but-unwritten token)
         seq.prompt = seq.token_seq.tokens + (

@@ -143,6 +143,19 @@ class EngineMetrics:
             "dynamo_moe_routed_rows_total",
             "rows the routed expert dispatch sent to experts: real "
             "token positions x experts per token x layers")
+        # State slots of a model with recurrent layers (engine/pages.py
+        # SlotPool); they stay 0 for every other model.
+        self.state_resets = c(
+            "dynamo_engine_state_resets_total",
+            "first prefill chunks of a model with recurrent layers: a "
+            "state slot started from zero")
+        self.state_slots = Gauge(
+            "dynamo_engine_state_slots",
+            "state slots of a model with recurrent layers (scratch slot 0 "
+            "not counted); 0 for a model without")
+        self.state_slots_in_use = Gauge(
+            "dynamo_engine_state_slots_in_use",
+            "state slots owned by admitted sequences")
         # Step-profiler attribution (engine/profiler.py). Constructed
         # unconditionally so names are stable in /metrics and telemetry
         # snapshots; they only move when DYN_STEP_PROFILE arms the
@@ -195,7 +208,9 @@ class EngineMetrics:
                   self.refills_behind_burst, self.mixed_steps,
                   self.decode_steps_during_prefill,
                   self.block_forwards, self.blocks, self.moe_routed_rows,
-                  self.goodput_tokens, self.padded_tokens,
+                  self.state_resets, self.state_slots,
+                  self.state_slots_in_use, self.goodput_tokens,
+                  self.padded_tokens,
                   self.dispatch_gap, self.device_info):
             registry.register(m)
         if self.host_seconds is not None:

@@ -39,7 +39,9 @@ EventSink = Callable[[KvCacheEvent], None]
 # KV geometry: the ONE place a model configuration becomes the cache's
 # shapes and bytes. init_cache, the pp cache, the KV-import check, KVBM's
 # tier blocks and the memory ledger all ask here; a new cache format (a
-# latent a token, per-layer geometry) changes these three answers.
+# latent a token, per-layer geometry) changes these three answers. The
+# fourth answer is what a SEQUENCE owns, whatever its length: the state of
+# a model's recurrent layers (`state_shapes`), held in slots (`SlotPool`).
 # ---------------------------------------------------------------------------
 
 
@@ -61,6 +63,48 @@ def kv_block_shape(cfg, n_pages: Optional[int] = None) -> tuple:
 def kv_page_bytes(cfg, dtype_itemsize: int = 2) -> int:
     """Bytes one KV page reserves on device (k + v, all layers)."""
     return math.prod(kv_block_shape(cfg)) * dtype_itemsize
+
+
+def state_shapes(cfg, num_slots: int) -> tuple:
+    """What a recurrent (Mamba-2) layer keeps a sequence, for `num_slots`
+    slots: ((S, K-1, C) the convolution's last inputs, in the activations'
+    dtype; (S, H, P, N) the SSM state, float32). Attention layers keep
+    pages (`kv_layer_shape`), expert layers nothing."""
+    return ((num_slots, cfg.conv_kernel - 1, cfg.conv_dim),
+            (num_slots, cfg.mamba_heads, cfg.mamba_head_dim, cfg.ssm_state))
+
+
+def state_slot_bytes(cfg, dtype_itemsize: int = 2) -> int:
+    """Bytes one slot reserves on device, all recurrent layers."""
+    tail, ssm = state_shapes(cfg, 1)
+    return cfg.count("mamba") * (math.prod(tail) * dtype_itemsize
+                                 + math.prod(ssm) * 4)
+
+
+class SlotPool:
+    """Free list of the state slots of a model with recurrent layers: a
+    sequence owns one from admission to its end. Slot 0 is scratch, as
+    page 0 is: invalid lanes and padding rows point at it."""
+
+    def __init__(self, num_slots: int) -> None:
+        self.num_slots = num_slots                 # incl. scratch slot 0
+        self._free = list(range(num_slots - 1, 0, -1))
+
+    @property
+    def in_use(self) -> int:
+        return self.num_slots - 1 - len(self._free)
+
+    def take(self) -> int:
+        if not self._free:
+            raise BlockStateInvalid("no free state slot: more sequences "
+                                    "running than max_batch_size")
+        return self._free.pop()
+
+    def give(self, slot: int) -> None:
+        if not 0 < slot < self.num_slots or slot in self._free:
+            raise BlockStateInvalid(f"state slot {slot} returned twice "
+                                    "or never taken")
+        self._free.append(slot)
 
 
 class BlockStateInvalid(RuntimeError):

@@ -33,8 +33,22 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-# layer-dict keys that get quantized (contraction dim = axis -2)
-QUANT_KEYS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
+# layer-dict keys that get quantized (contraction dim = axis -2): the
+# attention and FFN projections, a Mamba-2 layer's in_proj / out_proj and a
+# shared expert's two (models/nemotron_h.py, whose layer dict holds one
+# dict a kind of layer)
+QUANT_KEYS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down",
+              "in_proj", "out_proj", "w_shared_up", "w_shared_down")
+
+
+def _map_layers(fn, layers: dict) -> dict:
+    """`fn` over the QUANT_KEYS leaves of a layer dict that are not
+    QTensors yet, through the dicts by kind of a hybrid stack."""
+    return {
+        k: (_map_layers(fn, v) if isinstance(v, dict)
+            else fn(v) if k in QUANT_KEYS and not isinstance(v, QTensor)
+            else v)
+        for k, v in layers.items()}
 
 
 @jax.tree_util.register_pytree_node_class
@@ -231,11 +245,8 @@ def quantize_params(params: dict, quantize_lm_head: bool = True,
     bits = _bits_of(mode)
     act_bits = _act_bits_of(mode)
     out = dict(params)
-    out["layers"] = {
-        k: (quantize(v, bits, act_bits)
-            if k in QUANT_KEYS and not isinstance(v, QTensor) else v)
-        for k, v in params["layers"].items()
-    }
+    out["layers"] = _map_layers(
+        lambda v: quantize(v, bits, act_bits), params["layers"])
     if quantize_lm_head and "lm_head" in params \
             and not isinstance(params["lm_head"], QTensor) \
             and _lm_head_quant_ok(params["lm_head"]):
@@ -268,11 +279,7 @@ def quantize_params_host(params: dict,
     accelerator (numpy over ml_dtypes bf16 is emulated and takes tens
     of minutes at 8B scale on a small host)."""
     out = dict(params)
-    out["layers"] = {
-        k: (quantize_host(v)
-            if k in QUANT_KEYS and not isinstance(v, QTensor) else v)
-        for k, v in params["layers"].items()
-    }
+    out["layers"] = _map_layers(quantize_host, params["layers"])
     if quantize_lm_head and "lm_head" in params \
             and not isinstance(params["lm_head"], QTensor) \
             and _lm_head_quant_ok(params["lm_head"]):

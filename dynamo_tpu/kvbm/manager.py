@@ -101,6 +101,11 @@ class KvbmManager:
 
     def __init__(self, engine, config: Optional[KvbmConfig] = None,
                  fault_injector=None) -> None:
+        refuse = getattr(engine, "refuse_if_recurrent", None)
+        if refuse is not None:
+            # a tier block is a prefix's pages; a recurrent layer's state at
+            # that boundary is not in them
+            refuse("a KVBM tier")
         self.engine = engine
         self.config = config or KvbmConfig()
         self.store = TieredStore(self.config.host_blocks,

@@ -36,6 +36,7 @@ from typing import Any, Optional
 
 import numpy as np
 
+from dynamo_tpu.models import family_module
 from dynamo_tpu.models.llama import LlamaConfig
 
 logger = logging.getLogger(__name__)
@@ -89,6 +90,12 @@ def config_from_hf(path: str, **overrides: Any) -> LlamaConfig:
     checkpoint dir's config.json."""
     with open(os.path.join(path, "config.json")) as f:
         hf = json.load(f)
+    if hf.get("model_type") == "nemotron_h":
+        # a hybrid stack (Mamba-2, expert and attention layers): a family
+        # as a file of its own, config, entries and checkpoint layout
+        from dynamo_tpu.models import nemotron_h
+
+        return nemotron_h.config_from_hf(hf, **overrides)
     arch = (hf.get("architectures") or ["LlamaForCausalLM"])[0]
     known = ("llama", "mistral", "mixtral", "qwen2", "qwen3moe", "sdar")
     if not any(f in arch.lower() for f in known):
@@ -195,6 +202,8 @@ def load_llama_params(path: str, cfg: LlamaConfig) -> dict:
     cast to cfg.dtype, norms to fp32 (matching init_params)."""
     import ml_dtypes
 
+    if getattr(cfg, "entries_module", None):
+        return family_module(cfg).load_params(path, cfg)
     w_dtype = np.dtype(ml_dtypes.bfloat16) \
         if cfg.dtype.__name__ == "bfloat16" else np.dtype(cfg.dtype.__name__)
     idx = _TensorIndex(path)
@@ -352,6 +361,10 @@ def load_llama_params_device(path: str, cfg: LlamaConfig,
 
     import jax
     import jax.numpy as jnp
+
+    if getattr(cfg, "entries_module", None):
+        return family_module(cfg).load_params(path, cfg,
+                                              quantize=quantize)
 
     from dynamo_tpu.engine.quant import (
         QUANT_KEYS,

@@ -48,6 +48,21 @@ from dynamo_tpu.models.llama import (
 class MoeConfig(LlamaConfig):
     num_experts: int = 8
     experts_per_token: int = 2
+    # Facts of a checkpoint's config.json about its expert layers; the
+    # defaults are the Mixtral / Qwen3-MoE layer and lower to what stood
+    # here before. `router_scoring`: "softmax" (gates = softmax over the
+    # k best logits) or "sigmoid" (scores sigmoid(logits); the k experts
+    # are chosen by score + the learned `router_bias`, weighted by the
+    # UNBIASED scores renormalised over the chosen and times
+    # `routed_scaling`). `expert_act`: "swiglu" (gate and up projections)
+    # or "relu2" (un-gated: down(relu(up(x))^2), no w_gate leaf).
+    # `shared_expert_size` > 0: one more expert of that width, un-gated
+    # relu2 (the one form served), added for every token (w_shared_up /
+    # w_shared_down).
+    router_scoring: str = "softmax"
+    routed_scaling: float = 1.0
+    expert_act: str = "swiglu"
+    shared_expert_size: int = 0
 
     @classmethod
     def tiny(cls, **kw) -> "MoeConfig":
@@ -145,9 +160,33 @@ def moe_route(h: jax.Array, lp: dict, cfg: MoeConfig
     over those k logits. That equals the softmax over all experts
     renormalised over the k chosen (`norm_topk_prob`): exp(l_e) / sum over
     the chosen of exp(l), whichever sum came first."""
+    if cfg.router_scoring == "sigmoid":
+        return _route_sigmoid(h, lp, cfg)
     router_logits = (h @ lp["router"]).astype(jnp.float32)  # (..., T, X)
     topv, topi = jax.lax.top_k(router_logits, cfg.experts_per_token)
     return jax.nn.softmax(topv, axis=-1), topi
+
+
+def _route_sigmoid(h: jax.Array, lp: dict, cfg: MoeConfig
+                   ) -> tuple[jax.Array, jax.Array]:
+    """Sigmoid routing with a learned correction (`router_bias`, (X,)):
+    the bias moves the CHOICE of the k experts and never their weights,
+    which are the unbiased scores over their sum, times `routed_scaling`.
+    Scores in float32 at full matmul precision: the k-th and (k+1)-th
+    biased scores can lie close, and a bf16 product would choose another
+    expert than the model's."""
+    scores = jax.nn.sigmoid(jnp.dot(
+        h.astype(jnp.float32), lp["router"].astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))               # (..., T, X)
+    _, topi = jax.lax.top_k(scores + lp["router_bias"],
+                            cfg.experts_per_token)
+    chosen = jnp.take_along_axis(scores, topi, axis=-1)
+    gates = chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20)
+    return gates * cfg.routed_scaling, topi
+
+
+def _relu2(x: jax.Array) -> jax.Array:
+    return jnp.square(jax.nn.relu(x))
 
 
 def moe_mlp(h: jax.Array, lp: dict, cfg: MoeConfig) -> jax.Array:
@@ -167,9 +206,10 @@ def moe_mlp(h: jax.Array, lp: dict, cfg: MoeConfig) -> jax.Array:
                                            route_layout, row_tile)
 
     k, n_exp = cfg.experts_per_token, cfg.num_experts
+    gated = cfg.expert_act == "swiglu"
     flat = h.reshape(-1, h.shape[-1])                       # (T, E)
     routed = flat.shape[0] * k
-    tile = row_tile(routed, n_exp, *lp["w_gate"].shape[-2:])
+    tile = row_tile(routed, n_exp, *lp["w_up"].shape[-2:])
     with jax.named_scope("moe_route"):
         gates, topi = moe_route(flat, lp, cfg)              # (T, k)
         pos, tile_expert, n_used, sizes = route_layout(
@@ -187,12 +227,22 @@ def moe_mlp(h: jax.Array, lp: dict, cfg: MoeConfig) -> jax.Array:
 
     with jax.named_scope("moe_experts"):
         rows = flat[src]                                    # (M, E)
-        gate = jax.nn.silu(product(rows, "w_gate"))
-        down = product(gate * product(rows, "w_up"), "w_down")  # (M, E)
+        if gated:
+            gate = jax.nn.silu(product(rows, "w_gate"))
+            down = product(gate * product(rows, "w_up"), "w_down")
+        else:
+            down = product(_relu2(product(rows, "w_up")), "w_down")
+    if cfg.shared_expert_size:
+        with jax.named_scope("moe_shared"):
+            shared = qm(_relu2(qm(flat, lp["w_shared_up"])),
+                        lp["w_shared_down"])
     with jax.named_scope("moe_combine"):
         per_token = down[pos].reshape(flat.shape[0], k, -1)
         out = jnp.einsum("tke,tk->te", per_token.astype(jnp.float32),
-                         gates).astype(h.dtype)
+                         gates)
+        if cfg.shared_expert_size:
+            out = out + shared.astype(jnp.float32)
+        out = out.astype(h.dtype)
     return out.reshape(h.shape)
 
 

@@ -259,6 +259,9 @@ def build_engine_and_card(args: argparse.Namespace, event_sink, metrics_sink,
         dllm_unmasking_strategy=args.dllm_unmasking_strategy,
         pipeline_parallel_size=args.pipeline_parallel_size,
         pp_microbatches=args.pp_microbatches, **overrides)
+    if args.is_prefill_worker or args.enable_disagg:
+        engine.refuse_if_recurrent(
+            "a disaggregated role (a KV export or import)")
     if mesh is not None:
         card.runtime_config.tensor_parallel_size = args.tensor_parallel_size
     engine.config.prefill_chunk = args.prefill_chunk

@@ -376,3 +376,102 @@ def test_sdar_prefill_round(sds, pallas_impl, bp):
     # 16 x 512 tokens x top-8 = 65536 routed rows, padded to 98304, of
     # 2048 bf16 in and out of the experts: under 2.5 GB of temporaries
     assert compiled.memory_analysis().temp_size_in_bytes < (5 << 29)
+
+
+# -- Nemotron-3-Nano widths: state slots, the SSM step kernel, relu2 experts -
+# What Mosaic refuses of `ssm_decode_update`, a bf16 copy of an expert stack
+# or a gathered copy of the SSM state shows here and costs no chip time.
+
+NEMOTRON = dict(vocab_size=131072, hidden_size=2688, intermediate_size=1856,
+              num_heads=32, num_kv_heads=2, head_dim=128, rms_eps=1e-5,
+              num_experts=128, experts_per_token=6, shared_expert_size=3712,
+              mamba_heads=64, mamba_head_dim=64, ssm_groups=8, ssm_state=128,
+              chunk_size=128, max_pages_per_seq=192)
+NEMOTRON_LANES, NEMOTRON_SLOTS, NEMOTRON_PAGES = 128, 129, 32768
+NEMOTRON_SCOPES = ("ssm_in_proj", "ssm_conv", "ssm_out", "moe_route", "moe_experts",
+          "moe_shared", "moe_combine", "attn_qkv", "kv_write", "attn_core",
+          "attn_out", "lm_head")
+
+
+def nemotron_model(sds, pattern="MEM*E"):
+    """(cfg, params, k_cache, v_cache) as shapes on the described chip:
+    int8 as `--quantize int8` serves it, the expert width padded as the
+    loader pads it."""
+    from dynamo_tpu.engine.quant import quantize_params
+    from dynamo_tpu.models import nemotron_h as nh
+
+    cfg = nh.NemotronHConfig(num_layers=len(pattern), pattern=pattern,
+                             **NEMOTRON)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)
+
+    def served(key):
+        params = nh.init_params(key, cfg)
+        params["layers"] = nh.pad_expert_width(params["layers"])
+        return quantize_params(params, mode="int8")
+
+    params = on_chip(jax.eval_shape(served, jax.random.PRNGKey(0)))
+    kc, vc = on_chip(jax.eval_shape(
+        lambda: nh.init_cache(cfg, NEMOTRON_PAGES, NEMOTRON_SLOTS)))
+    return cfg, params, kc, vc
+
+
+def test_ssm_decode_update_kernel(sds, pallas_impl):
+    from dynamo_tpu.models.nemotron_h import ssm_decode_update
+
+    f32 = jnp.float32
+    compiled = jax.jit(ssm_decode_update, donate_argnums=(0,)).lower(
+        sds((NEMOTRON_SLOTS, 64, 64, 128), f32), sds((NEMOTRON_LANES,), jnp.int32),
+        sds((NEMOTRON_LANES, 64, 64), f32), sds((NEMOTRON_LANES, 64), f32), sds((64,), f32),
+        sds((NEMOTRON_LANES, 8, 128), f32), sds((NEMOTRON_LANES, 8, 128), f32),
+        sds((64,), f32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "ssm_decode_update" in text     # the name a device trace shows
+    # in place at the slots: no second copy of the state
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+def _no_copies(text: str) -> None:
+    # no bf16 copy of an expert stack, no gathered copy of the state
+    assert not re.search(r"bf16\[(1,)?128,2688,19\d\d\]", text)
+    assert not re.search(r"bf16\[(1,)?128,19\d\d,2688\]", text)
+    assert not re.search(rf"f32\[{NEMOTRON_LANES},64,64,128\]", text)
+
+
+def test_decode_burst_holds_no_copy_of_stacks_or_state(sds, pallas_impl):
+    from dynamo_tpu.models.nemotron_h import decode_multi_step
+
+    cfg, params, kc, vc = nemotron_model(sds)
+    b, i32, u32, f32 = NEMOTRON_LANES, jnp.int32, jnp.uint32, jnp.float32
+    compiled = decode_multi_step.lower(
+        params, kc, vc, sds((b,), i32), sds((b,), i32),
+        sds((b, cfg.max_pages_per_seq), i32), sds((b,), jnp.bool_),
+        sds((b,), u32), sds((b,), u32), sds((b,), f32), sds((b,), f32),
+        sds((b,), i32), cfg, 8, topk_lp=0, slots=sds((b,), i32)).compile()
+    text = compiled.as_text()
+    assert text.count("ssm_decode_update") >= cfg.count("mamba")
+    assert text.count("moe_gmm") >= 2 * cfg.count("moe")
+    assert text.count("paged_decode_attention") >= cfg.count("attn")
+    assert not _missing(text, NEMOTRON_SCOPES + ("ssm_update", "sample"))
+    _no_copies(text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+def test_prefill_round_holds_no_copy_of_stacks(sds, pallas_impl):
+    from dynamo_tpu.models.nemotron_h import prefill_batch
+
+    cfg, params, kc, vc = nemotron_model(sds)
+    i32, t = jnp.int32, 512
+    compiled = prefill_batch.lower(
+        params, kc, vc, sds((1, t), i32),
+        sds((1, cfg.max_pages_per_seq), i32), sds((1,), i32),
+        sds((1,), i32), cfg, aligned=True, slots=sds((1,), i32)).compile()
+    text = compiled.as_text()
+    assert text.count("moe_gmm") >= 2 * cfg.count("moe")
+    assert text.count("paged_prefill_attention") >= cfg.count("attn")
+    assert text.count("kv_write_pages") >= cfg.count("attn")
+    assert not _missing(text, NEMOTRON_SCOPES + ("ssm_scan",))
+    _no_copies(text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30

@@ -840,6 +840,11 @@ class TpuEngine:
         # sequence and corrupt it)
         self._inflight: Optional[dict] = None
         self._defer_releases: Optional[list] = None
+        # decoding lanes that ended since the last burst was consumed
+        # (their callers, in a closed loop, are on their way back), and
+        # the device seconds of the last burst that landed
+        self._lanes_ended = 0
+        self._burst_s = 0.0
         # rows a real token position sends through the routed expert
         # dispatch (0: a dense model, or experts sharded over 'ep', which
         # keep the dense mask): what dynamo_moe_routed_rows_total counts
@@ -1555,14 +1560,67 @@ class TpuEngine:
         decode burst is launched behind the samplers before any of them
         is synced (_chain_burst): the first tokens reach the host while
         it runs."""
-        pending = [s for s in self._running if not s.prefilled]
+        inf = self._inflight
+        if (inf is not None and self._refills_behind_burst
+                and await self._prefill_behind(inf)):
+            return True
+        pending = self._unprefilled(inf["firsts"] if inf else ())
         if not pending:
             return False
+        async with self._device_lock:
+            await self._land_first_tokens(
+                await self._launch_prefill(pending))
+        return True
+
+    def _unprefilled(self, firsts) -> list[_Seq]:
+        """Admitted sequences whose prefill is still to be launched:
+        not those of `firsts`, launched and not yet landed."""
+        launched = {id(s) for group, _, _ in firsts for s in group}
+        return [s for s in self._running
+                if not s.prefilled and id(s) not in launched]
+
+    async def _prefill_behind(self, inf: dict) -> int:
+        """Launch the prefill of everyone admitted and not yet
+        prefilled behind the dense burst in flight `inf`, and leave the
+        first tokens on the device (`inf["firsts"]`: _chain_burst has to
+        wait for the burst, and the scheduler stays awake meanwhile);
+        _pipeline_consume lands them with the burst. Returns how many
+        sequences that was: 0 where a lane _chain_burst would refuse is
+        among them, and that wave keeps the loop's order."""
+        pending = self._unprefilled(inf["firsts"])
+        if not pending or any(s.needs_constrained for s in pending):
+            return 0
+        async with self._device_lock:
+            inf["firsts"] += await self._launch_prefill(pending)
+        return len(pending)
+
+    @property
+    def _by_sequence(self) -> bool:
+        """Rounds go by sequence, a first token sampled behind the round
+        that ends its prompt: every engine but a draft or pp one, which
+        needs the whole batch first."""
+        return self.draft_params is None and self.config.pp_mesh is None
+
+    @property
+    def _refills_behind_burst(self) -> bool:
+        """A dense burst in flight is waited for with the scheduler
+        awake (_land_burst) and refilled behind (_prefill_behind) where
+        a refill is _prefill_pending's by-sequence rounds and nothing
+        else: not with a draft, a pp or sp mesh, a chunk budget or the
+        ragged round."""
+        return (self._by_sequence and self._sp_params is None
+                and self.config.prefill_chunk_budget <= 0
+                and not self._ragged_active())
+
+    async def _launch_prefill(self, pending: list[_Seq]) -> list:
+        """_prefill_pending's first half: launch every round of `pending`
+        and the first-token samplers behind them, and wait for none.
+        Returns `firsts`: (sequences, sampled on the device, tk) a group,
+        in launch order, for _land_first_tokens. Call under the device
+        lock."""
         mcfg, cfg = self.model_cfg, self.config
-        # (sequences, sampled on the device, tk) in launch order; the
-        # draft replay and the pp path need the whole batch first
         firsts: list[tuple[list[_Seq], Any, int]] = []
-        by_sequence = self.draft_params is None and cfg.pp_mesh is None
+        by_sequence = self._by_sequence
 
         def sample_early(done):
             group = [s for s in pending if id(s) in done]
@@ -1610,22 +1668,30 @@ class TpuEngine:
 
         self.metrics.prefill_new_tokens.inc(sum(
             max(len(s.prompt) - s.cached_len, 0) for s in pending))
-        async with self._device_lock:
-            await asyncio.to_thread(prefill_all)
-            chained = by_sequence and await self._chain_burst(firsts)
-            # a lane its first token ends is overshoot in the chained
-            # burst, which still writes to its pages
-            deferred = self._inflight["deferred"] if chained else None
-            for group, sampled, tk in firsts:
-                # ONE host sync a group; later rounds are still running
-                packed = await asyncio.to_thread(self._host_sync, sampled)
-                self._defer_releases = deferred
-                try:
-                    self._emit_first_tokens(group, packed, tk,
-                                            draft_done=True)
-                finally:
-                    self._defer_releases = None
-        return True
+        await asyncio.to_thread(prefill_all)
+        return firsts
+
+    async def _land_first_tokens(self, firsts: list,
+                                 chain: bool = True) -> None:
+        """_prefill_pending's second half: chain the next burst behind
+        the samplers where the lanes allow it and no burst is in flight
+        (and the caller lets it: `chain`), then one host sync and one
+        emission a group. Call under the device lock."""
+        chained = (chain and self._by_sequence
+                   and await self._chain_burst(firsts))
+        # a lane its first token ends is overshoot in the chained
+        # burst, which still writes to its pages
+        deferred = self._inflight["deferred"] if chained else None
+        for group, sampled, tk in firsts:
+            # ONE host sync a group; later rounds are still running
+            packed = await asyncio.to_thread(self._host_sync, sampled)
+            self._defer_releases = deferred
+            try:
+                self._emit_first_tokens(group, packed, tk, draft_done=True)
+            finally:
+                self._defer_releases = None
+        if chained:
+            self._inflight["t0"] = time.perf_counter()
 
     async def _chain_burst(self, firsts: list) -> bool:
         """Launch the decode burst that follows a prefill wave behind
@@ -2258,7 +2324,10 @@ class TpuEngine:
             "k": k_steps, "batch": batch, "packed": packed,
             "positions": positions, "valid": valid, "seeds": seeds,
             "steps": steps, "temps": temps, "top_ps": top_ps,
-            "top_ks": top_ks, "tk": tk, "deferred": []}
+            "top_ks": top_ks, "tk": tk, "deferred": [], "firsts": [],
+            # when the device is taken to have started it: now, or once
+            # the samplers ahead of a chained one are done
+            "t0": time.perf_counter()}
 
     async def _decode_iter(self) -> bool:
         if self._dllm:
@@ -2635,7 +2704,7 @@ class TpuEngine:
         (denoising steps + 1) / B forwards a token. The burst is
         launched, then waited for with the scheduler awake: whoever
         arrives meanwhile is admitted and prefilled behind it
-        (_land_block_burst)."""
+        (_land_burst)."""
         from dynamo_tpu.models.llama import block_decode_multi_step
 
         runnable = [s for s in self._running if s.prefilled]
@@ -2694,7 +2763,11 @@ class TpuEngine:
             packed, self.k_cache, self.v_cache = (
                 await asyncio.to_thread(launch) if trk.compiled
                 else launch())
-        packed = await self._land_block_burst(packed)      # ONE host sync
+        async def refill() -> int:
+            behind = sum(not s.prefilled for s in self._running)
+            return behind if await self._prefill_blocks() else 0
+
+        packed = await self._land_burst(packed, refill)    # ONE host sync
         rec = self.step_recorder
         if rec is not None:
             rec.record(trk.entry, trk.shape,
@@ -2730,27 +2803,36 @@ class TpuEngine:
                                 append_inputs=False)
         return True
 
-    async def _land_block_burst(self, packed) -> np.ndarray:
-        """Wait for the block burst in flight with the scheduler awake:
-        the sync runs in a thread, and whoever arrives meanwhile and
-        finds a lane and pages is admitted at once (_admit, its rules
-        unchanged). Their whole-block rounds go out as ONE batch, as the
-        loop's own prefill would make it (a round reads every expert's
-        weights whatever its width): behind the burst as soon as every
-        lane is taken, since nobody else can join the round then, and
-        otherwise when the burst lands, ahead of its emission, so the
-        device goes from the burst to the rounds while the host emits
-        and builds the next burst. A round writes only the new
-        sequences' pages, and none of them can belong to a lane of the
-        burst: a lane that ended was released when its own burst landed,
-        and nothing here preempts. An arrival _admit turns away (no
-        lane, no pages), or one that may need a remote onboard awaited,
-        waits for the landing and the loop's own admit, as every arrival
-        did."""
-        async def refill() -> None:
-            behind = sum(not s.prefilled for s in self._running)
-            if await self._prefill_blocks():
-                self.metrics.refills_behind_burst.inc(behind)
+    # the share of a burst's device time after which a held successor
+    # is launched all the same: late enough for a caller ~60 ms away,
+    # early enough that the launch (a few ms) beats the landing
+    _SPEC_HOLD = 0.75
+
+    async def _land_burst(self, packed, refill, successor=None,
+                          deadline: float = 0.0) -> np.ndarray:
+        """Wait for a burst in flight with the scheduler awake: the sync
+        runs in a thread, and whoever arrives meanwhile and finds a lane
+        and pages is admitted at once (_admit, its rules unchanged).
+        `refill()` launches the prefill of everyone admitted and not yet
+        prefilled, as ONE batch as the loop's own prefill would make it
+        (_prefill_blocks for a block burst, _prefill_behind for a dense
+        one), and returns how many sequences that was. It is called
+        behind the burst as soon as every lane is taken, since nobody
+        else can join the batch then, and otherwise when the burst
+        lands, ahead of its emission, so the device goes from the burst
+        (a dense one's rounds queue behind its speculative successor
+        where it has one) to the rounds while the host emits and builds
+        the next burst. A round writes only the new sequences' pages and
+        slots, and none of them can belong to a lane of the burst: the
+        pages of a lane that ended are out of the pool until the burst
+        that may still write them has landed, and nothing here preempts.
+        An arrival _admit turns away (no lane, no pages), or one that
+        may need a remote onboard awaited, waits for the landing and the
+        loop's own admit, as every arrival did. `successor()`, where a
+        dense burst's speculative successor was held for a caller on its
+        way back, is called once `deadline` (perf_counter) has passed."""
+        async def launch() -> None:
+            self.metrics.refills_behind_burst.inc(await refill())
 
         sync = asyncio.ensure_future(
             asyncio.to_thread(self._host_sync, packed))
@@ -2762,10 +2844,19 @@ class TpuEngine:
                 with self._span("admit"):
                     self._admit()
                 if len(self._running) >= self.config.max_batch_size:
-                    await refill()
-            await self._wake.wait()
+                    await launch()
+            timeout = None
+            if successor is not None:
+                timeout = deadline - time.perf_counter()
+                if timeout <= 0:
+                    await successor()
+                    successor = timeout = None
+            try:
+                await asyncio.wait_for(self._wake.wait(), timeout)
+            except asyncio.TimeoutError:
+                pass
         packed = sync.result()
-        await refill()
+        await launch()
         return packed
 
     def _mesh_dispatch(self, trk, fn, *args, span_tokens: int = 0,
@@ -3618,10 +3709,15 @@ class TpuEngine:
         # slots-full-only guard left every partial batch unpipelined,
         # paying the full sync per burst exactly when per-request
         # latency is most visible.
-        can_spec = ((len(self._running) >= cfg.max_batch_size
+        def can_spec() -> bool:
+            return ((len(self._running) >= cfg.max_batch_size
                      or (not self._waiting
                          and len(self._running) == len(batch)))
                     and self.draft_params is None
+                    # a wave prefilled behind this burst joins the next
+                    # one from the device (_chain_burst), not the one
+                    # after it
+                    and not inf["firsts"]
                     and all(s in self._running and not s.ctx.is_cancelled()
                             and not s.needs_constrained for s in batch)
                     # every lane will hit max_tokens within the burst
@@ -3630,64 +3726,97 @@ class TpuEngine:
                     # queue behind its wasted device time
                     and any(s.max_tokens - s.generated > k
                             for s in batch))
-        if can_spec:
-            if all(self._cover(s, s.pos + 2 * k - 1) for s in batch):
-                b = cfg.max_batch_size
-                with self._span("decode_prep"):
-                    page_tables2 = np.zeros((b, mcfg.max_pages_per_seq),
-                                            dtype=np.int32)
-                    for i, s in enumerate(batch):
-                        page_tables2[i, :len(s.pages)] = s.pages
-                # the speculative burst runs the program of the burst
-                # it follows, under that burst's (entry, shape)
-                trk2 = self.metrics.compile.track(
-                    "decode_burst", (b, k, inf.get("tk", 0)))
 
-                def dispatch2():
-                    # sliced on device while the in-flight burst still
-                    # runs: no device idle at stake, so under no span
-                    tokens2 = inf["packed"][0, k - 1].astype(jnp.int32)
-                    with trk2:
-                        return self._mesh_dispatch(
-                            trk2, self._decode_multi_step,
-                            self.params, self.k_cache, self.v_cache,
-                            tokens2,
-                            jax.numpy.asarray(inf["positions"] + k),
-                            jax.numpy.asarray(page_tables2),
-                            jax.numpy.asarray(inf["valid"]),
-                            jax.numpy.asarray(inf["seeds"]),
-                            jax.numpy.asarray(inf["steps"] + k),
-                            jax.numpy.asarray(inf["temps"]),
-                            jax.numpy.asarray(inf["top_ps"]),
-                            jax.numpy.asarray(inf["top_ks"]),
-                            mcfg, k, topk_lp=inf.get("tk", 0),
-                            span_tokens=len(batch) * k,
-                            **self._slot_kw(batch, b))
+        async def speculate() -> None:
+            nonlocal nxt
+            if not (can_spec() and all(
+                    self._cover(s, s.pos + 2 * k - 1) for s in batch)):
+                return
+            b = cfg.max_batch_size
+            with self._span("decode_prep"):
+                page_tables2 = np.zeros((b, mcfg.max_pages_per_seq),
+                                        dtype=np.int32)
+                for i, s in enumerate(batch):
+                    page_tables2[i, :len(s.pages)] = s.pages
+            # the speculative burst runs the program of the burst
+            # it follows, under that burst's (entry, shape)
+            trk2 = self.metrics.compile.track(
+                "decode_burst", (b, k, inf.get("tk", 0)))
 
-                rec = self.step_recorder
-                t_d2 = time.perf_counter() if rec is not None else 0.0
-                async with self._device_lock:
-                    packed2, self.k_cache, self.v_cache = \
-                        await asyncio.to_thread(dispatch2)
-                if rec is not None:
-                    rec.record("decode_burst",
-                               (b, k, inf.get("tk", 0)),
-                               time.perf_counter() - t_d2,
-                               good_tokens=len(batch) * k,
-                               work_tokens=b * k, lanes=len(batch),
-                               width=b, tokens=len(batch) * k,
-                               synced=False)
-                self.metrics.pipelined_bursts.inc()
-                nxt = {"k": k, "batch": batch, "packed": packed2,
-                       "positions": inf["positions"] + k,
-                       "valid": inf["valid"], "seeds": inf["seeds"],
-                       "steps": inf["steps"] + k, "temps": inf["temps"],
-                       "top_ps": inf["top_ps"],
-                       "top_ks": inf["top_ks"],
-                       "tk": inf.get("tk", 0), "deferred": []}
+            def dispatch2():
+                # sliced on device while the in-flight burst still
+                # runs: no device idle at stake, so under no span
+                tokens2 = inf["packed"][0, k - 1].astype(jnp.int32)
+                with trk2:
+                    return self._mesh_dispatch(
+                        trk2, self._decode_multi_step,
+                        self.params, self.k_cache, self.v_cache,
+                        tokens2,
+                        jax.numpy.asarray(inf["positions"] + k),
+                        jax.numpy.asarray(page_tables2),
+                        jax.numpy.asarray(inf["valid"]),
+                        jax.numpy.asarray(inf["seeds"]),
+                        jax.numpy.asarray(inf["steps"] + k),
+                        jax.numpy.asarray(inf["temps"]),
+                        jax.numpy.asarray(inf["top_ps"]),
+                        jax.numpy.asarray(inf["top_ks"]),
+                        mcfg, k, topk_lp=inf.get("tk", 0),
+                        span_tokens=len(batch) * k,
+                        **self._slot_kw(batch, b))
+
+            rec = self.step_recorder
+            t_d2 = time.perf_counter() if rec is not None else 0.0
+            async with self._device_lock:
+                packed2, self.k_cache, self.v_cache = \
+                    await asyncio.to_thread(dispatch2)
+            if rec is not None:
+                rec.record("decode_burst",
+                           (b, k, inf.get("tk", 0)),
+                           time.perf_counter() - t_d2,
+                           good_tokens=len(batch) * k,
+                           work_tokens=b * k, lanes=len(batch),
+                           width=b, tokens=len(batch) * k,
+                           synced=False)
+            self.metrics.pipelined_bursts.inc()
+            nxt = {"k": k, "batch": batch, "packed": packed2,
+                   "positions": inf["positions"] + k,
+                   "valid": inf["valid"], "seeds": inf["seeds"],
+                   "steps": inf["steps"] + k, "temps": inf["temps"],
+                   "top_ps": inf["top_ps"],
+                   "top_ks": inf["top_ks"],
+                   "tk": inf.get("tk", 0), "deferred": [],
+                   "firsts": [], "t0": 0.0}
+
+        # A decoding lane that ended since the last burst was consumed
+        # left a lane free and, in a closed loop, a caller on its way
+        # back: a successor launched now would have that caller wait it
+        # out whole before its prefill. Hold it until most of this burst
+        # has passed (by the last burst's device time); the caller
+        # admitted meanwhile is prefilled behind THIS burst and joins
+        # the next one (can_spec is then false). Nobody came: the
+        # successor still goes out ahead of the landing.
+        expected, self._lanes_ended = self._lanes_ended, 0
+        held = ()
+        if (expected and self._burst_s and self._refills_behind_burst
+                and len(self._running) < cfg.max_batch_size):
+            held = (speculate,
+                    inf["t0"] + self._SPEC_HOLD * self._burst_s)
+        else:
+            await speculate()
         rec = self.step_recorder
         t_sync = time.perf_counter() if rec is not None else 0.0
-        packed = await asyncio.to_thread(self._host_sync, inf["packed"])
+        if self._refills_behind_burst:
+            packed = await self._land_burst(
+                inf["packed"], lambda: self._prefill_behind(inf), *held)
+        else:
+            packed = await asyncio.to_thread(self._host_sync, inf["packed"])
+        # did the waves behind this burst take every lane (before its
+        # emission frees any)? Then nobody can arrive ahead of their burst
+        full = len(self._running) >= cfg.max_batch_size
+        now = time.perf_counter()
+        self._burst_s = now - inf["t0"]
+        if nxt is not None:
+            nxt["t0"] = now             # queued behind this one
         if rec is not None:
             # the honest device wait for a pipelined burst: np.asarray
             # round-trip, not block_until_ready;
@@ -3705,6 +3834,20 @@ class TpuEngine:
         for pages in inf["deferred"]:
             self.pool.release_sequence(pages)
         self._inflight = nxt
+        # the waves prefilled behind this burst (_prefill_behind), their
+        # first tokens still on the device
+        firsts = inf["firsts"]
+        if firsts and nxt is not None:
+            nxt["firsts"] = firsts      # launched behind nxt: land with it
+        elif firsts:
+            # host state is current again: the waves' burst goes out
+            # behind their samplers, the emit above under their rounds.
+            # With a lane to spare it waits for the first tokens, as
+            # after any wave prefilled behind a burst: whoever arrives
+            # while they are synced (long prompts: many rounds) is
+            # prefilled ahead of the next burst, not behind it
+            async with self._device_lock:
+                await self._land_first_tokens(firsts, full)
         return True
 
     # -- lifecycle helpers --------------------------------------------------
@@ -3840,6 +3983,7 @@ class TpuEngine:
         seq.finished = True
         if seq in self._running:
             self._running.remove(seq)
+            self._lanes_ended += seq.prefilled
         if seq in self._waiting:
             self._waiting.remove(seq)
         self._give_slot(seq)

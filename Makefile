@@ -71,7 +71,7 @@ kvbm-soak:
 # warm path, breaker events, /debug/requests, doctor trace analyzer.
 trace-smoke:
 	$(PYTEST) tests/test_trace_smoke.py tests/test_tracing.py \
-		tests/test_trace_sampling.py
+		tests/test_trace_sampling.py tests/test_request_stages.py
 
 # autoscaling gate (docs/autoscaling.md): the CLOSED loop — frontend +
 # fleet supervisor + SLA planner on live event-plane telemetry, driven

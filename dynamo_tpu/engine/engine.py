@@ -60,7 +60,7 @@ from dynamo_tpu.protocols import (
     SpecDecodeStats,
     WorkerStats,
 )
-from dynamo_tpu.runtime.context import Context
+from dynamo_tpu.runtime.context import ENGINE, WORKER_IN, Context
 from dynamo_tpu.runtime.tracing import RequestTrace
 from dynamo_tpu.tokens import TokenBlockSequence
 
@@ -351,7 +351,7 @@ class _Seq:
     seed: int = 0
     arrival: int = 0
     # lifecycle timestamps (perf_counter for metrics, time_ns for span
-    # boundaries) + the per-request trace handle. `trace` is None unless
+    # boundaries, the stage clock) + the trace handle. `trace` is None unless
     # DYN_TRACE is on — every scheduler touch is `if seq.trace is not
     # None`, so disabled tracing allocates nothing on the hot loop.
     t_enqueue: float = 0.0
@@ -1131,7 +1131,7 @@ class TpuEngine:
                       else int(self._rng.randint(0, 2**31 - 1))),
                 arrival=self._arrivals,
                 t_enqueue=time.perf_counter(),
-                t_enqueue_ns=time.time_ns(),
+                t_enqueue_ns=context.stamp(WORKER_IN),
                 trace=trace,
                 tenant=tenant,
                 cls=cls,
@@ -3902,8 +3902,8 @@ class TpuEngine:
             # this lane's FIRST emission: TTFT measured at the source
             self.metrics.ttft.observe(
                 max(time.perf_counter() - seq.t_enqueue, 0.0))
+            seq.t_first_ns = seq.ctx.stamp(ENGINE)  # the clock's instant
             if seq.trace is not None:
-                seq.t_first_ns = time.time_ns()
                 if seq.t_admit_ns:
                     seq.trace.stage("engine.prefill", seq.t_admit_ns,
                                     seq.t_first_ns,

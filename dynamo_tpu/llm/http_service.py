@@ -34,7 +34,13 @@ from dynamo_tpu.llm.protocols_openai import (
     sse_encode,
     sse_encode_event,
 )
-from dynamo_tpu.runtime.context import Context
+from dynamo_tpu.runtime.context import (
+    FRONTEND_OUT,
+    HTTP_PARSE,
+    HTTP_RECV,
+    Context,
+)
+from dynamo_tpu.runtime.stages import FRONTEND_LEG, FRONTEND_SPANS
 
 
 class _AuditTap:
@@ -612,6 +618,7 @@ class HttpService:
 
     async def _serve_openai(self, request: web.Request,
                             kind: str) -> web.StreamResponse:
+        recv_ns = time.time_ns()  # the stage clock's first stamp
         endpoint = ("chat_completions" if kind == KIND_CHAT
                     else "completions")
         try:
@@ -640,7 +647,7 @@ class HttpService:
         stream = bool(body.get("stream"))
         request_id = new_request_id(
             "chatcmpl" if kind == KIND_CHAT else "cmpl")
-        ctx = Context(request_id=request_id)
+        ctx = Context(request_id=request_id, stages={HTTP_RECV: recv_ns})
         if tenant is not None:
             from dynamo_tpu.tenancy.config import TENANT_HEADER
 
@@ -660,17 +667,21 @@ class HttpService:
             # capture deltas without perturbing the stream; the record is
             # published (off hot path) when the stream finishes
             engine = _AuditTap(engine, audit_rec, self.audit)
+        ctx.stamp(HTTP_PARSE)  # where the time to the first token starts
         start = time.perf_counter()
         self._inflight.add(1)
         # request span (make_request_span analog): honors an incoming W3C
         # traceparent header; the span is current for this handler task,
         # so downstream transport hops inherit the trace; entered right
-        # at the try so no exception path can leak it as current
+        # at the try so no exception path can leak it as current. It
+        # starts at the handler's first statement, so the stage spans
+        # emitted under it (`_first_frame_out`) lie inside it.
         span = tracer().start_span(
             f"http {endpoint}",
             traceparent=request.headers.get("traceparent"),
             attributes={"http.target": request.path,
                         "request.id": request_id, "model": model})
+        span.start_ns = recv_ns
         span.__enter__()
         rec = {"request_id": request_id, "endpoint": endpoint,
                "model": model, "stream": stream, "tenant": tenant,
@@ -687,7 +698,7 @@ class HttpService:
             chunks = engine.generate(pipeline_request, ctx)
             if stream:
                 return await self._stream_sse(
-                    request, endpoint, chunks, ctx, start, rec)
+                    request, endpoint, chunks, ctx, start, rec, span)
             # unary: aggregate the stream
             try:
                 full = await (aggregate_chat_stream(chunks)
@@ -722,9 +733,24 @@ class HttpService:
             self._dbg_inflight.pop(request_id, None)
             self._dbg_recent.append(rec)
 
+    def _first_frame_out(self, ctx: Context, rec: dict, span) -> None:
+        """The write of a stream's first chunk with content has returned:
+        the frontend's leg of the stage clock ends. The time to the first
+        token is read off the clock's own two stamps (`http_parse` ->
+        `frontend_out`), so the stage intervals behind `http_parse` add
+        up to it and no second timer runs beside them."""
+        ttft = max(ctx.stamp(FRONTEND_OUT) - ctx.stages[HTTP_PARSE], 0) / 1e9
+        self._observe_latency("ttft", ttft, cls=rec.get("class"))
+        rec["first_token_s"] = round(ttft, 6)
+        if self.quota is not None and rec.get("tenant"):
+            self.quota.metrics.observe_ttft(rec["tenant"], ttft)
+        self.manager.runtime.stage_metrics.leg_ended(
+            ctx.stages, FRONTEND_LEG, span, FRONTEND_SPANS)
+
     async def _stream_sse(self, request: web.Request, endpoint: str,
                           chunks, ctx: Context, start: float,
-                          rec: Optional[dict] = None) -> web.StreamResponse:
+                          rec: Optional[dict] = None,
+                          span=None) -> web.StreamResponse:
         resp = web.StreamResponse(headers={
             "Content-Type": "text/event-stream",
             "Cache-Control": "no-cache",
@@ -737,31 +763,26 @@ class HttpService:
             resp.headers["x-dyn-class-downgraded"] = \
                 rec["downgraded_from"]
             resp.headers["x-dyn-class"] = str(rec.get("class", ""))
-        first_token_at: Optional[float] = None
         last_token_at: Optional[float] = None
         try:
             async for chunk in chunks:
-                if first_token_at is None and self._has_content(chunk):
-                    first_token_at = time.perf_counter()
-                    self._observe_latency("ttft", first_token_at - start,
-                                          cls=rec.get("class"))
-                    rec["first_token_s"] = round(first_token_at - start, 6)
-                    if self.quota is not None and rec.get("tenant"):
-                        self.quota.metrics.observe_ttft(
-                            rec["tenant"], first_token_at - start)
-                elif self._has_content(chunk) and last_token_at is not None:
-                    self._observe_latency(
-                        "itl", time.perf_counter() - last_token_at,
-                        cls=rec.get("class"))
-                if self._has_content(chunk):
-                    last_token_at = time.perf_counter()
-                    rec["last_token_s"] = round(last_token_at - start, 6)
+                content = self._has_content(chunk)
+                first = content and last_token_at is None
+                if content:
+                    now = time.perf_counter()
+                    if last_token_at is not None:
+                        self._observe_latency("itl", now - last_token_at,
+                                              cls=rec.get("class"))
+                    last_token_at = now
+                    rec["last_token_s"] = round(now - start, 6)
                 self._observe_usage(chunk.get("usage"))
                 if chunk.get("usage"):
                     rec["usage"] = chunk["usage"]
                 if not resp.prepared:
                     await resp.prepare(request)
                 await resp.write(sse_encode(chunk))
+                if first:
+                    self._first_frame_out(ctx, rec, span)
             if not resp.prepared:
                 await resp.prepare(request)
             await resp.write(SSE_DONE)

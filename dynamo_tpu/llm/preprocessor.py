@@ -26,7 +26,7 @@ from dynamo_tpu.llm.protocols_openai import (
 )
 from dynamo_tpu.llm.tokenizer import Tokenizer
 from dynamo_tpu.protocols import PreprocessedRequest, StopConditions
-from dynamo_tpu.runtime.context import Context
+from dynamo_tpu.runtime.context import PREPROCESS, Context
 from dynamo_tpu.runtime.engine import Operator
 
 KIND_CHAT = "chat"
@@ -226,6 +226,7 @@ class OpenAIPreprocessor(Operator):
                 oai.messages, image_tokens = await self._resolve_images(
                     oai.messages, context)
             pre = self.preprocess_chat(oai, image_tokens)
+            context.stamp(PREPROCESS)
             request_id = request.get("request_id") or new_request_id()
             async for chunk in self._postprocess_chat(
                     pre, oai, request_id, created, context):
@@ -233,6 +234,7 @@ class OpenAIPreprocessor(Operator):
         else:
             oai_c = CompletionRequest.from_dict(request["body"])
             pre = self.preprocess_completion(oai_c)
+            context.stamp(PREPROCESS)
             request_id = request.get("request_id") or new_request_id("cmpl")
             async for chunk in self._postprocess_completion(
                     pre, oai_c, request_id, created, context):

@@ -32,7 +32,7 @@ from dynamo_tpu.protocols import (
     PreprocessedRequest,
     WorkerStats,
 )
-from dynamo_tpu.runtime.context import Context
+from dynamo_tpu.runtime.context import ENGINE, WORKER_IN, Context
 from dynamo_tpu.runtime.tracing import RequestTrace
 from dynamo_tpu.tokens import TokenBlockSequence
 
@@ -303,7 +303,7 @@ class MockEngine:
             req=req, ctx=context, queue=asyncio.Queue(),
             seq=TokenBlockSequence(self.config.block_size, req.token_ids),
             arrival=self._arrivals,
-            trace=trace, t_enqueue_ns=time.time_ns(),
+            trace=trace, t_enqueue_ns=context.stamp(WORKER_IN),
             tenant=tenant,
             cls=cls,
         )
@@ -578,7 +578,7 @@ class MockEngine:
             r.generated += 1
             now_ns = time.time_ns()
             if r.generated == 1:
-                r.t_first_ns = now_ns
+                r.t_first_ns = r.ctx.stamp(ENGINE, now_ns)
                 self.metrics.ttft.observe((now_ns - r.t_enqueue_ns) / 1e9)
                 if r.trace is not None:
                     r.trace.event("first_token")

@@ -51,7 +51,7 @@ from dynamo_tpu.router.scheduler import (
     WorkerLoad,
 )
 from dynamo_tpu.runtime.component import EndpointClient, Instance
-from dynamo_tpu.runtime.context import Context
+from dynamo_tpu.runtime.context import ROUTE, Context
 from dynamo_tpu.runtime.events import EventBus
 from dynamo_tpu.runtime.push import PushRouter
 from dynamo_tpu.runtime.store import DELETE
@@ -626,6 +626,7 @@ class KvPushRouter:
                 token_ids, self.config.block_size)
             request["extra"] = extra
         first = True
+        ctx.stamp(ROUTE)  # the instance chosen, its request ready to send
         try:
             async for item in self.push.direct(request, worker_id, ctx):
                 if first:

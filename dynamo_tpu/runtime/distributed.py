@@ -67,6 +67,12 @@ class DistributedRuntime:
         from dynamo_tpu.runtime.tracing import tracer
 
         tracer().register_metrics(self.metrics)
+        # the request stage family (runtime/stages.py): the transport
+        # server observes the worker's leg, the HTTP service the frontend's
+        from dynamo_tpu.runtime.stages import StageMetrics
+
+        self.stage_metrics = StageMetrics(self.metrics)
+        transport_server.stage_metrics = self.stage_metrics
         # surface retry/timeout/breaker counters on both observability
         # planes: the `_sys.stats` scrape and the Prometheus registry
         transport_server.extra_stats = self._robustness_stats

@@ -168,6 +168,60 @@ class Histogram:
         return out
 
 
+class LabeledHistogram:
+    """One histogram a value of ONE label, rendered as a single labeled
+    Prometheus family (`Histogram` itself carries no labels). Shards are
+    made on first use, so a value never observed renders nothing."""
+
+    def __init__(self, name: str, label: str, help: str = "",
+                 buckets: Sequence[float] = DEFAULT_BUCKETS) -> None:
+        self.name = name
+        self.label = label
+        self.help = help
+        self.buckets = tuple(sorted(buckets))
+        self._shards: dict[str, Histogram] = {}
+        self._lock = threading.Lock()
+
+    def _shard(self, value: str) -> Histogram:
+        h = self._shards.get(value)
+        if h is None:
+            with self._lock:
+                h = self._shards.setdefault(
+                    value, Histogram(self.name, self.help, self.buckets))
+        return h
+
+    def observe(self, value: str, x: float) -> None:
+        self._shard(value).observe(x)
+
+    def quantile(self, value: str, q: float) -> float:
+        h = self._shards.get(value)
+        return h.quantile(q) if h is not None else 0.0
+
+    def stats(self, value: str) -> tuple[float, int]:
+        """(sum, count) of one label value's observations."""
+        h = self._shards.get(value)
+        return (h.sum, h.count) if h is not None else (0.0, 0)
+
+    def values(self) -> list[str]:
+        return sorted(self._shards)
+
+    def render(self) -> list[str]:
+        out = [f"# HELP {self.name} {self.help}",
+               f"# TYPE {self.name} histogram"]
+        for value in self.values():
+            counts, total_sum, total = self._shards[value].snapshot()
+            tag = f'{self.label}="{value}"'
+            acc = 0
+            for i, ub in enumerate(self.buckets):
+                acc += counts[i]
+                out.append(f'{self.name}_bucket{{le="{ub}",{tag}}} {acc}')
+            acc += counts[-1]
+            out.append(f'{self.name}_bucket{{le="+Inf",{tag}}} {acc}')
+            out.append(f'{self.name}_sum{{{tag}}} {total_sum}')
+            out.append(f'{self.name}_count{{{tag}}} {total}')
+        return out
+
+
 class MetricsRegistry:
     """A node in the registry hierarchy; children share the flat metric map
     but get dotted name prefixes (reference hierarchical prefixes)."""

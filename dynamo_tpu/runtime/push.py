@@ -25,7 +25,7 @@ from typing import Any, AsyncIterator, Callable, Optional
 
 from dynamo_tpu.runtime.breaker import CircuitBreaker
 from dynamo_tpu.runtime.component import EndpointClient, Instance
-from dynamo_tpu.runtime.context import Context
+from dynamo_tpu.runtime.context import ROUTE, Context
 from dynamo_tpu.runtime.engine import AsyncEngine
 from dynamo_tpu.runtime.transport import STREAM_ERR_MSG, ConnectError
 
@@ -129,6 +129,7 @@ class PushRouter:
                 # re-filter only after a failure fed the breaker
                 candidates = self._candidates()
             inst = self.select(instance_id, candidates)
+            ctx.stamp(ROUTE)  # a no-op behind KvPushRouter, which stamped
             local = rt.local_engine(inst.subject)
             yielded = False
             try:

@@ -252,6 +252,23 @@ class Tracer:
                 self._open[trace_id] = self._open.get(trace_id, 0) + 1
         return span
 
+    def stage(self, parent: Span, name: str, start_ns: int,
+              end_ns: Optional[int] = None, **attributes: Any) -> None:
+        """Emit one completed span, a child of `parent`, with explicit
+        timestamps: for work timed where no span could be open around it
+        (the scheduler loop, the request's stage clock). Routed through
+        the sampling sink; the Recorder drain already moves the file I/O
+        off the loop."""
+        span = Span(name=name, trace_id=parent.trace_id,
+                    span_id=secrets.token_hex(8),
+                    parent_span_id=parent.span_id,
+                    start_ns=start_ns,
+                    sampled=parent.sampled,
+                    attributes={"service.name": self.service,
+                                **attributes})
+        span.end_ns = end_ns or time.time_ns()
+        self._export(span)
+
     def _export(self, span: Span) -> None:
         if self._recorder is None:
             return
@@ -365,17 +382,8 @@ class RequestTrace:
     def stage(self, name: str, start_ns: int, end_ns: Optional[int] = None,
               **attributes: Any) -> None:
         """Emit one completed stage span (child of the request root) with
-        explicit timestamps — routed through the tracer's sampling sink;
-        the Recorder drain already moves the file I/O off the loop."""
-        span = Span(name=name, trace_id=self.trace_id,
-                    span_id=secrets.token_hex(8),
-                    parent_span_id=self.root.span_id,
-                    start_ns=start_ns,
-                    sampled=self.root.sampled,
-                    attributes={"service.name": self._tracer.service,
-                                **attributes})
-        span.end_ns = end_ns or time.time_ns()
-        self._tracer._export(span)
+        explicit timestamps (`Tracer.stage`)."""
+        self._tracer.stage(self.root, name, start_ns, end_ns, **attributes)
 
     def event(self, name: str, **attributes: Any) -> None:
         """Point-in-time lifecycle event, recorded on the root span as an

@@ -12,6 +12,10 @@ Frames (codec.py msgpack):
   server→client: {t:"data", rid, payload}
                  {t:"end", rid} | {t:"err", rid, error}
 
+The request's stage clock (runtime/stages.py) goes out in `headers` under
+`x-dyn-stages` and comes back ONCE, as the field `stages` of the data frame
+that carries the engine's first emission; no other frame has the field.
+
 Cancellation propagates: context cancel on the client side sends a cancel
 frame; the server cancels the handler task (reference: context.rs kill signal).
 """
@@ -22,10 +26,17 @@ import asyncio
 import itertools
 import logging
 import random
+import time
 from typing import Any, AsyncIterator, Callable, Optional
 
-from dynamo_tpu.runtime import codec
-from dynamo_tpu.runtime.context import Context
+from dynamo_tpu.runtime import codec, stages
+from dynamo_tpu.runtime.context import (
+    ENGINE,
+    TRANSPORT_BACK,
+    TRANSPORT_IN,
+    WORKER_OUT,
+    Context,
+)
 from dynamo_tpu.runtime.engine import AsyncEngine
 from dynamo_tpu.runtime.faults import FaultInjector
 
@@ -71,6 +82,9 @@ class TransportServer:
         # (the runtime wires client/breaker counters here so routers'
         # failure handling is observable from the same endpoint)
         self.extra_stats: Optional[Callable[[], dict]] = None
+        # the process's stage family (the runtime sets it): observed when
+        # a request's first emission has gone out, `stages.WORKER_LEG`
+        self.stage_metrics: Optional[stages.StageMetrics] = None
 
     def _stat(self, subject: str) -> dict:
         return self.stats.setdefault(subject, {
@@ -143,15 +157,21 @@ class TransportServer:
         write_lock = asyncio.Lock()
         self._conn_writers.add(writer)
 
-        async def send(obj: dict) -> None:
+        async def send(obj: dict, clock: Optional[dict] = None,
+                       back: bool = False) -> None:
             async with write_lock:
+                if clock is not None:
+                    # stamped with the socket in hand, behind whatever
+                    # frames were ahead: the stamp rides in this frame,
+                    # so the frame's own write and drain are the reader's
+                    clock.setdefault(WORKER_OUT, time.time_ns())
+                    if back:
+                        obj[stages.FIELD] = clock
                 codec.write_frame(writer, obj)
                 await writer.drain()
 
         async def run_request(rid: str, subject: str, payload: Any,
                               headers: dict) -> None:
-            import time as _time
-
             from dynamo_tpu.runtime.tracing import TRACEPARENT, tracer
 
             ctx = inflight[rid][1]
@@ -190,7 +210,7 @@ class TransportServer:
             stat = self._stat(subject)
             stat["requests"] += 1
             stat["inflight"] += 1
-            t0 = _time.perf_counter()
+            t0 = time.perf_counter()
             # Server-side deadline: the client stamped its overall budget
             # on the request; once it passes, the client is gone (its own
             # timer fired first), so abort the handler instead of
@@ -217,9 +237,22 @@ class TransportServer:
                         attributes={"rpc.subject": subject,
                                     "request.id": rid}) as span:
                     n = 0
+                    clock: Optional[dict] = ctx.stages
                     async for item in engine.generate(payload, ctx):
-                        await send({"t": "data", "rid": rid,
-                                    "payload": item})
+                        frame = {"t": "data", "rid": rid, "payload": item}
+                        if clock is not None and ENGINE in clock:
+                            # the first emission's frame: the clock goes
+                            # back in it (to a sender that keeps one) and
+                            # this process's leg has ended
+                            await send(frame, clock,
+                                       back=stages.HEADER in headers)
+                            if self.stage_metrics is not None:
+                                self.stage_metrics.leg_ended(
+                                    clock, stages.WORKER_LEG, span,
+                                    stages.WORKER_SPANS)
+                            clock = None
+                        else:
+                            await send(frame)
                         n += 1
                     span.set_attribute("response.items", n)
                     stat["items"] += n
@@ -244,7 +277,7 @@ class TransportServer:
                 if timer is not None:
                     timer.cancel()
                 stat["inflight"] -= 1
-                stat["total_processing_s"] += _time.perf_counter() - t0
+                stat["total_processing_s"] += time.perf_counter() - t0
                 inflight.pop(rid, None)
 
         try:
@@ -256,10 +289,16 @@ class TransportServer:
                 t = msg.get("t")
                 if t == "req":
                     rid = msg["rid"]
-                    ctx = Context(request_id=rid, headers=msg.get("headers") or {})
+                    headers = msg.get("headers") or {}
+                    # the sender's clock goes on from here; a request
+                    # without one starts its own at this stamp
+                    ctx = Context(request_id=rid, headers=headers,
+                                  stages=stages.from_wire(
+                                      headers.get(stages.HEADER)))
+                    ctx.stamp(TRANSPORT_IN)
                     task = asyncio.get_running_loop().create_task(
                         run_request(rid, msg["subject"], msg.get("payload"),
-                                    msg.get("headers") or {})
+                                    headers)
                     )
                     inflight[rid] = (task, ctx)
                     self._conn_tasks.add(task)
@@ -326,6 +365,9 @@ class _Connection:
                             "connection", self.address, exc_info=True)
                     break
                 rid = msg.get("rid")
+                clock = msg.get(stages.FIELD)
+                if isinstance(clock, dict):
+                    clock[TRANSPORT_BACK] = time.time_ns()
                 if self._injector is not None:
                     action = self._injector.on_frame(
                         self.address, self._subjects.get(rid), rid, msg)
@@ -554,6 +596,14 @@ class TransportClient:
         try:
             q = conn.open_stream(rid, subject)
             headers = inject_headers(dict(ctx.headers))
+            # the stage clock leaves with the request's first hop into a
+            # worker and is merged when it comes back; a worker's own hop
+            # to another worker (TRANSPORT_IN stamped) carries none
+            clocked = bool(ctx.stages) and TRANSPORT_IN not in ctx.stages
+            if clocked:
+                headers[stages.HEADER] = dict(ctx.stages)
+            else:
+                headers.pop(stages.HEADER, None)
             if expires is not None:
                 # stamp the REMAINING time, not the configured total: the
                 # server-side abort timer must share this request's budget
@@ -600,6 +650,11 @@ class TransportClient:
                     raise ConnectionError(STREAM_ERR_MSG)
                 t = msg.get("t")
                 if t == "data":
+                    if clocked and stages.FIELD in msg:
+                        clocked = False
+                        for stage, ns in stages.from_wire(
+                                msg[stages.FIELD]).items():
+                            ctx.stages.setdefault(stage, ns)
                     yield msg["payload"]
                 elif t == "end":
                     return

@@ -13,10 +13,7 @@ from dynamo_tpu.tenancy.config import (  # noqa: F401
     tenancy_from_env,
 )
 from dynamo_tpu.tenancy.fair import FairScheduler, tenant_state  # noqa: F401
-from dynamo_tpu.tenancy.metrics import (  # noqa: F401
-    TenantHistogram,
-    TenantMetrics,
-)
+from dynamo_tpu.tenancy.metrics import TenantMetrics  # noqa: F401
 from dynamo_tpu.tenancy.quota import (  # noqa: F401
     QuotaGate,
     TokenBucket,

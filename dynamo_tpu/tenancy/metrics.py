@@ -11,10 +11,10 @@ a frontend and a worker sharing one in-proc registry (tests, run/main)
 must not shadow each other's identically-named objects (the registry is
 first-wins by name).
 
-Counters/gauges carry a `tenant` label. The runtime Histogram has no
-label support, so `TenantHistogram` shards one histogram per tenant and
-renders them as a single labeled Prometheus family — quantiles stay
-available per tenant for /debug/tenants and doctor. Per-tenant *_sum
+Counters/gauges carry a `tenant` label; the histograms are the runtime's
+`LabeledHistogram` on it: one histogram shard per tenant, rendered as a
+single labeled Prometheus family — quantiles stay available per tenant
+for /debug/tenants and doctor. Per-tenant *_sum
 counters ride alongside so the event-plane telemetry snapshots (which
 only walk Counter/Gauge/Histogram) can still merge per-tenant latency
 across the fleet.
@@ -22,66 +22,12 @@ across the fleet.
 
 from __future__ import annotations
 
-import threading
-from typing import Sequence
-
-from dynamo_tpu.runtime.metrics import Counter, Gauge, Histogram
+from dynamo_tpu.runtime.metrics import Counter, Gauge, LabeledHistogram
 
 _TTFT_BUCKETS = (0.001, 0.005, 0.01, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5,
                  5.0, 10.0, 30.0)
 _WAIT_BUCKETS = (0.0001, 0.001, 0.005, 0.01, 0.05, 0.1, 0.5, 1.0, 2.5,
                  5.0, 10.0, 30.0)
-
-
-class TenantHistogram:
-    """Per-tenant histogram shards rendered as one labeled family."""
-
-    def __init__(self, name: str, help: str = "",
-                 buckets: Sequence[float] = _TTFT_BUCKETS) -> None:
-        self.name = name
-        self.help = help
-        self.buckets = tuple(sorted(buckets))
-        self._shards: dict[str, Histogram] = {}
-        self._lock = threading.Lock()
-
-    def _shard(self, tenant: str) -> Histogram:
-        h = self._shards.get(tenant)
-        if h is None:
-            with self._lock:
-                h = self._shards.setdefault(
-                    tenant, Histogram(self.name, self.help, self.buckets))
-        return h
-
-    def observe(self, tenant: str, value: float) -> None:
-        self._shard(tenant).observe(value)
-
-    def quantile(self, tenant: str, q: float) -> float:
-        h = self._shards.get(tenant)
-        return h.quantile(q) if h is not None else 0.0
-
-    def stats(self, tenant: str) -> tuple[float, int]:
-        h = self._shards.get(tenant)
-        return (h.sum, h.count) if h is not None else (0.0, 0)
-
-    def tenants(self) -> list[str]:
-        return sorted(self._shards)
-
-    def render(self) -> list[str]:
-        out = [f"# HELP {self.name} {self.help}",
-               f"# TYPE {self.name} histogram"]
-        for tenant in sorted(self._shards):
-            counts, total_sum, total = self._shards[tenant].snapshot()
-            acc = 0
-            for i, ub in enumerate(self.buckets):
-                acc += counts[i]
-                out.append(f'{self.name}_bucket'
-                           f'{{le="{ub}",tenant="{tenant}"}} {acc}')
-            acc += counts[-1]
-            out.append(f'{self.name}_bucket'
-                       f'{{le="+Inf",tenant="{tenant}"}} {acc}')
-            out.append(f'{self.name}_sum{{tenant="{tenant}"}} {total_sum}')
-            out.append(f'{self.name}_count{{tenant="{tenant}"}} {total}')
-        return out
 
 
 class TenantMetrics:
@@ -97,8 +43,8 @@ class TenantMetrics:
             "quota 429s by tenant and reason (streams|token_rate)")
         self.streams = Gauge(
             "dynamo_tenant_streams", "live streams by tenant")
-        self.ttft = TenantHistogram(
-            "dynamo_tenant_ttft_seconds",
+        self.ttft = LabeledHistogram(
+            "dynamo_tenant_ttft_seconds", "tenant",
             "client-visible TTFT by tenant", _TTFT_BUCKETS)
         self.ttft_sum = Counter(
             "dynamo_tenant_ttft_seconds_total",
@@ -110,8 +56,8 @@ class TenantMetrics:
         self.goodput = Counter(
             "dynamo_tenant_goodput_tokens_total",
             "decoded tokens emitted by tenant")
-        self.queue_wait = TenantHistogram(
-            "dynamo_tenant_queue_wait_seconds",
+        self.queue_wait = LabeledHistogram(
+            "dynamo_tenant_queue_wait_seconds", "tenant",
             "enqueue-to-admission wait by tenant", _WAIT_BUCKETS)
         self.queue_wait_sum = Counter(
             "dynamo_tenant_queue_wait_seconds_total",

@@ -1825,7 +1825,8 @@ class TpuEngine:
         with trk:
             sampled = self._mesh_dispatch(
                 trk, sample_tokens_lp, logits, *lane_arrays, rows=rows,
-                topk_lp=tk, span_tokens=len(pending), routed_tokens=0)
+                topk_lp=tk, span_tokens=len(pending), routed_tokens=0,
+                temps=lane_arrays[2])
         rec = self.step_recorder
         if rec is not None:
             rec.record("sample_first", trk.shape, trk.elapsed_s,
@@ -2048,7 +2049,8 @@ class TpuEngine:
                     jax.numpy.asarray(top_ps),
                     jax.numpy.asarray(top_ks),
                     mcfg, k_steps, aligned, tk,
-                    span_tokens=sum(chunk_lens) + len(batch) * k_steps)
+                    span_tokens=sum(chunk_lens) + len(batch) * k_steps,
+                    temps=temps)
                 # ONE host sync; chunk logits stay on device for the
                 # first-token sampler
                 return self._host_sync(packed), ch_logits, kc, vc
@@ -2306,7 +2308,7 @@ class TpuEngine:
                 jax.numpy.asarray(valid), jax.numpy.asarray(seeds),
                 jax.numpy.asarray(steps), jax.numpy.asarray(temps),
                 jax.numpy.asarray(top_ps), jax.numpy.asarray(top_ks),
-                mcfg, k_steps, topk_lp=tk,
+                mcfg, k_steps, topk_lp=tk, temps=temps,
                 span_tokens=len(batch) * k_steps, **self._slot_kw(batch, b))
         rec = self.step_recorder
         if rec is not None:
@@ -2562,7 +2564,7 @@ class TpuEngine:
                     jax.numpy.asarray(steps), jax.numpy.asarray(temps),
                     jax.numpy.asarray(top_ps), jax.numpy.asarray(top_ks),
                     mcfg, cfg.pp_mesh, k_steps,
-                    n_micro=cfg.pp_microbatches, topk_lp=tk,
+                    n_micro=cfg.pp_microbatches, topk_lp=tk, temps=temps,
                     span_tokens=len(batch) * k_steps, **ckw)
                 return self._host_sync(packed), kc, vc  # ONE host sync
 
@@ -2618,7 +2620,8 @@ class TpuEngine:
                     g_bits, g_next, g_eos_ok, jax.numpy.asarray(g_ids),
                     jax.numpy.asarray(g_states),
                     jax.numpy.asarray(stop_ids), mcfg, k_steps,
-                    topk_lp=tk, span_tokens=len(batch) * k_steps)
+                    topk_lp=tk, span_tokens=len(batch) * k_steps,
+                    temps=temps)
                 return self._host_sync(sampled), kc, vc
             sampled, kc, vc = self._mesh_dispatch(
                 trk, self._decode_multi_step,
@@ -2628,7 +2631,7 @@ class TpuEngine:
                 jax.numpy.asarray(seeds), jax.numpy.asarray(steps),
                 jax.numpy.asarray(temps), jax.numpy.asarray(top_ps),
                 jax.numpy.asarray(top_ks), mcfg, k_steps, topk_lp=tk,
-                span_tokens=len(batch) * k_steps,
+                span_tokens=len(batch) * k_steps, temps=temps,
                 **self._slot_kw(batch, b))
             return self._host_sync(sampled), kc, vc       # ONE host sync
 
@@ -2750,7 +2753,7 @@ class TpuEngine:
                     jax.numpy.asarray(top_ks),
                     mcfg, n_blocks, steps, cfg.dllm_unmasking_strategy,
                     span_tokens=len(batch) * k_steps,
-                    routed_tokens=forwards * blk)
+                    routed_tokens=forwards * blk, temps=temps)
 
         t_launch = time.perf_counter()
         async with self._device_lock:
@@ -2860,7 +2863,8 @@ class TpuEngine:
         return packed
 
     def _mesh_dispatch(self, trk, fn, *args, span_tokens: int = 0,
-                       routed_tokens: Optional[int] = None, **kwargs):
+                       routed_tokens: Optional[int] = None,
+                       temps: Optional[np.ndarray] = None, **kwargs):
         """The one place every jitted dispatch passes through, on the
         thread that runs it. Armed (DYN_STEP_PROFILE) the call sits under
         a `dispatch` host span labelled as CompileTracker labels it, with
@@ -2868,7 +2872,12 @@ class TpuEngine:
         as rows through an MoE model's routed dispatch, unless the site
         says how many did: `routed_tokens`, 0 for a sampler); the sites
         convert their inputs (`jnp.asarray`) before they get here, so
-        those transfers are outside the span. Mesh-recorder shim too. Off
+        those transfers are outside the span. An entry that samples through
+        `sample_tokens_traced` (not the speculative burst, whose ratio
+        test builds both sides' candidate sets for every batch) hands the
+        host's copy of its lanes' temperatures (`temps`): where none
+        draws the program's sampler takes its greedy branch, and the
+        dispatch is counted. Mesh-recorder shim too. Off
         (mesh_recorder is None, the default): one attribute check, then
         the call — tokens and scheduler_stats stay byte-identical
         (pinned by tests/test_mesh_recorder.py). Armed: a
@@ -2880,6 +2889,8 @@ class TpuEngine:
         if self._routed_per_token:
             self.metrics.moe_routed_rows.inc(self._routed_per_token * (
                 span_tokens if routed_tokens is None else routed_tokens))
+        if temps is not None and not (temps > 0).any():
+            self.metrics.sampler_greedy_dispatches.inc(entry=trk.entry)
         srec = self.step_recorder
         # ONE frame and one call site armed or not: the persistent
         # compile cache's key follows the source lines of the call stack
@@ -3273,7 +3284,7 @@ class TpuEngine:
                 jax.numpy.asarray(seeds), jax.numpy.asarray(steps),
                 jax.numpy.asarray(temps), jax.numpy.asarray(top_ps),
                 jax.numpy.asarray(top_ks), mcfg, tk,
-                span_tokens=sum(chunk_lens) + len(batch))
+                span_tokens=sum(chunk_lens) + len(batch), temps=temps)
             # ONE host sync; chunk logits stay on device for the
             # first-token sampler
             packed = self._host_sync(packed)
@@ -3761,7 +3772,7 @@ class TpuEngine:
                         jax.numpy.asarray(inf["top_ps"]),
                         jax.numpy.asarray(inf["top_ks"]),
                         mcfg, k, topk_lp=inf.get("tk", 0),
-                        span_tokens=len(batch) * k,
+                        span_tokens=len(batch) * k, temps=inf["temps"],
                         **self._slot_kw(batch, b))
 
             rec = self.step_recorder

@@ -123,6 +123,11 @@ class EngineMetrics:
             "dynamo_engine_refills_behind_burst_total",
             "sequences admitted while a block burst was in flight and "
             "prefilled behind it, ahead of its emission")
+        self.sampler_greedy_dispatches = c(
+            "dynamo_engine_sampler_greedy_dispatches_total",
+            "dispatches of a sampling entry in which no lane drew "
+            "(every temperature 0): the sampler ran its argmax and no "
+            "candidate set, by entry")
         self.mixed_steps = c(
             "dynamo_engine_mixed_steps_total",
             "fused prefill-chunk + decode-burst steps")
@@ -205,7 +210,8 @@ class EngineMetrics:
                   self.decode_seconds, self.tokens_emitted,
                   self.prefill_emitted, self.prefill_new_tokens,
                   self.pipelined_bursts, self.chained_refills,
-                  self.refills_behind_burst, self.mixed_steps,
+                  self.refills_behind_burst,
+                  self.sampler_greedy_dispatches, self.mixed_steps,
                   self.decode_steps_during_prefill,
                   self.block_forwards, self.blocks, self.moe_routed_rows,
                   self.state_resets, self.state_slots,

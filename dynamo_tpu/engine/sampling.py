@@ -6,10 +6,25 @@ Randomness is derived *inside* the jit from (seed, step) pairs, so the
 scheduler passes plain integers and replay/migration is deterministic.
 
 TPU note: full-vocab `sort` costs tens of ms; instead `lax.top_k` keeps the
-MAX_CANDIDATES highest logits (cheap on TPU) and top-k/top-p/sampling run
-on that truncated set. User top_k is clipped to MAX_CANDIDATES; top-p mass
-is computed over the candidates (the tail beyond 64 candidates carries
+MAX_CANDIDATES highest logits and top-k/top-p/sampling run on that
+truncated set. User top_k is clipped to MAX_CANDIDATES; top-p mass is
+computed over the candidates (the tail beyond 64 candidates carries
 negligible probability for real models). Greedy uses a full argmax.
+
+What a batch pays (one TPU v5e, (128, 131072) f32 logits, measured alone
+on the chip for PR 44): the top-k is linear in rows x vocabulary and is
+NOT cheap at a wide batch of a large vocabulary: 3.50 ms of a sampler's
+3.82, next to 0.22 for the argmax and 0.32 for the chosen token's
+log-probability. So `sample_tokens_traced` builds the candidate set under
+a `lax.cond` on "does any lane of this batch draw": an all-greedy batch
+runs the argmax alone (0.3-0.5 ms with its log-probability), a batch in
+which one lane draws runs the whole of it for every lane as before
+(3.77 ms): the same operations in the same order, so on one compiled
+program its tokens are what they were (on the chip a drawn stream still
+moves whenever XLA compiles the program around the sampler anew: PERF.md
+§6, PR 44). Every sampling site (the fused decode loops, the block burst,
+the first-token sampler) takes the condition from here; none keeps one of
+its own.
 """
 
 from __future__ import annotations
@@ -85,19 +100,31 @@ def sample_tokens_traced(logits: jax.Array, seeds: jax.Array,
     min_p × max-probability (after temperature). temperature <= 0 ⇒
     greedy. Returns (B,) i32 tokens. Traceable (used inside fused decode
     loops)."""
-    b, v = logits.shape
-    greedy = jnp.argmax(logits, axis=-1)
-    masked, cand_idx, t = _candidate_mask(logits, temperature, top_p,
-                                          top_k, min_p)
+    def greedy():
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
-    def sample_one(seed, step, lg, tt):
-        key = jax.random.fold_in(jax.random.PRNGKey(seed), step)
-        return jax.random.categorical(key, lg / tt)
+    def draw():
+        masked, cand_idx, t = _candidate_mask(logits, temperature, top_p,
+                                              top_k, min_p)
 
-    choice = jax.vmap(sample_one)(
-        seeds.astype(jnp.uint32), steps.astype(jnp.uint32), masked, t)
-    sampled = jnp.take_along_axis(cand_idx, choice[:, None], axis=-1)[:, 0]
-    return jnp.where(temperature > 0, sampled, greedy).astype(jnp.int32)
+        def sample_one(seed, step, lg, tt):
+            key = jax.random.fold_in(jax.random.PRNGKey(seed), step)
+            return jax.random.categorical(key, lg / tt)
+
+        choice = jax.vmap(sample_one)(
+            seeds.astype(jnp.uint32), steps.astype(jnp.uint32), masked, t)
+        sampled = jnp.take_along_axis(cand_idx, choice[:, None],
+                                      axis=-1)[:, 0]
+        return jnp.where(temperature > 0, sampled, greedy())
+
+    # the candidate set only where some lane of the batch draws: a greedy
+    # batch is the argmax it always was (the predicate is loop-invariant
+    # in the fused loops, and XLA hoists it). The argmax is taken inside
+    # each branch and not ahead of the condition: ahead of it the block
+    # burst's head wrote its 256 x 151936 logits as f32 where it writes
+    # bf16 (+78 MB of temporaries compiled for a v5e, +1.8% TPOT in the
+    # SDAR cell, PR 44)
+    return lax.cond(jnp.any(temperature > 0), draw, greedy)
 
 
 sample_tokens = jax.jit(sample_tokens_traced)

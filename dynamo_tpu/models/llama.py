@@ -713,14 +713,9 @@ def block_decode_multi_step(params: dict, k_cache: tuple, v_cache: tuple,
         """tokens, their log-probabilities and each row's confidence (the
         log-probability of its best token), (L, B) each."""
         flat = logits.reshape(lanes * blk, -1)
-        # the candidate set (a top-k over the vocabulary a row) only where
-        # some lane draws: a greedy batch is an argmax
-        toks = lax.cond(
-            jnp.any(temperature > 0),
-            lambda: sample_tokens_traced(
-                flat, rep(seeds), pos.reshape(-1), rep(temperature),
-                rep(top_p), rep(top_k)),
-            lambda: jnp.argmax(flat, axis=-1).astype(jnp.int32))
+        toks = sample_tokens_traced(
+            flat, rep(seeds), pos.reshape(-1), rep(temperature),
+            rep(top_p), rep(top_k))
         logp = jax.nn.log_softmax(flat, axis=-1)
         chosen = jnp.take_along_axis(logp, toks[:, None], axis=-1)[:, 0]
         shape = (lanes, blk)

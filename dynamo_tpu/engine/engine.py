@@ -277,7 +277,7 @@ class TpuEngineConfig:
     dllm_unmasking_strategy: str = "sequential"
 
 
-@dataclass
+@dataclass(eq=False)  # a sequence is itself: `in` / `.remove` scan pointers
 class _Seq:
     req: PreprocessedRequest
     ctx: Context

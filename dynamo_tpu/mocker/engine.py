@@ -104,7 +104,7 @@ class MockEngineConfig:
     ragged: bool = False
 
 
-@dataclass
+@dataclass(eq=False)  # identity, as TpuEngine._Seq: list scans compare pointers
 class _MockRequest:
     req: PreprocessedRequest
     ctx: Context

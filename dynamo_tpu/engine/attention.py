@@ -128,7 +128,8 @@ def visible(s_pos: jax.Array, q_pos: jax.Array, attn_block: int
 def prefill_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                       page_table: jax.Array, q_positions: jax.Array,
                       seq_len: jax.Array, page_size: int,
-                      attn_block: int = 1) -> jax.Array:
+                      attn_block: int = 1,
+                      scale: float | None = None) -> jax.Array:
     """Causal attention for one sequence's prefill, reading K/V from pages
     (`attn_block` > 1: causal by blocks of that many positions).
 
@@ -148,7 +149,8 @@ def prefill_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     k = _repeat_kv(k, groups, axis=0)                      # (H, S, D)
     v = _repeat_kv(v, groups, axis=0)
     scores = jnp.einsum("thd,hsd->hts", q.astype(jnp.float32),
-                        k.astype(jnp.float32)) / (d ** 0.5)
+                        k.astype(jnp.float32))
+    scores = scores / (d ** 0.5) if scale is None else scores * scale
     s_pos = jnp.arange(k.shape[1])
     mask = visible(s_pos[None, :], q_positions[:, None], attn_block) \
         & (s_pos[None, :] < seq_len)                       # (T, S)
@@ -189,24 +191,53 @@ def mixed_attention(q_dec: jax.Array, q_chunk: jax.Array,
 
 def paged_attention_decode(q: jax.Array, k_pages: jax.Array,
                            v_pages: jax.Array, lengths: jax.Array,
-                           page_tables: jax.Array,
-                           page_size: int) -> jax.Array:
+                           page_tables: jax.Array, page_size: int,
+                           scale: float | None = None) -> jax.Array:
     """One-token-per-sequence paged attention.
 
     q: (B, H, D); k_pages/v_pages: (KVH, N, P, D); lengths: (B,) valid
     lengths (0 = padding lane); page_tables: (B, max_pages). → (B, H, D).
+    A cache whose rows are wider than a head: `folded`.
     """
+    if k_pages.shape[-1] != q.shape[-1]:
+        return folded(paged_attention_decode, q, k_pages, v_pages, lengths,
+                      page_tables, page_size)
     # Mosaic tiling constraint: last dims must align to (8, 128) lanes —
     # head_dim must be a multiple of 128 for the kernel's block specs.
     if use_pallas():
         if q.shape[-1] % 128 == 0:
             return _pallas_decode(q, k_pages, v_pages, lengths,
-                                  page_tables)
+                                  page_tables, scale)
         _note_fallback("head_dim")
-    return _xla_decode(q, k_pages, v_pages, lengths, page_tables)
+    return _xla_decode(q, k_pages, v_pages, lengths, page_tables, scale)
 
 
-def _xla_decode(q, k_pages, v_pages, lengths, page_tables):
+def folded(core, q: jax.Array, k_pages: jax.Array, *rest, **kw):
+    """`core(q, k_pages, ...)` where the cache's rows are wider than a
+    head: `fold` kv heads side by side in one row (engine/pages.py
+    `kv_layer_shape`: two 64-wide heads a 128-lane row, the cache's
+    published bytes and a row the kernels can tile). Each q head is laid
+    into its kv head's lanes of a row-wide vector of zeros, so that the
+    products the cores make as they always did, `fold * groups` q heads
+    to a row, give its scores against its own kv head alone (the zeros
+    meet the neighbours' lanes; the MXU contracts 128 deep either way);
+    the output's other lanes, the neighbours' values under this head's
+    probabilities, are dropped. `core` takes `scale=`, the scores' factor
+    of the head's own width. q (..., H, D) -> (..., H, D)."""
+    d = q.shape[-1]
+    fold = k_pages.shape[-1] // d
+    h = q.shape[-2]
+    groups = h // (k_pages.shape[0] * fold)
+    mine = jax.nn.one_hot((jnp.arange(h) // groups) % fold, fold,
+                          dtype=jnp.bool_)[:, :, None]      # (H, fold, 1)
+    wide = jnp.where(mine, q[..., None, :], 0).reshape(
+        q.shape[:-1] + (fold * d,))
+    out = core(wide, k_pages, *rest, scale=1.0 / (d ** 0.5), **kw)
+    out = out.reshape(out.shape[:-1] + (fold, d))
+    return jnp.sum(jnp.where(mine, out, 0), axis=-2)
+
+
+def _xla_decode(q, k_pages, v_pages, lengths, page_tables, scale=None):
     kvh, _, p, d = k_pages.shape
     b, h, _ = q.shape
     groups = h // kvh
@@ -216,7 +247,8 @@ def _xla_decode(q, k_pages, v_pages, lengths, page_tables):
     k = _repeat_kv(k, groups, axis=1)                      # (B, H, S, D)
     v = _repeat_kv(v, groups, axis=1)
     scores = jnp.einsum("bhd,bhsd->bhs", q.astype(jnp.float32),
-                        k.astype(jnp.float32)) / (d ** 0.5)
+                        k.astype(jnp.float32))
+    scores = scores / (d ** 0.5) if scale is None else scores * scale
     s_pos = jnp.arange(k.shape[2])
     mask = s_pos[None, :] < lengths[:, None]               # (B, S)
     scores = jnp.where(mask[:, None, :], scores, _NEG_INF)
@@ -264,8 +296,9 @@ def decode_geometry(batch: int, kvh: int, groups: int, page_size: int,
 
 
 def paged_decode_attention(q, k_pages, v_pages, lengths, page_tables, *,
-                           interpret=False):
-    """The decode attention kernel (signature = `_xla_decode`).
+                           scale=None, interpret=False):
+    """The decode attention kernel (signature = `_xla_decode`; `scale`:
+    the scores' factor where it is not 1 / sqrt(row width), `folded`).
 
     Reads only what is live: a lane walks `cdiv(length, block tokens)`
     blocks of its own page table, a padding lane (`length == 0`) none,
@@ -293,7 +326,8 @@ def paged_decode_attention(q, k_pages, v_pages, lengths, page_tables, *,
     ppb, lanes = decode_geometry(b, kvh, groups, p, d, kv_dtype.itemsize)
     t = ppb * p
     slots = _DECODE_SLOTS
-    scale = 1.0 / (d ** 0.5)
+    if scale is None:
+        scale = 1.0 / (d ** 0.5)
     f32 = jnp.float32
     # q and K meet in their own dtype when it is one (bf16 products are
     # exact in the f32 accumulator), else in f32
@@ -446,12 +480,13 @@ def paged_decode_attention(q, k_pages, v_pages, lengths, page_tables, *,
     return out[:, :, :groups].reshape(b, h, d)
 
 
-def _pallas_decode(q, k_pages, v_pages, lengths, page_tables):
+def _pallas_decode(q, k_pages, v_pages, lengths, page_tables, scale=None):
     from dynamo_tpu.engine.kernels import (KV_SPEC, REP_SPEC, ROW_SPEC,
                                            per_tp_shard)
 
     return per_tp_shard(
-        paged_decode_attention,
+        paged_decode_attention if scale is None else functools.partial(
+            paged_decode_attention, scale=scale),
         (ROW_SPEC, KV_SPEC, KV_SPEC, REP_SPEC, REP_SPEC),
         ROW_SPEC)(q, k_pages, v_pages, lengths, page_tables)
 
@@ -459,8 +494,8 @@ def _pallas_decode(q, k_pages, v_pages, lengths, page_tables):
 def paged_attention_prefill(q: jax.Array, k_pages: jax.Array,
                             v_pages: jax.Array, page_tables: jax.Array,
                             q_starts: jax.Array, seq_lens: jax.Array,
-                            page_size: int, attn_block: int = 1
-                            ) -> jax.Array:
+                            page_size: int, attn_block: int = 1,
+                            scale: float | None = None) -> jax.Array:
     """Causal attention of a round of prefill chunks against the pages
     that already hold them; with `attn_block` > 1 causal by blocks
     (`visible`), every key of a row's own block visible to it.
@@ -469,7 +504,11 @@ def paged_attention_prefill(q: jax.Array, k_pages: jax.Array,
     k_pages/v_pages: (KVH, N, P, D); page_tables: (Bp, max_pages);
     q_starts/seq_lens: (Bp,) (`seq_len == q_start` = padding lane).
     -> (Bp, T, H, D). Rows at or past `seq_len` are finite and ignored.
+    A cache whose rows are wider than a head: `folded`.
     """
+    if k_pages.shape[-1] != q.shape[-1]:
+        return folded(paged_attention_prefill, q, k_pages, v_pages,
+                      page_tables, q_starts, seq_lens, page_size, attn_block)
     kvh, _, p, d = k_pages.shape
     _, t, h, _ = q.shape
     if use_pallas():
@@ -480,12 +519,12 @@ def paged_attention_prefill(q: jax.Array, k_pages: jax.Array,
             _note_fallback("chunk_shape")
         else:
             return _pallas_prefill(q, k_pages, v_pages, page_tables,
-                                   q_starts, seq_lens, attn_block)
+                                   q_starts, seq_lens, attn_block, scale)
     positions = q_starts[:, None] + jnp.arange(t)[None, :]
     return jax.vmap(
         lambda q1, pt, pos1, sl: prefill_attention(
             q1, k_pages, v_pages, pt, q_positions=pos1, seq_len=sl,
-            page_size=page_size, attn_block=attn_block)
+            page_size=page_size, attn_block=attn_block, scale=scale)
     )(q, page_tables, positions, seq_lens)
 
 
@@ -533,10 +572,13 @@ def prefill_geometry(kvh: int, groups: int, chunk: int, page_size: int,
 # jitted so that the layers of a step share one trace and one lowering of
 # the kernel: inline, 28 layers' worth cost a prefill program 6 s of
 # lowering at every start, compile cache or not (PERF.md §6, PR 32)
-@functools.partial(jax.jit, static_argnames=("attn_block", "interpret"))
+@functools.partial(jax.jit,
+                   static_argnames=("attn_block", "scale", "interpret"))
 def paged_prefill_attention(q, k_pages, v_pages, page_tables, q_starts,
-                            seq_lens, *, attn_block=1, interpret=False):
-    """The prefill attention kernel (operands as `paged_attention_prefill`).
+                            seq_lens, *, attn_block=1, scale=None,
+                            interpret=False):
+    """The prefill attention kernel (operands as `paged_attention_prefill`;
+    `scale` as in `paged_decode_attention`).
     `attn_block` > 1: a row sees up to the end of its own block
     (`visible`), so a tile walks up to the block end of its last row.
 
@@ -568,7 +610,8 @@ def paged_prefill_attention(q, k_pages, v_pages, page_tables, q_starts,
     tk = ppb * p
     rows = groups * tq
     slots = _PREFILL_SLOTS
-    scale = 1.0 / (d ** 0.5)
+    if scale is None:
+        scale = 1.0 / (d ** 0.5)
     f32 = jnp.float32
     qk_dtype = kv_dtype if q.dtype == kv_dtype else f32
     qk_precision = jax.lax.Precision.HIGHEST if qk_dtype == f32 else None
@@ -699,13 +742,15 @@ def paged_prefill_attention(q, k_pages, v_pages, page_tables, q_starts,
 
 
 def _pallas_prefill(q, k_pages, v_pages, page_tables, q_starts, seq_lens,
-                    attn_block=1):
+                    attn_block=1, scale=None):
     from dynamo_tpu.engine.kernels import KV_SPEC, REP_SPEC, per_tp_shard
 
     chunk_spec = jax.sharding.PartitionSpec(None, None, "tp")
     return per_tp_shard(
-        functools.partial(paged_prefill_attention, attn_block=attn_block)
-        if attn_block != 1 else paged_prefill_attention,
+        functools.partial(paged_prefill_attention, attn_block=attn_block,
+                          scale=scale)
+        if attn_block != 1 or scale is not None
+        else paged_prefill_attention,
         (chunk_spec, KV_SPEC, KV_SPEC, REP_SPEC, REP_SPEC, REP_SPEC),
         chunk_spec)(q, k_pages, v_pages, page_tables, q_starts, seq_lens)
 

@@ -47,8 +47,15 @@ EventSink = Callable[[KvCacheEvent], None]
 
 def kv_layer_shape(cfg, num_pages: int) -> tuple:
     """(KVH, N, P, D): one layer's K (or V) cache of `num_pages` pages —
-    the layout the paged-attention kernels want."""
-    return (cfg.num_kv_heads, num_pages, cfg.page_size, cfg.head_dim)
+    the layout the paged-attention kernels want. A configuration whose
+    heads are narrower than a 128-lane row says how many kv heads ride
+    side by side in one (`kv_fold`, models/lfm2_moe.py: two 64-wide heads):
+    (KVH / fold, N, P, fold * D), the same bytes a token, a row the
+    kernels can tile (engine/attention.py `folded`). The heads of a row
+    are neighbours, so k (..., KVH, D) becomes its rows by a reshape."""
+    fold = getattr(cfg, "kv_fold", 1)
+    return (cfg.num_kv_heads // fold, num_pages, cfg.page_size,
+            cfg.head_dim * fold)
 
 
 def kv_block_shape(cfg, n_pages: Optional[int] = None) -> tuple:
@@ -66,19 +73,26 @@ def kv_page_bytes(cfg, dtype_itemsize: int = 2) -> int:
 
 
 def state_shapes(cfg, num_slots: int) -> tuple:
-    """What a recurrent (Mamba-2) layer keeps a sequence, for `num_slots`
-    slots: ((S, K-1, C) the convolution's last inputs, in the activations'
-    dtype; (S, H, P, N) the SSM state, float32). Attention layers keep
-    pages (`kv_layer_shape`), expert layers nothing."""
-    return ((num_slots, cfg.conv_kernel - 1, cfg.conv_dim),
-            (num_slots, cfg.mamba_heads, cfg.mamba_head_dim, cfg.ssm_state))
+    """What a layer with a recurrent operator keeps a sequence, for
+    `num_slots` slots: the pair of arrays that stands where an attention
+    layer's K and V pages stand, each (shape, dtype). The configuration
+    answers what a slot of one such layer holds (`slot_state`: a Mamba-2
+    layer its convolution's last inputs and its float32 SSM state,
+    models/nemotron_h.py; a gated short convolution the inputs of its two
+    older taps, models/lfm2_moe.py; None in a dtype: the activations');
+    attention layers keep pages (`kv_layer_shape`), FFNs nothing."""
+    return tuple(((num_slots, *shape), dtype or cfg.dtype)
+                 for shape, dtype in cfg.slot_state)
 
 
 def state_slot_bytes(cfg, dtype_itemsize: int = 2) -> int:
     """Bytes one slot reserves on device, all recurrent layers."""
-    tail, ssm = state_shapes(cfg, 1)
-    return cfg.count("mamba") * (math.prod(tail) * dtype_itemsize
-                                 + math.prod(ssm) * 4)
+    import numpy as np
+
+    return cfg.state_layers * sum(
+        math.prod(shape) * (np.dtype(dtype).itemsize if dtype
+                            else dtype_itemsize)
+        for shape, dtype in cfg.slot_state)
 
 
 class SlotPool:

@@ -361,7 +361,7 @@ def _chunk_kv(page_tables: jax.Array, cached_lens: jax.Array,
 
     f_pages, f_offs, f_valid = flat(page_ids), flat(offsets), flat(new_valid)
     page_path = (aligned and T % P == 0 and use_pallas()
-                 and kv_write_supported(P, cfg.head_dim))
+                 and kv_write_supported(P, kv_layer_shape(cfg, 1)[-1]))
     if page_path:
         # one destination page id per (seq, page-slot); slots entirely past
         # seq_len go to scratch 0

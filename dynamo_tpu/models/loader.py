@@ -96,7 +96,24 @@ def config_from_hf(path: str, **overrides: Any) -> LlamaConfig:
         from dynamo_tpu.models import nemotron_h
 
         return nemotron_h.config_from_hf(hf, **overrides)
+    if hf.get("model_type") == "lfm2_moe":
+        # gated short convolutions and attention, dense then expert FFNs:
+        # the same kind of family
+        from dynamo_tpu.models import lfm2_moe
+
+        return lfm2_moe.config_from_hf(hf, **overrides)
     arch = (hf.get("architectures") or ["LlamaForCausalLM"])[0]
+    # every layer of the families below is attention + FFN: a stack that
+    # names another kind of layer would load with tensors missing, or not
+    # fail at all
+    other = sorted(set(hf.get("layer_types") or ()) - {"full_attention"})
+    if other:
+        raise ValueError(
+            f"{arch} checkpoint at {path} (model_type "
+            f"{hf.get('model_type')!r}): layer_types names {other}, which "
+            "the llama-family loader does not serve; a stack of mixed "
+            "layers is a family of its own (models/nemotron_h.py, "
+            "models/lfm2_moe.py)")
     known = ("llama", "mistral", "mixtral", "qwen2", "qwen3moe", "sdar")
     if not any(f in arch.lower() for f in known):
         logger.warning("loading %s with the llama-family loader", arch)

@@ -53,13 +53,16 @@ class MoeConfig(LlamaConfig):
     # here before. `router_scoring`: "softmax" (gates = softmax over the
     # k best logits) or "sigmoid" (scores sigmoid(logits); the k experts
     # are chosen by score + the learned `router_bias`, weighted by the
-    # UNBIASED scores renormalised over the chosen and times
+    # UNBIASED scores renormalised over the chosen, their sum plus
+    # `router_norm_eps` (1e-20 Nemotron-H's, 1e-6 LFM2's), and times
     # `routed_scaling`). `expert_act`: "swiglu" (gate and up projections)
-    # or "relu2" (un-gated: down(relu(up(x))^2), no w_gate leaf).
+    # or "relu2" (un-gated: down(relu(up(x))^2), no w_gate leaf); either
+    # goes with either scoring.
     # `shared_expert_size` > 0: one more expert of that width, un-gated
     # relu2 (the one form served), added for every token (w_shared_up /
     # w_shared_down).
     router_scoring: str = "softmax"
+    router_norm_eps: float = 1e-20
     routed_scaling: float = 1.0
     expert_act: str = "swiglu"
     shared_expert_size: int = 0
@@ -181,7 +184,8 @@ def _route_sigmoid(h: jax.Array, lp: dict, cfg: MoeConfig
     _, topi = jax.lax.top_k(scores + lp["router_bias"],
                             cfg.experts_per_token)
     chosen = jnp.take_along_axis(scores, topi, axis=-1)
-    gates = chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20)
+    gates = chosen / (jnp.sum(chosen, axis=-1, keepdims=True)
+                      + cfg.router_norm_eps)
     return gates * cfg.routed_scaling, topi
 
 

@@ -106,6 +106,19 @@ class NemotronHConfig(MoeConfig):
         return self.count("moe")
 
     @property
+    def state_layers(self) -> int:
+        return self.count("mamba")
+
+    @property
+    def slot_state(self) -> tuple:
+        """What a slot holds a Mamba-2 layer (engine/pages.py
+        `state_shapes`): the convolution's last K - 1 inputs in the
+        activations' dtype, the SSM state in float32."""
+        return (((self.conv_kernel - 1, self.conv_dim), None),
+                ((self.mamba_heads, self.mamba_head_dim, self.ssm_state),
+                 jnp.float32))
+
+    @property
     def d_inner(self) -> int:
         return self.mamba_heads * self.mamba_head_dim
 
@@ -271,8 +284,8 @@ def init_cache(cfg: NemotronHConfig, num_pages: int, num_slots: int = 2
     first, second = [], []
     for kind, _, _ in cfg.table:
         if kind == "mamba":
-            first.append(jnp.zeros(tail, cfg.dtype))
-            second.append(jnp.zeros(ssm, jnp.float32))
+            first.append(jnp.zeros(*tail))
+            second.append(jnp.zeros(*ssm))
         elif kind == "attn":
             first.append(jnp.zeros(kv, cfg.dtype))
             second.append(jnp.zeros(kv, cfg.dtype))

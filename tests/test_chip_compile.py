@@ -475,3 +475,91 @@ def test_prefill_round_holds_no_copy_of_stacks(sds, pallas_impl):
     assert not _missing(text, NEMOTRON_SCOPES + ("ssm_scan",))
     _no_copies(text)
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+# LFM2-8B-A1B at its published widths (benchmarks/chip/configs/
+# lfm2-8b-a1b-int8.json), five layers: both operators, both FFNs
+LFM2 = dict(vocab_size=65536, hidden_size=2048, intermediate_size=1792,
+            dense_size=7168, num_dense_layers=2, num_heads=32,
+            num_kv_heads=8, head_dim=64, rope_theta=1e6, num_experts=32,
+            experts_per_token=4, max_pages_per_seq=224,
+            operators=("conv", "conv", "attn", "conv", "attn"),
+            num_layers=5)
+LFM2_LANES, LFM2_SLOTS, LFM2_PAGES = 64, 65, 14336
+LFM2_SCOPES = ("conv_in_proj", "conv_mix", "conv_out_proj", "attn_qkv",
+               "kv_write", "attn_core", "attn_out", "mlp", "moe_route",
+               "moe_experts", "moe_combine", "lm_head")
+
+
+def lfm2_model(sds):
+    """(cfg, params, k_cache, v_cache) as shapes on the described chip:
+    int8 as `--quantize int8` serves it, the cache two heads a row."""
+    from dynamo_tpu.engine.quant import quantize_params
+    from dynamo_tpu.models import lfm2_moe as lf
+
+    cfg = lf.Lfm2MoeConfig(**LFM2)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda k: quantize_params(lf.init_params(k, cfg), mode="int8"),
+        jax.random.PRNGKey(0)))
+    kc, vc = on_chip(jax.eval_shape(
+        lambda: lf.init_cache(cfg, LFM2_PAGES, LFM2_SLOTS)))
+    return cfg, params, kc, vc
+
+
+def _no_lfm2_copies(text: str) -> None:
+    # no bf16 copy of an expert stack, no cache a head a row
+    assert not re.search(r"bf16\[(1,)?32,2048,1792\]", text)
+    assert not re.search(r"bf16\[(1,)?32,1792,2048\]", text)
+    assert not re.search(rf"bf16\[8,{LFM2_PAGES},16,64\]", text)
+
+
+def test_lfm2_decode_burst_runs_the_kernels_at_head_dim_64(sds, pallas_impl):
+    from dynamo_tpu.engine import attention
+    from dynamo_tpu.models.lfm2_moe import decode_multi_step
+
+    cfg, params, kc, vc = lfm2_model(sds)
+    assert kc[2].shape == (4, LFM2_PAGES, 16, 128)      # 12 288 B a token
+    assert kc[0].shape == (LFM2_SLOTS, 2048)
+    before = attention.attention_fallbacks.get(reason="head_dim")
+    b, i32, u32, f32 = LFM2_LANES, jnp.int32, jnp.uint32, jnp.float32
+    compiled = decode_multi_step.lower(
+        params, kc, vc, sds((b,), i32), sds((b,), i32),
+        sds((b, cfg.max_pages_per_seq), i32), sds((b,), jnp.bool_),
+        sds((b,), u32), sds((b,), u32), sds((b,), f32), sds((b,), f32),
+        sds((b,), i32), cfg, 8, topk_lp=0, slots=sds((b,), i32)).compile()
+    text = compiled.as_text()
+    assert attention.attention_fallbacks.get(reason="head_dim") == before
+    assert text.count("paged_decode_attention") >= cfg.count("attn")
+    assert text.count("kv_write_rows") >= cfg.count("attn")
+    assert text.count("moe_gmm") >= 3 * cfg.num_moe_layers
+    assert not _missing(text, LFM2_SCOPES + ("sample",))
+    _no_lfm2_copies(text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+def test_lfm2_prefill_round_runs_the_kernels_at_head_dim_64(sds,
+                                                            pallas_impl):
+    from dynamo_tpu.engine import attention
+    from dynamo_tpu.models.lfm2_moe import prefill_batch
+
+    cfg, params, kc, vc = lfm2_model(sds)
+    before = sum(attention.attention_fallbacks.get(reason=r)
+                 for r in ("head_dim", "chunk_shape"))
+    i32, t = jnp.int32, 512
+    compiled = prefill_batch.lower(
+        params, kc, vc, sds((1, t), i32),
+        sds((1, cfg.max_pages_per_seq), i32), sds((1,), i32),
+        sds((1,), i32), cfg, aligned=True, slots=sds((1,), i32)).compile()
+    text = compiled.as_text()
+    assert before == sum(attention.attention_fallbacks.get(reason=r)
+                         for r in ("head_dim", "chunk_shape"))
+    assert text.count("paged_prefill_attention") >= cfg.count("attn")
+    assert text.count("kv_write_pages") >= cfg.count("attn")
+    assert text.count("moe_gmm") >= 3 * cfg.num_moe_layers
+    assert not _missing(text, LFM2_SCOPES)
+    _no_lfm2_copies(text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30

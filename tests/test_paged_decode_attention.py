@@ -15,8 +15,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import functools
+
 from dynamo_tpu.engine.attention import (_xla_decode, decode_geometry,
-                                         paged_decode_attention)
+                                         folded, paged_decode_attention)
 
 PAGE, D, LANES = 16, 128, 8
 GEOMETRIES = [(8, 4), (4, 7), (2, 4), (1, 7), (8, 5)]     # (KVH, groups)
@@ -30,11 +32,13 @@ def _interpreted(*args):
     return paged_decode_attention(*args, interpret=pltpu.InterpretParams())
 
 
-def _case(kvh, groups, dtype, seed):
+def _case(kvh, groups, dtype, seed, d=D):
     """Eight lanes whose lengths sit on every edge the kernel has, over a
     cache whose pages are handed out in random order; lanes 6 and 7 share
-    the pages of their first block."""
-    ppb, _ = decode_geometry(LANES, kvh, groups, PAGE, D,
+    the pages of their first block. Heads `d` wide, D // d of them to a
+    row of the cache the kernel sees."""
+    fold = D // d
+    ppb, _ = decode_geometry(LANES, kvh // fold, groups * fold, PAGE, D,
                              jnp.dtype(dtype).itemsize)
     block = ppb * PAGE
     max_pages = 2 * ppb + 3               # the last block is a partial one
@@ -45,11 +49,20 @@ def _case(kvh, groups, dtype, seed):
     tables = rng.permutation(n_pages)[:LANES * max_pages].reshape(
         LANES, max_pages)
     tables[7, :ppb] = tables[6, :ppb]
-    k, v = (jnp.asarray(rng.standard_normal((kvh, n_pages, PAGE, D)), dtype)
+    k, v = (jnp.asarray(rng.standard_normal((kvh, n_pages, PAGE, d)), dtype)
             for _ in range(2))
-    q = jnp.asarray(rng.standard_normal((LANES, kvh * groups, D)), dtype)
+    q = jnp.asarray(rng.standard_normal((LANES, kvh * groups, d)), dtype)
     return (q, k, v, jnp.asarray(lengths, jnp.int32),
             jnp.asarray(tables, jnp.int32))
+
+
+def rows_of(pages, fold=2):
+    """A cache (KVH, N, P, d) as engine/pages.py `kv_layer_shape` lays it
+    out where `fold` heads ride in one row: (KVH / fold, N, P, fold * d),
+    neighbouring heads side by side."""
+    kvh, n, p, d = pages.shape
+    return pages.reshape(kvh // fold, fold, n, p, d).transpose(
+        0, 2, 3, 1, 4).reshape(kvh // fold, n, p, fold * d)
 
 
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
@@ -62,6 +75,35 @@ def test_kernel_matches_the_xla_reference(kvh, groups, dtype):
     live = np.asarray(args[3]) > 0
     np.testing.assert_allclose(got[live], want[live], **TOLERANCE[dtype])
     assert not got[~live].any()           # padding lanes: zeros, not NaN
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("kvh,groups", [(8, 4), (2, 4), (4, 7)])
+def test_two_64_wide_heads_to_a_row(kvh, groups, dtype):
+    """head_dim 64 (LFM2: 32 q / 8 kv heads of 64, GQA 4): the cache holds
+    two kv heads side by side in a 128-lane row and the kernel runs as it
+    does at 128, over q heads laid into their own head's lanes (`folded`).
+    Against the reference over the cache a head a row, the same ragged
+    lengths, page-boundary starts and shared pages."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    q, k, v, lengths, tables = _case(kvh, groups, dtype, seed=kvh + groups,
+                                     d=64)
+    kernel = functools.partial(paged_decode_attention,
+                               interpret=pltpu.InterpretParams())
+    got = np.asarray(folded(kernel, q, rows_of(k), rows_of(v), lengths,
+                            tables), np.float32)
+    want = np.asarray(_xla_decode(q, k, v, lengths, tables), np.float32)
+    assert got.shape == want.shape
+    live = np.asarray(lengths) > 0
+    np.testing.assert_allclose(got[live], want[live], **TOLERANCE[dtype])
+    assert not got[~live].any()
+    # and the XLA path over the same rows is the reference itself
+    np.testing.assert_allclose(
+        np.asarray(folded(_xla_decode, q, rows_of(k), rows_of(v), lengths,
+                          tables), np.float32)[live], want[live],
+        **TOLERANCE[dtype])
 
 
 def test_a_batch_of_two_grid_steps():

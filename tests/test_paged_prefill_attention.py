@@ -25,7 +25,7 @@ import numpy as np
 import pytest
 
 from dynamo_tpu.engine import attention
-from dynamo_tpu.engine.attention import (paged_attention_prefill,
+from dynamo_tpu.engine.attention import (folded, paged_attention_prefill,
                                          paged_prefill_attention,
                                          prefill_attention,
                                          prefill_geometry)
@@ -59,6 +59,16 @@ def _interpreted():
                                      interpret=pltpu.InterpretParams()))
 
 
+def _folded(*args):
+    """The kernel over a cache of two 64-wide heads a row; `scale` is a
+    static argument of the kernel's own jit."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    return folded(functools.partial(paged_prefill_attention,
+                                    interpret=pltpu.InterpretParams()),
+                  *args)
+
+
 def _reference(q, k, v, tables, starts, lens):
     pos = starts[:, None] + jnp.arange(q.shape[1])[None, :]
     return jax.vmap(lambda q1, pt, p1, sl: prefill_attention(
@@ -74,9 +84,10 @@ def _chunk(position, block):
             "off-edge": (block + 37, block + 37 + T)}[position]
 
 
-def _round(kvh, groups, position, width, dtype, block):
+def _round(kvh, groups, position, width, dtype, block, d=D):
     """A round of `width` lanes: lane 0 at `position`, the others at the
-    other positions in turn, the last of a wide round a padding lane."""
+    other positions in turn, the last of a wide round a padding lane.
+    Heads `d` wide."""
     names = ["first", "middle", "ends-inside", "off-edge"]
     at = names.index(position)
     spans = [_chunk(names[(at + i) % 4], block) for i in range(width)]
@@ -90,11 +101,11 @@ def _round(kvh, groups, position, width, dtype, block):
         width, max_pages)
     dead = np.arange(max_pages)[None, :] >= -(-lens[:, None] // PAGE)
     tables[dead] = n_pages - 1                     # the poison page
-    k, v = (rng.standard_normal((kvh, n_pages, PAGE, D)) for _ in range(2))
+    k, v = (rng.standard_normal((kvh, n_pages, PAGE, d)) for _ in range(2))
     nan = np.ones(n_pages, bool)
     nan[np.unique(tables[~dead])] = False
     k[:, nan], v[:, nan] = np.nan, np.nan
-    q = rng.standard_normal((width, T, kvh * groups, D))
+    q = rng.standard_normal((width, T, kvh * groups, d))
     return (jnp.asarray(q, dtype), jnp.asarray(k, dtype),
             jnp.asarray(v, dtype), jnp.asarray(tables, jnp.int32),
             jnp.asarray(starts), jnp.asarray(lens))
@@ -123,6 +134,57 @@ def test_kernel_matches_the_xla_reference(small_tiles, geometry, position,
         np.testing.assert_allclose(got[lane, :n], want[lane, :n],
                                    **TOLERANCE[dtype])
         assert not got[lane, -(-n // tq) * tq:].any()   # tiles of padding
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("position",
+                         ["first", "middle", "ends-inside", "off-edge"])
+@pytest.mark.parametrize("kvh,groups", [(4, 3), (2, 2)])
+def test_two_64_wide_heads_to_a_row(small_tiles, kvh, groups, position,
+                                    dtype):
+    """head_dim 64: two kv heads side by side in a 128-lane row of the
+    cache, the kernel as it runs at 128 over q heads laid into their own
+    head's lanes (`folded`), against the reference over the cache a head a
+    row; the same poisoned pages, ragged ends and page-boundary starts."""
+    from tests.test_paged_decode_attention import rows_of
+
+    tq, ppb = prefill_geometry(kvh // 2, groups * 2, T, PAGE, D,
+                               jnp.dtype(dtype).itemsize)
+    assert (tq, ppb * PAGE) == (16, 128)
+    q, k, v, tables, starts, lens = _round(kvh, groups, position, 2, dtype,
+                                           ppb * PAGE, d=64)
+    got = np.asarray(_folded(q, rows_of(k), rows_of(v), tables, starts,
+                             lens), np.float32)
+    want = np.asarray(_reference(q, jnp.nan_to_num(k), jnp.nan_to_num(v),
+                                 tables, starts, lens), np.float32)
+    assert got.shape == want.shape and np.isfinite(got).all()
+    for lane, n in enumerate(np.asarray(lens - starts)):
+        np.testing.assert_allclose(got[lane, :n], want[lane, :n],
+                                   **TOLERANCE[dtype])
+
+
+def test_the_tiles_lfm2_runs_on_the_chip():
+    """32 q / 8 kv heads of 64 as the kernel sees them, 4 rows of 8 q
+    heads: a 128-token chunk behind 300 cached tokens, bf16, no small
+    tiles."""
+    from tests.test_paged_decode_attention import rows_of
+
+    assert prefill_geometry(4, 8, 512, 16, 128, 2) == (128, 16)
+    chunk = 128
+    rng = np.random.default_rng(64)
+    max_pages = -(-(300 + chunk) // PAGE)
+    tables = rng.permutation(max_pages + 3)[:max_pages][None]
+    k, v = (jnp.asarray(rng.standard_normal((8, max_pages + 3, PAGE, 64)),
+                        jnp.bfloat16) for _ in range(2))
+    q = jnp.asarray(rng.standard_normal((1, chunk, 32, 64)), jnp.bfloat16)
+    rest = (jnp.asarray(tables, jnp.int32), jnp.asarray([300], jnp.int32),
+            jnp.asarray([300 + chunk - 9], jnp.int32))
+    got = np.asarray(_folded(q, rows_of(k), rows_of(v), *rest), np.float32)
+    want = np.asarray(_reference(q, k, v, *rest), np.float32)
+    np.testing.assert_allclose(got[0, :chunk - 9], want[0, :chunk - 9],
+                               **TOLERANCE[jnp.bfloat16])
+    assert np.isfinite(got).all()
 
 
 @pytest.mark.parametrize("geometry,chunk,tile,block", [

@@ -2873,7 +2873,7 @@ class TpuEngine:
         says how many did: `routed_tokens`, 0 for a sampler); the sites
         convert their inputs (`jnp.asarray`) before they get here, so
         those transfers are outside the span. An entry that samples through
-        `sample_tokens_traced` (not the speculative burst, whose ratio
+        `sample_with_logprob` (not the speculative burst, whose ratio
         test builds both sides' candidate sets for every batch) hands the
         host's copy of its lanes' temperatures (`temps`): where none
         draws the program's sampler takes its greedy branch, and the

@@ -11,20 +11,38 @@ truncated set. User top_k is clipped to MAX_CANDIDATES; top-p mass is
 computed over the candidates (the tail beyond 64 candidates carries
 negligible probability for real models). Greedy uses a full argmax.
 
-What a batch pays (one TPU v5e, (128, 131072) f32 logits, measured alone
-on the chip for PR 44): the top-k is linear in rows x vocabulary and is
-NOT cheap at a wide batch of a large vocabulary: 3.50 ms of a sampler's
-3.82, next to 0.22 for the argmax and 0.32 for the chosen token's
-log-probability. So `sample_tokens_traced` builds the candidate set under
-a `lax.cond` on "does any lane of this batch draw": an all-greedy batch
-runs the argmax alone (0.3-0.5 ms with its log-probability), a batch in
-which one lane draws runs the whole of it for every lane as before
-(3.77 ms): the same operations in the same order, so on one compiled
-program its tokens are what they were (on the chip a drawn stream still
-moves whenever XLA compiles the program around the sampler anew: PERF.md
-§6, PR 44). Every sampling site (the fused decode loops, the block burst,
-the first-token sampler) takes the condition from here; none keeps one of
-its own.
+What a batch pays (one TPU v5e, alone on the chip: PR 44 for the candidate
+set, PR 49 for the greedy tail; PERF.md §6). The top-k is linear in rows x
+vocabulary and is NOT cheap at a wide batch of a large vocabulary: 3.50 ms
+of a sampler's 3.82 at (128, 131072). So the candidate set is built under
+ONE `lax.cond` on "does any lane of this batch draw", in
+`sample_with_logprob`, the function every sampling site takes its token and
+its log-probability from (the fused decode loops, the block burst, the
+mixed, ragged, guided and pipeline steps, the first-token sampler); none
+keeps a condition, an argmax or a log-softmax of its own. A batch in which
+one lane draws runs the whole of it for every lane as before (3.77 ms): the
+same operations in the same order, so on one compiled program its tokens
+are what they were (on the chip a drawn stream still moves whenever XLA
+compiles the program around the sampler anew: PERF.md §6, PR 44).
+
+An all-greedy batch wants an argmax and the chosen token's log-probability:
+three reductions over one (rows, vocabulary) array (the maximum, where it
+first stands, the sum of exponentials), which XLA ran as three passes at
+300-420 GB/s each over logits the head had widened to float32 for them.
+The greedy branch is now the kernel `greedy_tail` (engine/greedy_tail.py),
+which reads the logits ONCE, in the dtype the head made them: the heads of
+the fused loops hand on their product as it is (bf16 where the model is),
+the condition's operand is that array, and whoever computes on it widens.
+Device ms a call, alone on the chip, the XLA form as it stood -> the kernel:
+SDAR's (256, 151936) bf16 0.54 -> 0.11 (f32: 0.89 -> 0.21), Nemotron's
+(128, 131072) 0.15 -> 0.05 (f32: 0.30 -> 0.09), LFM2's (64, 65536) 0.040
+-> 0.015, Mistral's (32, 32768) 0.013 -> 0.006, Qwen's (8, 152064) 0.014
+-> 0.008; in the SDAR block burst, where the parent also copied its
+position-major logits to float32 and flattened them for the reductions,
+1.7 ms a head forward -> 0.09.
+Off the TPU, under a mesh (a Mosaic call is not partitioned) and for shapes
+the kernel does not tile, the logits are widened ahead of the condition as
+they always were and the same two results come from XLA in two passes.
 """
 
 from __future__ import annotations
@@ -34,6 +52,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from dynamo_tpu.engine.greedy_tail import greedy_tail, greedy_tail_supported
 
 _NEG_INF = -1e30
 MAX_CANDIDATES = 64
@@ -90,6 +110,26 @@ def filtered_probs(logits: jax.Array, temperature: jax.Array,
     return full.at[jnp.arange(b)[:, None], cand_idx].add(cand_p)
 
 
+def _draw_tokens(logits: jax.Array, seeds: jax.Array, steps: jax.Array,
+                 temperature: jax.Array, top_p: jax.Array, top_k: jax.Array,
+                 min_p: Optional[jax.Array] = None) -> jax.Array:
+    """(B,) i32 tokens of a batch in which some lane draws: the candidate
+    set, keys from (seed, step), a categorical draw a lane; a greedy lane
+    of such a batch takes its argmax."""
+    masked, cand_idx, t = _candidate_mask(logits, temperature, top_p,
+                                          top_k, min_p)
+
+    def sample_one(seed, step, lg, tt):
+        key = jax.random.fold_in(jax.random.PRNGKey(seed), step)
+        return jax.random.categorical(key, lg / tt)
+
+    choice = jax.vmap(sample_one)(
+        seeds.astype(jnp.uint32), steps.astype(jnp.uint32), masked, t)
+    sampled = jnp.take_along_axis(cand_idx, choice[:, None], axis=-1)[:, 0]
+    return jnp.where(temperature > 0, sampled,
+                     jnp.argmax(logits, axis=-1).astype(jnp.int32))
+
+
 def sample_tokens_traced(logits: jax.Array, seeds: jax.Array,
                          steps: jax.Array, temperature: jax.Array,
                          top_p: jax.Array, top_k: jax.Array,
@@ -98,32 +138,81 @@ def sample_tokens_traced(logits: jax.Array, seeds: jax.Array,
     (B,) f32; top_k: (B,) i32 (0 = disabled); min_p: (B,) f32 (0 =
     disabled) — drops candidates whose probability is below
     min_p × max-probability (after temperature). temperature <= 0 ⇒
-    greedy. Returns (B,) i32 tokens. Traceable (used inside fused decode
-    loops)."""
+    greedy. Returns (B,) i32 tokens. Traceable. The tokens alone: a site
+    that also wants their log-probabilities calls `sample_with_logprob`."""
+    return lax.cond(
+        jnp.any(temperature > 0),
+        lambda: _draw_tokens(logits, seeds, steps, temperature, top_p,
+                             top_k, min_p),
+        lambda: jnp.argmax(logits, axis=-1).astype(jnp.int32))
+
+
+def _greedy_tail_runs(logits: jax.Array) -> bool:
+    """Whether a greedy batch of these logits goes through the kernel: on
+    the TPU, one device's whole rows (a Mosaic call is not partitioned
+    over a mesh), a shape it tiles."""
+    from dynamo_tpu.engine.attention import use_pallas
+
+    mesh = jax.sharding.get_abstract_mesh()
+    return (use_pallas() and (mesh.empty or mesh.size == 1)
+            and greedy_tail_supported(logits.shape, logits.dtype))
+
+
+def sample_with_logprob(logits: jax.Array, seeds: jax.Array,
+                        steps: jax.Array, temperature: jax.Array,
+                        top_p: jax.Array, top_k: jax.Array,
+                        min_p: Optional[jax.Array] = None, *,
+                        rows: Optional[jax.Array] = None,
+                        best: bool = False) -> tuple:
+    """`sample_tokens_traced`'s tokens AND `chosen_logprob` of them:
+    (tokens (B,) i32, logprob (B,) f32), the pair every sampling site
+    makes. logits (B, V) as the head made them, bf16 or f32.
+
+    One condition, "does any lane of this batch draw". It does: the
+    candidate set and the draw as they stood, then `chosen_logprob`. It
+    does not: each row's argmax and its log-softmax, which is
+    -log(sum(exp(x - max))) because the chosen token IS the maximum. On
+    the TPU that is one kernel that reads the logits once, in the dtype
+    they come in (`greedy_tail`): the condition's operand is the head's own
+    product, widened by nobody (a head that writes f32 for the sampler's
+    sake writes twice the bytes: +1.8% TPOT in the SDAR cell, PR 44).
+    Elsewhere (off the TPU, under a mesh, a shape the kernel does not
+    tile) the logits are widened ahead of the condition, where XLA fuses
+    the widening into the head as it always did, and the greedy branch is
+    the same two results in two passes, with no row maximum of its own
+    and no (B, V) result. The argmax stays inside each branch.
+
+    rows (B,) i32, when given, are the rows of a taller or shorter
+    `logits` the B lanes read (a prefill round's output as it left the
+    round): the greedy branch reduces the rows there are and gathers two
+    numbers a lane, the drawing one gathers the logits. best=True adds a
+    third result, the log-probability of each row's most likely token (a
+    block-diffusion step's confidence): the greedy branch's second
+    result again, max(log_softmax) in the drawing one."""
+    kernel = _greedy_tail_runs(logits)
+    if not kernel:
+        logits = logits.astype(jnp.float32)
+
     def greedy():
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        if kernel:
+            tok, lp = greedy_tail(logits)
+        else:
+            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            top = jnp.take_along_axis(logits, tok[:, None], axis=-1)
+            lp = -jnp.log(jnp.sum(jnp.exp(logits - top), axis=-1))
+        if rows is not None:
+            tok, lp = tok[rows], lp[rows]
+        return (tok, lp, lp) if best else (tok, lp)
 
     def draw():
-        masked, cand_idx, t = _candidate_mask(logits, temperature, top_p,
-                                              top_k, min_p)
+        x = (logits if rows is None else logits[rows]).astype(jnp.float32)
+        tok = _draw_tokens(x, seeds, steps, temperature, top_p, top_k,
+                           min_p)
+        lp = chosen_logprob(x, tok)
+        if best:
+            return tok, lp, jnp.max(jax.nn.log_softmax(x, axis=-1), axis=-1)
+        return tok, lp
 
-        def sample_one(seed, step, lg, tt):
-            key = jax.random.fold_in(jax.random.PRNGKey(seed), step)
-            return jax.random.categorical(key, lg / tt)
-
-        choice = jax.vmap(sample_one)(
-            seeds.astype(jnp.uint32), steps.astype(jnp.uint32), masked, t)
-        sampled = jnp.take_along_axis(cand_idx, choice[:, None],
-                                      axis=-1)[:, 0]
-        return jnp.where(temperature > 0, sampled, greedy())
-
-    # the candidate set only where some lane of the batch draws: a greedy
-    # batch is the argmax it always was (the predicate is loop-invariant
-    # in the fused loops, and XLA hoists it). The argmax is taken inside
-    # each branch and not ahead of the condition: ahead of it the block
-    # burst's head wrote its 256 x 151936 logits as f32 where it writes
-    # bf16 (+78 MB of temporaries compiled for a v5e, +1.8% TPOT in the
-    # SDAR cell, PR 44)
     return lax.cond(jnp.any(temperature > 0), draw, greedy)
 
 
@@ -197,7 +286,7 @@ def chosen_logprob(logits: jax.Array, sampled: jax.Array) -> jax.Array:
     """(B,) log-probability of each row's sampled token (traceable) —
     the ONE definition both prefill sampling and the fused decode loop
     use, so their logprob semantics can never diverge."""
-    logp = jax.nn.log_softmax(logits, axis=-1)
+    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
     return jnp.take_along_axis(logp, sampled[:, None], axis=-1)[:, 0]
 
 
@@ -224,7 +313,7 @@ def topk_logprobs(logits: jax.Array, k: int) -> tuple[jax.Array,
     ordering: the OpenAI response promises values sorted descending, so
     this path must NOT quantize its selection key (see
     stable_topk_logprobs for the spec lane's index-stable variant)."""
-    logp = jax.nn.log_softmax(logits, axis=-1)
+    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
     vals, ids = jax.lax.top_k(logp, k)
     return ids.astype(jnp.float32), vals
 
@@ -238,14 +327,14 @@ def _sample_tokens_lp_traced(logits, seeds, steps, temperature, top_p,
     topk lps...]. `rows` (B,) i32, when given, picks the B rows to
     sample out of a taller `logits` inside the program (a prefill
     round's (Bp, V) output as it left the round: no slice or stack
-    launched ahead of the sampler)."""
-    if rows is not None:
-        logits = logits[rows]
-    sampled = sample_tokens_traced(logits, seeds, steps, temperature,
-                                   top_p, top_k, min_p)
-    packed = [sampled.astype(jnp.float32), chosen_logprob(logits, sampled)]
+    launched ahead of the sampler; a greedy batch reduces the Bp rows and
+    gathers the lanes' two numbers, `sample_with_logprob`)."""
+    sampled, chosen = sample_with_logprob(logits, seeds, steps, temperature,
+                                          top_p, top_k, min_p, rows=rows)
+    packed = [sampled.astype(jnp.float32), chosen]
     if topk_lp:
-        ids, vals = topk_logprobs(logits, topk_lp)
+        ids, vals = topk_logprobs(
+            logits if rows is None else logits[rows], topk_lp)
         packed += [ids[:, i] for i in range(topk_lp)]
         packed += [vals[:, i] for i in range(topk_lp)]
     return jnp.stack(packed)

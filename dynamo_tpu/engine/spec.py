@@ -207,6 +207,7 @@ def spec_decode_multi_step(
             dlogits, dk, dv = _decode_once(
                 draft_params, dk, dv, dtok, pos + j, page_tables, valid,
                 draft_cfg)
+            dlogits = dlogits.astype(jnp.float32)
             if j == gamma:
                 break
             allow_j = allow_rows(st)

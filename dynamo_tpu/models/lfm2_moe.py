@@ -412,7 +412,8 @@ def _decode_once(params, k_cache, v_cache, tokens, positions, page_tables,
     with jax.named_scope("lm_head"):
         x = rms_norm(x, params["final_norm"], cfg.rms_eps)
         logits = qm(x, params["lm_head"])
-    return logits.astype(jnp.float32), tuple(first), tuple(second)
+    # as the head's product: the sampler reads it as it is
+    return logits, tuple(first), tuple(second)
 
 
 @partial(jax.jit, static_argnames=("cfg", "num_steps", "topk_lp"),
@@ -428,8 +429,7 @@ def decode_multi_step(params: dict, k_cache: tuple, v_cache: tuple,
     """models/llama.py `decode_multi_step` for this family: `num_steps`
     fused decode + sample iterations, one host round trip, `slots` (B,)
     beside `page_tables` (an invalid lane: slot 0). Same packed output."""
-    from dynamo_tpu.engine.sampling import (chosen_logprob,
-                                            sample_tokens_traced,
+    from dynamo_tpu.engine.sampling import (sample_with_logprob,
                                             topk_logprobs)
 
     def body(i, carry):
@@ -438,10 +438,10 @@ def decode_multi_step(params: dict, k_cache: tuple, v_cache: tuple,
             params, kc, vc, toks, positions + i, page_tables, valid, slots,
             cfg)
         with jax.named_scope("sample"):
-            sampled = sample_tokens_traced(
+            sampled, chosen = sample_with_logprob(
                 logits, seeds, steps0 + i, temperature, top_p, top_k)
             out = out.at[0, i].set(sampled.astype(jnp.float32))
-            out = out.at[1, i].set(chosen_logprob(logits, sampled))
+            out = out.at[1, i].set(chosen)
             if topk_lp:
                 ids, vals = topk_logprobs(logits, topk_lp)
                 out = lax.dynamic_update_slice(

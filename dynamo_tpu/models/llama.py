@@ -484,7 +484,9 @@ def _decode_once(params: dict, k_cache: tuple, v_cache: tuple,
                  tokens: jax.Array, positions: jax.Array,
                  page_tables: jax.Array, valid: jax.Array,
                  cfg: LlamaConfig) -> tuple[jax.Array, tuple, tuple]:
-    """One decode iteration body (traced; shared by single/multi-step)."""
+    """One decode iteration body (traced; shared by single/multi-step).
+    The logits leave as the head's product, in its dtype: the sampler
+    reads them as they are, whoever computes on them widens."""
     x = params["embed"][tokens]                            # (B, E)
     page_ids, offsets, lengths = _decode_kv(page_tables, positions, valid,
                                             cfg)
@@ -506,7 +508,7 @@ def _decode_once(params: dict, k_cache: tuple, v_cache: tuple,
     with jax.named_scope("lm_head"):
         x = rms_norm(x, params["final_norm"], cfg.rms_eps)
         logits = qm(x, params["lm_head"])                  # (B, V)
-    return logits.astype(jnp.float32), tuple(new_k), tuple(new_v)
+    return logits, tuple(new_k), tuple(new_v)
 
 
 @partial(jax.jit, static_argnames=("cfg",), donate_argnums=(1, 2))
@@ -519,8 +521,9 @@ def decode_step(params: dict, k_cache: jax.Array, v_cache: jax.Array,
     tokens/positions/valid: (B,); page_tables: (B, max_pages).
     Returns (logits (B, V) fp32, k_cache, v_cache).
     """
-    return _decode_once(params, k_cache, v_cache, tokens, positions,
-                        page_tables, valid, cfg)
+    logits, k_cache, v_cache = _decode_once(
+        params, k_cache, v_cache, tokens, positions, page_tables, valid, cfg)
+    return logits.astype(jnp.float32), k_cache, v_cache
 
 
 @partial(jax.jit, static_argnames=("cfg", "num_steps", "topk_lp"),
@@ -553,20 +556,16 @@ def decode_multi_step(params: dict, k_cache: jax.Array, v_cache: jax.Array,
     variant only once some lane asks for alternatives, so the hot path
     never pays the (B, V) top-k when nobody wants it.
     """
-    from dynamo_tpu.engine.sampling import sample_tokens_traced
+    from dynamo_tpu.engine.sampling import (sample_with_logprob,
+                                            topk_logprobs)
 
     def body(i, carry):
         toks, kc, vc, out = carry
-        from dynamo_tpu.engine.sampling import chosen_logprob, topk_logprobs
-
         logits, kc, vc = _decode_once(
             params, kc, vc, toks, positions + i, page_tables, valid, cfg)
         with jax.named_scope("sample"):
-            sampled = sample_tokens_traced(
+            sampled, chosen = sample_with_logprob(
                 logits, seeds, steps0 + i, temperature, top_p, top_k)
-            # chosen-token logprob: one extra (B, V) reduction pass —
-            # noise next to the lm_head matmul that produced the logits
-            chosen = chosen_logprob(logits, sampled)
             out = out.at[0, i].set(sampled.astype(jnp.float32))
             out = out.at[1, i].set(chosen)
             if topk_lp:
@@ -594,7 +593,8 @@ def _block_forward(params: dict, k_cache: tuple, v_cache: tuple,
     key set, everything up to the block's end, so they ride
     `paged_attention_decode` as B x groups query rows a kv head with
     lengths = pos0 + B: no kernel of their own. ids: (L, B); pos0, valid:
-    (L,). Returns (logits (L, B, V) f32, or None without `head`, caches)."""
+    (L,). Returns (logits (L, B, V) as the head's product, or None without
+    `head`, caches)."""
     from dynamo_tpu.engine.attention import use_pallas
     from dynamo_tpu.engine.kernels import (kv_write_supported,
                                            paged_kv_write_block)
@@ -643,7 +643,11 @@ def _block_forward(params: dict, k_cache: tuple, v_cache: tuple,
     if head:
         with jax.named_scope("lm_head"):
             x = rms_norm(x, params["final_norm"], cfg.rms_eps)
-            logits = qm(x, params["lm_head"]).astype(jnp.float32)
+            # the product over flat rows, (L * B, V), is the array the
+            # sampler reads; taken over (L, B, E) it is laid out position
+            # major and the sampler's flat view costs a copy of the logits
+            logits = qm(x.reshape(lanes * blk, -1),
+                        params["lm_head"]).reshape(lanes, blk, -1)
     return logits, tuple(new_k), tuple(new_v)
 
 
@@ -700,7 +704,7 @@ def block_decode_multi_step(params: dict, k_cache: tuple, v_cache: tuple,
     log-probability of the untempered logits of the step that fixed each,
     position-major as `decode_multi_step` packs its steps; the given
     positions carry their ids and 0; caches)."""
-    from dynamo_tpu.engine.sampling import sample_tokens_traced
+    from dynamo_tpu.engine.sampling import sample_with_logprob
 
     lanes, blk = given.shape
     per_step = blk // denoise_steps
@@ -711,16 +715,13 @@ def block_decode_multi_step(params: dict, k_cache: tuple, v_cache: tuple,
 
     def sample(logits, pos):
         """tokens, their log-probabilities and each row's confidence (the
-        log-probability of its best token), (L, B) each."""
-        flat = logits.reshape(lanes * blk, -1)
-        toks = sample_tokens_traced(
-            flat, rep(seeds), pos.reshape(-1), rep(temperature),
-            rep(top_p), rep(top_k))
-        logp = jax.nn.log_softmax(flat, axis=-1)
-        chosen = jnp.take_along_axis(logp, toks[:, None], axis=-1)[:, 0]
-        shape = (lanes, blk)
-        return (toks.reshape(shape), chosen.reshape(shape),
-                jnp.max(logp, axis=-1).reshape(shape))
+        log-probability of its best token; asked of the sampler only by
+        the strategy that reads it), (L, B) each."""
+        got = [a.reshape(lanes, blk) for a in sample_with_logprob(
+            logits.reshape(lanes * blk, -1), rep(seeds), pos.reshape(-1),
+            rep(temperature), rep(top_p), rep(top_k),
+            best=strategy == "low_confidence_static")]
+        return got[0], got[1], got[-1]
 
     def block(b, carry):
         kc, vc, out = carry
@@ -834,11 +835,8 @@ def mixed_prefill_decode(params: dict, k_cache: tuple, v_cache: tuple,
     (Bp pow2, T bucket) × the fixed decode width. Returns
     (packed (2 + 2*topk_lp, num_steps, B) f32, chunk last-token logits
     (Bp, V) f32, k_cache, v_cache)."""
-    from dynamo_tpu.engine.sampling import (
-        chosen_logprob,
-        sample_tokens_traced,
-        topk_logprobs,
-    )
+    from dynamo_tpu.engine.sampling import (sample_with_logprob,
+                                            topk_logprobs)
 
     xc, xd, k_cache, v_cache = _mixed_forward(
         params, k_cache, v_cache, ch_tokens, ch_tables, ch_cached,
@@ -847,10 +845,9 @@ def mixed_prefill_decode(params: dict, k_cache: tuple, v_cache: tuple,
     x_last = jnp.take_along_axis(xc, last[:, None, None], axis=1)[:, 0]
     ch_logits = qm(x_last, params["lm_head"]).astype(jnp.float32)
 
-    logits0 = qm(xd, params["lm_head"]).astype(jnp.float32)
-
-    def record(out, i, logits, sampled):
-        chosen = chosen_logprob(logits, sampled)
+    def sample(out, i, logits):
+        sampled, chosen = sample_with_logprob(
+            logits, seeds, steps0 + i, temperature, top_p, top_k)
         out = out.at[0, i].set(sampled.astype(jnp.float32))
         out = out.at[1, i].set(chosen)
         if topk_lp:
@@ -859,21 +856,18 @@ def mixed_prefill_decode(params: dict, k_cache: tuple, v_cache: tuple,
                 out, ids.T[:, None, :], (2, i, 0))
             out = lax.dynamic_update_slice(
                 out, vals.T[:, None, :], (2 + topk_lp, i, 0))
-        return out
+        return sampled, out
 
     out0 = jnp.zeros((2 + 2 * topk_lp, num_steps, tokens.shape[0]),
                      dtype=jnp.float32)
-    sampled0 = sample_tokens_traced(
-        logits0, seeds, steps0, temperature, top_p, top_k)
-    out0 = record(out0, 0, logits0, sampled0)
+    sampled0, out0 = sample(out0, 0, qm(xd, params["lm_head"]))
 
     def body(i, carry):
         toks, kc, vc, out = carry
         logits, kc, vc = _decode_once(
             params, kc, vc, toks, positions + i, page_tables, valid, cfg)
-        sampled = sample_tokens_traced(
-            logits, seeds, steps0 + i, temperature, top_p, top_k)
-        return sampled, kc, vc, record(out, i, logits, sampled)
+        sampled, out = sample(out, i, logits)
+        return sampled, kc, vc, out
 
     _, k_cache, v_cache, out = lax.fori_loop(
         1, num_steps, body, (sampled0, k_cache, v_cache, out0))
@@ -915,11 +909,8 @@ def ragged_prefill_decode(params: dict, k_cache: tuple, v_cache: tuple,
     ch_logits (Bp, V) f32, k_cache, v_cache).
     """
     from dynamo_tpu.engine.attention import ragged_attention
-    from dynamo_tpu.engine.sampling import (
-        chosen_logprob,
-        sample_tokens_traced,
-        topk_logprobs,
-    )
+    from dynamo_tpu.engine.sampling import (sample_with_logprob,
+                                            topk_logprobs)
 
     x = params["embed"][tokens]                            # (Tb, E)
     qpos = jnp.where(valid, positions, -1).astype(jnp.int32)
@@ -941,11 +932,10 @@ def ragged_prefill_decode(params: dict, k_cache: tuple, v_cache: tuple,
 
     x = rms_norm(x, params["final_norm"], cfg.rms_eps)
     ch_logits = qm(x[ch_rows], params["lm_head"]).astype(jnp.float32)
-    d_logits = qm(x[d_rows], params["lm_head"]).astype(jnp.float32)
+    d_logits = qm(x[d_rows], params["lm_head"])
 
-    sampled = sample_tokens_traced(
+    sampled, chosen = sample_with_logprob(
         d_logits, seeds, steps0, temperature, top_p, top_k)
-    chosen = chosen_logprob(d_logits, sampled)
     out = jnp.zeros((2 + 2 * topk_lp, 1, d_rows.shape[0]),
                     dtype=jnp.float32)
     out = out.at[0, 0].set(sampled.astype(jnp.float32))
@@ -997,9 +987,8 @@ def decode_multi_step_guided(params: dict, k_cache, v_cache,
     the tables; the engine recomputes authoritative states host-side
     from the emitted tokens)."""
     from dynamo_tpu.engine.sampling import (
-        chosen_logprob,
         constrained_logits,
-        sample_tokens_traced,
+        sample_with_logprob,
         stop_token_mask,
     )
 
@@ -1012,11 +1001,10 @@ def decode_multi_step_guided(params: dict, k_cache, v_cache,
         logits, kc, vc = _decode_once(
             params, kc, vc, toks, positions + i, page_tables, valid, cfg)
         logits = constrained_logits(
-            logits, prompt_counts, counts, rep_pen, freq_pen, pres_pen,
-            g_bits, g_eos_ok, g_ids, st, is_stop)
-        sampled = sample_tokens_traced(
+            logits.astype(jnp.float32), prompt_counts, counts, rep_pen,
+            freq_pen, pres_pen, g_bits, g_eos_ok, g_ids, st, is_stop)
+        sampled, chosen = sample_with_logprob(
             logits, seeds, steps0 + i, temperature, top_p, top_k, min_p)
-        chosen = chosen_logprob(logits, sampled)
         st = g_next[g_ids, st, sampled].astype(jnp.int32)
         counts = counts.at[jnp.arange(B), sampled].add(
             valid.astype(counts.dtype))

@@ -337,9 +337,8 @@ def _pp_decode_local(params, k_cache, v_cache, tokens0, positions,
     copies never update and never matter."""
     from dynamo_tpu.engine.attention import paged_attention_decode
     from dynamo_tpu.engine.sampling import (
-        chosen_logprob,
         constrained_logits,
-        sample_tokens_traced,
+        sample_with_logprob,
         stop_token_mask,
         topk_logprobs,
     )
@@ -396,7 +395,7 @@ def _pp_decode_local(params, k_cache, v_cache, tokens0, positions,
         vc_all = jnp.stack(new_v)
 
         xf = rms_norm(x, params["final_norm"], cfg.rms_eps)
-        logits = qm(xf, params["lm_head"]).astype(jnp.float32)
+        logits = qm(xf, params["lm_head"])
         write = active & (stage == n_stages - 1)
         minp_m = None
         if use_constrained:
@@ -407,7 +406,7 @@ def _pp_decode_local(params, k_cache, v_cache, tokens0, positions,
             cnt_m = lax.dynamic_index_in_dim(counts, m, 0, False)
             gid_m = lax.dynamic_index_in_dim(g_ids, m, 0, False)
             logits = constrained_logits(
-                logits,
+                logits.astype(jnp.float32),
                 lax.dynamic_index_in_dim(prompt_counts, m, 0, False),
                 cnt_m,
                 lax.dynamic_index_in_dim(rep_pen, m, 0, False),
@@ -416,7 +415,7 @@ def _pp_decode_local(params, k_cache, v_cache, tokens0, positions,
                 g_bits, g_eos_ok, gid_m, st_m,
                 lax.dynamic_index_in_dim(is_stop, m, 0, False))
             minp_m = lax.dynamic_index_in_dim(min_p, m, 0, False)
-        sampled = sample_tokens_traced(
+        sampled, lp_chosen = sample_with_logprob(
             logits,
             lax.dynamic_index_in_dim(seeds, m, 0, False),
             lax.dynamic_index_in_dim(steps0, m, 0, False) + k_idx,
@@ -424,7 +423,6 @@ def _pp_decode_local(params, k_cache, v_cache, tokens0, positions,
             lax.dynamic_index_in_dim(top_p, m, 0, False),
             lax.dynamic_index_in_dim(top_k, m, 0, False),
             minp_m)
-        lp_chosen = chosen_logprob(logits, sampled)
         if use_constrained:
             new_st = g_next[gid_m, st_m, sampled].astype(jnp.int32)
             gst = lax.dynamic_update_index_in_dim(

@@ -148,6 +148,42 @@ def _missing(text: str, names) -> list[str]:
     return [n for n in names if f"/{n}/" not in text]
 
 
+def _greedy_tail_reads(text: str, operand: str, absent=()) -> None:
+    """The sampler's greedy branch is the kernel `greedy_tail` over
+    `operand`, the logits as they were written (a head's product is bf16):
+    the computation that holds the kernel holds none of the `absent`
+    arrays (no widening ahead of it, no log-softmax beside it, no gather
+    of the lanes' rows)."""
+    held = [c for c in text.split("\n}\n")
+            if 'custom_call_target="tpu_custom_call"' in c
+            and "/greedy_tail/" in c]
+    assert held, "no greedy_tail kernel in the compiled program"
+    for computation in held:
+        assert operand in computation
+        for shape in absent:
+            assert shape not in computation, shape
+
+
+def _reads_the_heads_product(text: str, rows: int, vocab: int) -> None:
+    _greedy_tail_reads(text, f"bf16[{rows},{vocab}]",
+                       absent=(f"f32[{rows},{vocab}]",))
+
+
+def test_first_token_sampler_reduces_the_rows_of_the_round(sds, pallas_impl):
+    """A prefill round of 8 sequences hands its (8, V) float32 logits to
+    the first-token sampler of a 128-lane engine with the rows the lanes
+    read: a greedy batch runs the kernel over the 8 rows there are and
+    gathers two numbers a lane, never 128 rows of logits."""
+    from dynamo_tpu.engine.sampling import sample_tokens_lp
+
+    b, v, i32, u32, f32 = 128, 131072, jnp.int32, jnp.uint32, jnp.float32
+    text = sample_tokens_lp.lower(
+        sds((8, v), f32), sds((b,), u32), sds((b,), u32), sds((b,), f32),
+        sds((b,), f32), sds((b,), i32), sds((b,), f32),
+        rows=sds((b,), i32), topk_lp=0).compile().as_text()
+    _greedy_tail_reads(text, f"f32[8,{v}]", absent=(f"[{b},{v}]",))
+
+
 @pytest.fixture
 def pallas_impl(monkeypatch):
     # use_pallas() asks the default backend, which is the CPU here:
@@ -212,6 +248,7 @@ def test_engine_decode_burst_holds_the_kernels(sds, model, pallas_impl):
     assert text.count("kv_write_rows") >= cfg.num_layers
     assert text.count("paged_decode_attention") >= cfg.num_layers
     assert not _missing(text, LAYER_SCOPES + ("sample",))
+    _reads_the_heads_product(text, b, cfg.vocab_size)
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 1 << 30
 
@@ -356,7 +393,11 @@ def test_sdar_block_burst_holds_its_kernels(sds, pallas_impl):
     # int8 one (the kernel indexes the layer itself): (128, 2048, 768) or
     # its transpose
     assert not re.search(r"(bf16|s8)\[128,(2048,768|768,2048)\]", text)
-    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+    # 64 lanes x 4 positions of bf16 logits, read once where they stand
+    _reads_the_heads_product(text, 4 * b, cfg.vocab_size)
+    # 161.6 MB with the head's product handed on as it is; 318.9 MB where
+    # the head widened it to float32 for the sampler (the parent of PR 49)
+    assert compiled.memory_analysis().temp_size_in_bytes < 240 << 20
 
 
 @pytest.mark.parametrize("bp", [1, 16])
@@ -456,6 +497,7 @@ def test_decode_burst_holds_no_copy_of_stacks_or_state(sds, pallas_impl):
     assert text.count("paged_decode_attention") >= cfg.count("attn")
     assert not _missing(text, NEMOTRON_SCOPES + ("ssm_update", "sample"))
     _no_copies(text)
+    _reads_the_heads_product(text, b, cfg.vocab_size)
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
 
 
@@ -538,6 +580,7 @@ def test_lfm2_decode_burst_runs_the_kernels_at_head_dim_64(sds, pallas_impl):
     assert text.count("moe_gmm") >= 3 * cfg.num_moe_layers
     assert not _missing(text, LFM2_SCOPES + ("sample",))
     _no_lfm2_copies(text)
+    _reads_the_heads_product(text, b, cfg.vocab_size)
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
 
 

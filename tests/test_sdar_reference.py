@@ -184,6 +184,44 @@ def denoise_through_the_cache(toy, prompt, n_blocks, steps, strategy):
     return served, np.stack([rows[p] for p in sorted(rows)]), order
 
 
+@pytest.mark.parametrize("steps", [4, 2])
+def test_the_burst_fixes_the_positions_max_log_softmax_would(toy, steps):
+    """`low_confidence_static` reads a row's confidence off the sampler's
+    greedy pair (the log-probability of the row's best token IS the chosen
+    token's) since PR 49. The burst itself against the steps by hand, whose
+    confidence is the formulation it had, max(log_softmax): the same ids,
+    and each with the log-probability of the step that fixed it, which
+    names the step (a position's logits move from step to step)."""
+    prompt = prompt_of(13, seed=9)
+    n_blocks = 3
+    served, z, order = denoise_through_the_cache(
+        toy, prompt, n_blocks, steps, "low_confidence_static")
+    assert order != sorted(order)               # not left to right
+    cfg, params = toy["cfg"], jax.device_put(toy["params"])
+    kc, vc = llama.init_cache(cfg, 16)
+    whole = len(prompt) - len(prompt) % BLOCK
+    table = jnp.arange(1, 17, dtype=jnp.int32)[None]
+    tokens = np.zeros((1, 16), np.int32)
+    tokens[0, :whole] = prompt[:whole]
+    _, kc, vc = llama.prefill_batch(
+        params, kc, vc, jnp.asarray(tokens), table, jnp.asarray([0]),
+        jnp.asarray([whole]), cfg)
+    tail = prompt[whole:]
+    given = np.zeros((1, BLOCK), np.int32)
+    given[0, :len(tail)] = tail
+    one = jnp.ones((1,), jnp.float32)
+    packed, _, _ = llama.block_decode_multi_step(
+        params, kc, vc, jnp.asarray(given), jnp.asarray([len(tail)]),
+        jnp.asarray([whole]), table, jnp.asarray([True]),
+        jnp.zeros((1,), jnp.uint32), 0 * one, one,
+        jnp.zeros((1,), jnp.int32), cfg, n_blocks, steps,
+        "low_confidence_static")
+    packed = np.asarray(packed)[:, len(tail):, 0]
+    assert [int(t) for t in packed[0]] == served
+    want = sdar_toy.log_softmax(z)[np.arange(len(served)), served]
+    np.testing.assert_allclose(packed[1], want, atol=F32_TOL)
+
+
 @pytest.mark.parametrize("steps,strategy", [
     (4, "sequential"), (2, "sequential"), (4, "low_confidence_static"),
     (2, "low_confidence_static")])

@@ -50,7 +50,10 @@ from dynamo_tpu.models.llama import (_chunk_kv, _decode_kv, _write_kv,
 from dynamo_tpu.models.mixtral import MoeConfig, moe_mlp
 
 _HIGHEST = jax.lax.Precision.HIGHEST
-_SSM_VMEM_BYTES = 64 << 20
+# what one phase of the state update's queue moves; the queue is two such
+# buffers, and the limit leaves the batch's small operands their room
+_SSM_PHASE_BYTES = 8 << 20
+_SSM_VMEM_BYTES = 4 * _SSM_PHASE_BYTES
 KINDS = {"M": "mamba", "E": "moe", "*": "attn"}
 
 
@@ -355,10 +358,12 @@ def ssm_chunk_scan(x, dt, a, b, c, s0, chunk: int):
 
 
 def ssm_kernel_runs(heads: int, head_dim: int, state: int) -> bool:
-    """The Pallas kernel holds a head's state as (head_dim, state) tiles
-    and the heads of a lane side by side in one tile's lanes."""
+    """The Pallas kernel holds a head's state as (head_dim, state) tiles,
+    the heads of a lane side by side in one tile's lanes, and a lane's
+    whole state as one buffer of its queue, which a phase must hold."""
     return (use_pallas() and state % 128 == 0 and head_dim % 8 == 0
-            and heads <= 128)
+            and heads <= 128
+            and heads * head_dim * state * 4 <= _SSM_PHASE_BYTES)
 
 
 def ssm_decode_update(state, slots, x, dt, a, b, c, d, *, interpret=None):
@@ -371,7 +376,7 @@ def ssm_decode_update(state, slots, x, dt, a, b, c, d, *, interpret=None):
     if interpret is None and not ssm_kernel_runs(heads, p, n):
         return _ssm_decode_update_xla(state, slots, x, dt, a, b, c, d)
     return _ssm_decode_update_kernel(state, slots, x, dt, a, b, c, d,
-                                     interpret=bool(interpret))
+                                     interpret=interpret or False)
 
 
 def _ssm_decode_update_xla(state, slots, x, dt, a, b, c, d):
@@ -386,13 +391,30 @@ def _ssm_decode_update_xla(state, slots, x, dt, a, b, c, d):
 
 def _ssm_decode_update_kernel(state, slots, x, dt, a, b, c, d, *,
                               interpret=False):
-    """Grid: one step a lane. The step's block of the state is its slot's
-    whole (H, P, N), fetched by the prefetched slot index and written back
-    to the same place (`input_output_aliases`): no gathered copy of the
-    state ever exists. A head's tile is (P, N): P in sublanes, N in lanes.
-    What varies along N (decay, dt, B, C) comes as rows; what varies along
-    P (x) comes transposed, (P, heads in lanes), so that a head's column
-    is a lane slice; y leaves the same way."""
+    """Grid: one step a BATCH of lanes, as many as divide the lanes and
+    fill `_SSM_PHASE_BYTES`. The state never leaves its slots but through
+    the kernel's own queue (`input_output_aliases`, no gathered copy): a
+    batch's lanes are fetched into VMEM buffers, updated where they lie
+    and written back to the slots they came from. The copies run in
+    PHASES, all of a batch's reads, then all of the batch before's writes,
+    never both at once: a read and a write stream that share the bus reach
+    81% of its bandwidth, a stream at a time 85% (PERF.md §6, PR 50). The
+    update of a batch runs under the write phase of the one before.
+
+    A head's tile is (P, N): P in sublanes, N in lanes. What varies along
+    N (B, C) comes as rows; what varies along P (x) comes transposed,
+    (P, heads in lanes), so that a head's column is a lane slice; a head's
+    decay is a scalar in SMEM; y leaves transposed as x came. The body is
+    two passes over a batch, each a loop of its own: the updates (a lane
+    broadcast of x's column a tile), then the sums over N (a lane
+    reduction a tile). In ONE instruction stream the two kinds of
+    cross-lane work take 7.8 us a lane, 1.3 and 1.1 us apart: the unit
+    serves one kind at a time.
+
+    Two invalid lanes share slot 0 and every buffer is written back with
+    what ITS lane read and updated, never with another's, so a live slot
+    (one lane's) is read once and written once; slot 0 (dt = 0) only ever
+    receives the bits it holds."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -401,44 +423,93 @@ def _ssm_decode_update_kernel(state, slots, x, dt, a, b, c, d, *,
     rep = heads // groups
     hl = -(-heads // 128) * 128
     f32 = jnp.float32
-    wide = (lanes, heads, n)
-    xt = jnp.pad(jnp.swapaxes(x, 1, 2), ((0, 0), (0, 0), (0, hl - heads)))
-    dec = jnp.broadcast_to(jnp.exp(dt * a)[..., None], wide)
-    dtw = jnp.broadcast_to(dt[..., None], wide)
+    fit = min(lanes, _SSM_PHASE_BYTES // (heads * p * n * 4))
+    kk = max(k for k in range(1, fit + 1) if lanes % k == 0)
+    steps = lanes // kk
+    pad = ((0, 0), (0, 0), (0, hl - heads))
+    xt = jnp.pad(jnp.swapaxes(x, 1, 2), pad)                # (B, P, hl)
+    dtrow = jnp.pad(dt[:, None, :], pad)                    # (B, 1, hl)
     drow = jnp.pad(d, (0, hl - heads))[None, :]
+    dec = jnp.exp(dt * a).reshape(-1)                       # (B * H,)
 
-    def kernel(slot_ref, xt_ref, dec_ref, dt_ref, b_ref, c_ref, d_ref,
-               s_in, y_ref, s_out):
-        cols = xt_ref[0]                                    # (P, hl)
-        lane = jax.lax.broadcasted_iota(jnp.int32, (p, hl), 1)
-        acc = jnp.zeros((p, hl), f32)
-        for h in range(heads):
-            g = h // rep
-            col = cols[:, h:h + 1]                          # (P, 1)
-            new = dec_ref[0, h:h + 1, :] * s_in[0, h] \
-                + (col * dt_ref[0, h:h + 1, :]) * b_ref[0, g:g + 1, :]
-            s_out[0, h] = new
-            out = jnp.sum(new * c_ref[0, g:g + 1, :], axis=-1,
-                          keepdims=True)                    # (P, 1)
-            acc = jnp.where(lane == h, out, acc)
-        y_ref[0] = acc + cols * d_ref[...]
+    def kernel(slot_ref, dec_ref, xt_ref, dt_ref, b_ref, c_ref, d_ref,
+               s_in, y_ref, s_out, buf, rsem, wsem):
+        k = pl.program_id(0)
+        cur = k % 2
 
-    def lane_block(*shape):
-        return pl.BlockSpec((1, *shape),
-                            lambda i, slot: (i,) + (0,) * len(shape))
+        def read(step, j):
+            return pltpu.make_async_copy(
+                s_in.at[slot_ref[step * kk + j]], buf.at[step % 2, j],
+                rsem.at[step % 2, j])
 
-    slot_block = pl.BlockSpec((1, heads, p, n),
-                              lambda i, slot: (slot[i], 0, 0, 0))
+        def write(step, j):
+            return pltpu.make_async_copy(
+                buf.at[step % 2, j], s_out.at[slot_ref[step * kk + j]],
+                wsem.at[step % 2, j])
+
+        def start(copy, step):
+            for j in range(kk):
+                copy(step, j).start()
+
+        def wait(copy, step):
+            for j in range(kk):
+                copy(step, j).wait()
+
+        # this batch's reads were started a step ago; once they are in,
+        # the bus is the batch before's writes', and the body runs under them
+        pl.when(k == 0)(lambda: start(read, k))
+        wait(read, k)
+        pl.when(k > 0)(lambda: start(write, k - 1))
+
+        def update(j, carry):
+            xdt = xt_ref[j] * dt_ref[j]                     # (P, hl)
+            for h in range(heads):
+                g = h // rep
+                buf[cur, j, h] = dec_ref[(k * kk + j) * heads + h] \
+                    * buf[cur, j, h] \
+                    + xdt[:, h:h + 1] * b_ref[j, g:g + 1, :]
+            return carry
+
+        def readout(j, carry):
+            lane = jax.lax.broadcasted_iota(jnp.int32, (p, hl), 1)
+            acc = jnp.zeros((p, hl), f32)
+            for h in range(heads):
+                g = h // rep
+                out = jnp.sum(buf[cur, j, h] * c_ref[j, g:g + 1, :],
+                              axis=-1, keepdims=True)       # (P, 1)
+                acc = jnp.where(lane == h, out, acc)
+            y_ref[j] = acc + xt_ref[j] * d_ref[...]
+            return carry
+
+        jax.lax.fori_loop(0, kk, update, 0)
+        jax.lax.fori_loop(0, kk, readout, 0)
+
+        # the next batch's reads fill the buffers those writes empty
+        pl.when(k > 0)(lambda: wait(write, k - 1))
+        pl.when(k + 1 < steps)(lambda: start(read, k + 1))
+
+        @pl.when(k == steps - 1)
+        def _():
+            start(write, k)
+            wait(write, k)
+
+    def batch_block(*shape):
+        return pl.BlockSpec((kk, *shape),
+                            lambda k, *_: (k,) + (0,) * len(shape))
+
+    in_place = pl.BlockSpec(memory_space=pl.ANY)
     yt, state = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=(lanes,),
-            in_specs=[lane_block(p, hl), lane_block(heads, n),
-                      lane_block(heads, n), lane_block(groups, n),
-                      lane_block(groups, n),
-                      pl.BlockSpec((1, hl), lambda i, slot: (0, 0)),
-                      slot_block],
-            out_specs=[lane_block(p, hl), slot_block]),
+            num_scalar_prefetch=2, grid=(steps,),
+            in_specs=[batch_block(p, hl), batch_block(1, hl),
+                      batch_block(groups, n), batch_block(groups, n),
+                      pl.BlockSpec((1, hl), lambda k, *_: (0, 0)),
+                      in_place],
+            out_specs=[batch_block(p, hl), in_place],
+            scratch_shapes=[pltpu.VMEM((2, kk, heads, p, n), f32),
+                            pltpu.SemaphoreType.DMA((2, kk)),
+                            pltpu.SemaphoreType.DMA((2, kk))]),
         out_shape=[jax.ShapeDtypeStruct((lanes, p, hl), f32),
                    jax.ShapeDtypeStruct(state.shape, f32)],
         input_output_aliases={7: 1},        # the state, updated in place
@@ -447,7 +518,7 @@ def _ssm_decode_update_kernel(state, slots, x, dt, a, b, c, d, *,
             vmem_limit_bytes=_SSM_VMEM_BYTES),
         interpret=interpret,
         name="ssm_decode_update",
-    )(slots.astype(jnp.int32), xt, dec, dtw, b, c, drow, state)
+    )(slots.astype(jnp.int32), dec, xt, dtrow, b, c, drow, state)
     return jnp.swapaxes(yt[:, :, :heads], 1, 2), state
 
 

@@ -474,6 +474,13 @@ def test_ssm_decode_update_kernel(sds, pallas_impl):
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
 
 
+def _kernels(text: str) -> list[tuple[str, str]]:
+    """(name, scope path) of every Mosaic kernel of a compiled program."""
+    return re.findall(
+        r'%([\w-]+?)(?:\.\d+)* = [^\n]*custom_call_target="tpu_custom_call"'
+        r'[^\n]*?op_name="([^"]*)"', text)
+
+
 def _no_copies(text: str) -> None:
     # no bf16 copy of an expert stack, no gathered copy of the state
     assert not re.search(r"bf16\[(1,)?128,2688,19\d\d\]", text)
@@ -493,6 +500,14 @@ def test_decode_burst_holds_no_copy_of_stacks_or_state(sds, pallas_impl):
         sds((b,), i32), cfg, 8, topk_lp=0, slots=sds((b,), i32)).compile()
     text = compiled.as_text()
     assert text.count("ssm_decode_update") >= cfg.count("mamba")
+    # ONE kernel a Mamba layer, under the name `ssm_update_hbm_roofline`
+    # finds its seconds by, and no other kernel of the mixer beside it
+    kernels = _kernels(text)
+    assert [name for name, scope in kernels if "/ssm_" in scope] \
+        == ["ssm_decode_update"] * cfg.count("mamba")
+    assert {name for name, _ in kernels} == {
+        "ssm_decode_update", "moe_gmm", "kv_write_rows",
+        "paged_decode_attention", "greedy_tail"}
     assert text.count("moe_gmm") >= 2 * cfg.count("moe")
     assert text.count("paged_decode_attention") >= cfg.count("attn")
     assert not _missing(text, NEMOTRON_SCOPES + ("ssm_update", "sample"))

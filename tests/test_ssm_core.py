@@ -174,16 +174,97 @@ def run_step(fn, i, slots, **kw):
               i["a"], i["b"], i["c"], i["d"], **kw)
 
 
-@pytest.mark.parametrize("heads,p,groups,n", [(4, 16, 2, 16), (8, 8, 8, 128),
-                                              (6, 8, 1, 32)])
+@pytest.mark.parametrize("heads,p,groups,n", [
+    (4, 16, 2, 16), (8, 8, 8, 128), (6, 8, 1, 32),
+    (64, 64, 8, 128),       # the Nemotron cell's own: 2 MiB a lane
+], ids=["small", "a-head-a-group", "one-group", "the-cell"])
 def test_the_kernel_in_interpret_mode_equals_its_xla_twin(heads, p, groups,
                                                           n):
+    """TOL as for the chunked form. The state is elementwise, the same
+    operations in the same order on both sides. y is a sum of N float32
+    products a row on both sides, the kernel's by a lane reduction over
+    the tile it has just written, the twin's by an einsum at full
+    precision: they differ by the order of the additions only, N x 2**-24
+    of the terms' size at worst (1e-5 at N = 128), whatever order a body
+    sums in, as long as it sums float32 products in float32."""
     i = step_inputs(heads=heads, p=p, groups=groups, n=n)
     slots = [3, 1, 6, 4, 2, 5]
     y, state = run_step(nh.ssm_decode_update, i, slots, interpret=True)
     want_y, want_state = run_step(nh._ssm_decode_update_xla, i, slots)
     np.testing.assert_allclose(y, want_y, atol=TOL, rtol=TOL)
     np.testing.assert_allclose(state, want_state, atol=TOL, rtol=TOL)
+
+
+@pytest.mark.parametrize("a_batch", [1, 2, 3, 6])
+def test_the_queue_in_batches_of_any_size_keeps_slot_zero_and_the_live_slots(
+        a_batch, monkeypatch, capfd):
+    """Six lanes as 6, 3, 2 batches and as 1: lanes 1 and 4 are invalid
+    (slot 0, dt = 0) BETWEEN live lanes, in one batch of the queue or in
+    two. Slot 0 keeps its bits and the slots of no lane keep theirs; a
+    live slot holds what the twin gives (the update is elementwise: to the
+    bit on the chip, within a fused multiply-add's rounding where XLA's
+    CPU backend fuses one side) and, to the bit, what the queue gives as
+    ONE batch: every buffer goes back to the slot it was read from. In the
+    TPU interpreter, which keeps the copies' and the body's clocks: no
+    buffer is read or written while a copy of it is in flight."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    i = step_inputs()
+    i["dt"] = i["dt"].at[jnp.asarray([1, 4])].set(0.0)
+    slots = [3, 0, 6, 4, 0, 5]
+    tpu = pltpu.InterpretParams(detect_races=True)
+    one_y, one_state = run_step(nh.ssm_decode_update, i, slots,
+                                interpret=tpu)
+    monkeypatch.setattr(nh, "_SSM_PHASE_BYTES",
+                        a_batch * i["state"][0].size * 4)
+    y, state = run_step(nh.ssm_decode_update, i, slots, interpret=tpu)
+    assert "RACE DETECTED" not in capfd.readouterr().out
+    want_y, want_state = run_step(nh._ssm_decode_update_xla, i, slots)
+    np.testing.assert_array_equal(state[0], 0.0)
+    for untouched in (1, 2):
+        np.testing.assert_array_equal(state[untouched], i["state"][untouched])
+    np.testing.assert_array_equal(state, one_state)
+    for live in (3, 4, 5, 6):
+        np.testing.assert_allclose(state[live], want_state[live], atol=TOL,
+                                   rtol=TOL)
+        assert not np.allclose(state[live], i["state"][live])
+    live_lanes = np.asarray([0, 2, 3, 5])
+    np.testing.assert_array_equal(y[live_lanes], one_y[live_lanes])
+    np.testing.assert_allclose(y[live_lanes], want_y[live_lanes], atol=TOL,
+                               rtol=TOL)
+
+
+@pytest.fixture
+def pallas_impl(monkeypatch):
+    # use_pallas() asks the default backend, the CPU here: answer as on
+    # the chip
+    from dynamo_tpu.engine import attention
+
+    monkeypatch.setattr(attention, "_impl", "pallas")
+
+
+@pytest.mark.parametrize("heads,p,n,runs", [
+    (64, 64, 128, True),        # the cell's
+    (8, 8, 128, True),
+    (6, 8, 32, False),          # a state narrower than a tile's lanes
+    (64, 12, 128, False),       # a head's rows not whole tiles
+    (130, 64, 128, False),      # more heads than one tile's lanes
+    (128, 128, 256, False),     # a lane's state (16 MiB) over a phase
+])
+def test_the_gate_asks_whole_tiles_and_a_lane_a_phase_can_hold(
+        heads, p, n, runs, pallas_impl):
+    assert nh.ssm_kernel_runs(heads, p, n) is runs
+
+
+def test_a_shape_the_gate_refuses_takes_the_xla_twin(pallas_impl,
+                                                     monkeypatch):
+    monkeypatch.setattr(nh, "_ssm_decode_update_kernel", None)
+    i = step_inputs(heads=6, p=8, groups=1, n=32)
+    slots = [3, 1, 6, 4, 2, 5]
+    y, state = run_step(nh.ssm_decode_update, i, slots)
+    want_y, want_state = run_step(nh._ssm_decode_update_xla, i, slots)
+    np.testing.assert_array_equal(y, want_y)
+    np.testing.assert_array_equal(state, want_state)
 
 
 @pytest.mark.parametrize("interpret", [None, True], ids=["xla", "kernel"])
